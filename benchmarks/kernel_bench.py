@@ -144,7 +144,7 @@ def sweep_decode_attention(caps, backends=None, do_autotune: bool = False):
                                                  bk=None, fp_upcast=True)
         for backend in backends:
             cands = autotune.decode_attention_candidates(
-                cap, hd=hd, group=group, quantized=True)
+                cap, nkv=nkv, hd=hd, group=group, quantized=True)
             blocks = [None] if backend == "xla-ref" else [None] + cands[:3]
             for blk in blocks:
                 out = dispatch.decode_attention(
@@ -185,7 +185,7 @@ def sweep_decode_attention(caps, backends=None, do_autotune: bool = False):
                         q, k, v, k_pos, pos, k_scale=ks, v_scale=vs,
                         block=tuple(blk), backend=backend),
                     candidates=autotune.decode_attention_candidates(
-                        cap, hd=hd, group=group, quantized=True)[:3])
+                        cap, nkv=nkv, hd=hd, group=group, quantized=True)[:3])
                 key = autotune.cache_key(
                     "decode_attention", (b, cap, nkv, group, hd), "int8", 8,
                     "flash", backend)

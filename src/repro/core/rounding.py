@@ -69,8 +69,9 @@ def hash_uniform(seed, idx, counter) -> jax.Array:
         h = _mix(seed ^ _GOLDEN)
         h = _mix(h ^ idx * _M1)
         h = _mix(h ^ counter * _M2)
-    # 24-bit mantissa → exact float32 uniform on [0,1)
-    return (h >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+    # 24-bit mantissa → exact float32 uniform on [0,1); the convert goes
+    # through int32 (exact below 2²⁴) because Mosaic has no uint32 → f32
+    return (h >> 8).astype(jnp.int32).astype(jnp.float32) * (1.0 / (1 << 24))
 
 
 def _coprime_multiplier(n: int) -> int:
@@ -151,7 +152,7 @@ def dither_bit(frac: jax.Array, slot: jax.Array, u: jax.Array, n_pulses: int) ->
     """
     N = float(n_pulses)
     f = jnp.asarray(frac, jnp.float32)
-    slot = slot.astype(jnp.float32)
+    slot = slot.astype(jnp.int32).astype(jnp.float32)  # < N ≪ 2²⁴: exact
 
     lo = f <= 0.5
     n_lo = jnp.floor(N * f)

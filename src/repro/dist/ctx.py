@@ -48,8 +48,7 @@ def current_mesh():
 
 
 def _mesh_sizes(mesh) -> dict:
-    return dict(zip(mesh.axis_names, getattr(mesh, "axis_sizes", None)
-                    or tuple(mesh.shape[a] for a in mesh.axis_names)))
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def axis_size(name: str) -> int:

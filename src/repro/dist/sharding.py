@@ -61,8 +61,7 @@ _TP_RULES = {
 
 
 def _mesh_sizes(mesh) -> dict:
-    return dict(zip(mesh.axis_names, getattr(mesh, "axis_sizes", None)
-                    or tuple(mesh.shape[a] for a in mesh.axis_names)))
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def data_axes(mesh):

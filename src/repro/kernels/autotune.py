@@ -30,12 +30,10 @@ from typing import Callable, Dict, List, Optional, Tuple
 __all__ = [
     "VMEM_BUDGET_BYTES",
     "matmul_vmem_bytes", "quantize_vmem_bytes", "decode_attention_vmem_bytes",
-    "verify_attention_vmem_bytes",
     "matmul_candidates", "quantize_candidates", "decode_attention_candidates",
-    "paged_attention_candidates", "verify_attention_candidates",
+    "paged_attention_candidates",
     "best_block", "autotune_matmul", "autotune_quantize",
     "autotune_decode_attention", "autotune_paged_attention",
-    "autotune_verify_attention",
     "cache_key", "load_cache", "save_cache", "clear_cache",
     "register_observer",
 ]
@@ -76,51 +74,61 @@ def matmul_vmem_bytes(block: Tuple[int, int, int]) -> int:
     """Working-set model for the fused matmul kernel at one grid step:
     double-buffered A (bm, bk) and B (bk, bn) input tiles, the f32
     accumulator + output tile (bm, bn), and the affine-zero cross-term rows
-    and columns.  Quantised codes are produced in registers (f32-valued),
-    modelled as one extra copy of each operand tile."""
+    and columns.  The in-kernel quantiser keeps tile-sized temporaries
+    (element index, hash, fraction, codes), modelled as four copies of each
+    operand tile: at (512, 512, 576) that gives 16.2 MiB against the 17 MiB
+    the TPU compiler reports for the kernel's scoped VMEM."""
     bm, bn, bk = block
     a_tile = bm * bk * _F32
     b_tile = bk * bn * _F32
     acc = bm * bn * _F32
     out = bm * bn * _F32
     sums = (bm + bn) * _F32
-    codes = a_tile + b_tile
-    return 2 * (a_tile + b_tile) + acc + out + sums + codes
+    temps = 4 * (a_tile + b_tile)
+    return 2 * (a_tile + b_tile) + acc + out + sums + temps
 
 
 def quantize_vmem_bytes(block: Tuple[int, int]) -> int:
-    """Elementwise kernel: double-buffered f32 input and int32 output tiles."""
+    """Elementwise kernel: double-buffered f32 input and int32 output tiles,
+    plus the hash quantiser's tile-sized temporaries, modelled as ten
+    tiles: at (512, 1024) that gives 28 MiB against the 27.9 MiB the TPU
+    compiler reports for the kernel's scoped VMEM."""
     bm, bn = block
-    return 2 * (bm * bn * _F32) * 2
+    tile = bm * bn * _F32
+    return 2 * 2 * tile + 10 * tile
 
 
-def decode_attention_vmem_bytes(block: Tuple[int], *, hd: int, group: int,
-                                quantized: bool) -> int:
-    """Working-set model for the flash-decode kernel at one grid step:
-    double-buffered K and V cache tiles (bk, hd) in their storage dtype
-    (int8 codes or bf16), their register upcasts (modelled as one f32 copy
-    each), the (group, bk) logit/weight tiles, per-position scale and k_pos
-    rows, and the online-softmax state (acc + m + s) plus the query tile."""
+def _plane_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of one (rows, cols) minor plane: Mosaic pads rows to the
+    dtype's sublane tile (8 rows of 32-bit, 16 of 16-bit, 32 of 8-bit) and
+    cols to the 128-lane width."""
+    sub = _SUBLANE * _F32 // itemsize
+    return -(-rows // sub) * sub * -(-cols // _LANE) * _LANE * itemsize
+
+
+def decode_attention_vmem_bytes(block: Tuple[int], *, nkv: int, hd: int,
+                                group: int, quantized: bool,
+                                kq: int = 1) -> int:
+    """Working-set model for the flash-decode / verify kernel at one grid
+    step, with every (…, n_kv, hd) and (…, group, bk) plane padded to its
+    VMEM tile: double-buffered K and V tiles (bk, n_kv, hd) in their
+    storage dtype (int8 codes or bf16), their in-kernel upcasts (K to bf16,
+    V to f32), the (n_kv, group, bk) logit and weight tiles, the per-position
+    scale and k_pos rows, and the online-softmax state, query and output
+    for the ``kq`` query rows scored per program."""
     (bk,) = block
     elem = 1 if quantized else 2
-    kv_tiles = 2 * 2 * bk * hd * elem          # double-buffered K and V
-    upcast = 2 * bk * hd * _F32                # in-register f32 working copies
-    logits = 2 * group * bk * _F32             # logit + weight tiles
-    scales = (2 * 2 * bk * _F32) if quantized else 0
-    kpos = 2 * bk * 4
-    state = group * (hd + 2) * _F32            # acc, m, s scratch
-    q_tile = group * hd * _F32
-    return kv_tiles + upcast + logits + scales + kpos + state + q_tile
-
-
-def verify_attention_vmem_bytes(block: Tuple[int], *, hd: int, kq: int,
-                                group: int, quantized: bool) -> int:
-    """Working-set model for the multi-token verify kernel: the flash-decode
-    model with the logit/weight tiles and softmax state widened from
-    ``group`` rows to the ``kq·group`` query rows scored per grid step (the
-    K/V tiles, scales and k_pos rows are shared across rows)."""
-    return decode_attention_vmem_bytes(block, hd=hd, group=kq * group,
-                                       quantized=quantized)
+    kv_tiles = 2 * 2 * bk * _plane_bytes(nkv, hd, elem)
+    upcast = bk * (_plane_bytes(nkv, hd, 2) + _plane_bytes(nkv, hd, _F32))
+    logits = 2 * nkv * _plane_bytes(group, bk, _F32)
+    scales = 2 * 2 * _plane_bytes(nkv, bk, _F32) if quantized else 0
+    kpos = 2 * _plane_bytes(1, bk, 4)
+    heads = kq * nkv
+    state = heads * (2 * _plane_bytes(group, 1, _F32)
+                     + _plane_bytes(group, hd, _F32))
+    q_out = 2 * heads * (_plane_bytes(group, hd, 2)
+                         + _plane_bytes(group, hd, _F32))
+    return kv_tiles + upcast + logits + scales + kpos + state + q_out
 
 
 def _tile_sizes(dim: int, quantum: int, ceiling: int) -> List[int]:
@@ -138,12 +146,14 @@ def _tile_sizes(dim: int, quantum: int, ceiling: int) -> List[int]:
 
 def matmul_candidates(m: int, k: int, n: int) -> List[Tuple[int, int, int]]:
     """(bm, bn, bk) candidates under the VMEM budget, MXU/f32-tile aligned
-    when the shape allows it."""
+    when the shape allows it.  The K tile must divide K (the kernel never
+    pads K) and, on the chip, be a multiple of 128 or all of K."""
     budget = VMEM_BUDGET_BYTES * _VMEM_USABLE_FRACTION
     cands = []
+    k_tiles = [t for t in _tile_sizes(k, _LANE, 1024) if k % t == 0] or [k]
     for bm in _tile_sizes(m, _SUBLANE * 4, 512):
         for bn in _tile_sizes(n, _LANE, 512):
-            for bk in _tile_sizes(k, _LANE, 1024):
+            for bk in k_tiles:
                 if matmul_vmem_bytes((bm, bn, bk)) <= budget:
                     cands.append((bm, bn, bk))
     return cands
@@ -159,7 +169,7 @@ def quantize_candidates(m: int, n: int) -> List[Tuple[int, int]]:
     ]
 
 
-def decode_attention_candidates(cap: int, *, hd: int, group: int,
+def decode_attention_candidates(cap: int, *, nkv: int, hd: int, group: int,
                                 quantized: bool) -> List[Tuple[int]]:
     """(bk,) cache-length tile candidates under the VMEM budget.  Lane-quantum
     multiples up to the cap; tiny caps (CPU-scale serving tests) fall back to
@@ -168,28 +178,14 @@ def decode_attention_candidates(cap: int, *, hd: int, group: int,
     cands = [
         (bk,)
         for bk in _tile_sizes(cap, _LANE, 4096)
-        if decode_attention_vmem_bytes((bk,), hd=hd, group=group,
+        if decode_attention_vmem_bytes((bk,), nkv=nkv, hd=hd, group=group,
                                        quantized=quantized) <= budget
     ]
     return cands or [(cap,)]
 
 
-def verify_attention_candidates(cap: int, *, hd: int, kq: int, group: int,
-                                quantized: bool) -> List[Tuple[int]]:
-    """(bk,) cache-length tile candidates for the verify kernel: the decode
-    candidate grid filtered through the widened ``kq·group``-row working
-    set, so deep drafts shrink the tile instead of blowing VMEM."""
-    budget = VMEM_BUDGET_BYTES * _VMEM_USABLE_FRACTION
-    cands = [
-        (bk,)
-        for bk in _tile_sizes(cap, _LANE, 4096)
-        if verify_attention_vmem_bytes((bk,), hd=hd, kq=kq, group=group,
-                                       quantized=quantized) <= budget
-    ]
-    return cands or [(cap,)]
-
-
-def paged_attention_candidates(max_len: int, *, hd: int, group: int,
+def paged_attention_candidates(max_len: int, *, nkv: int, hd: int,
+                               group: int,
                                quantized: bool) -> List[Tuple[int]]:
     """(bs,) pool-block-size candidates for the paged KV cache.
 
@@ -206,7 +202,7 @@ def paged_attention_candidates(max_len: int, *, hd: int, group: int,
         (bs,)
         for bs in _tile_sizes(max_len, _SUBLANE, 1024)
         if bs <= max_len
-        and decode_attention_vmem_bytes((bs,), hd=hd, group=group,
+        and decode_attention_vmem_bytes((bs,), nkv=nkv, hd=hd, group=group,
                                         quantized=quantized) <= budget
     ]
     return cands or [(max(1, max_len),)]
@@ -302,23 +298,17 @@ def best_block(kind: str, shape: tuple, dtype, bits: int, scheme: str,
         m, n = shape
         return max(quantize_candidates(m, n), key=lambda b: b[0] * b[1])
     if kind == "decode_attention":
-        _b, cap, _nkv, group, hd = shape
+        _b, cap, nkv, group, hd = shape
         cands = decode_attention_candidates(
-            cap, hd=hd, group=group, quantized="int8" in str(dtype))
+            cap, nkv=nkv, hd=hd, group=group, quantized="int8" in str(dtype))
         # largest tile = fewest sequential cache blocks per (slot, head);
         # length-aware skipping still prunes at this granularity
         return max(cands, key=lambda b: b[0])
-    if kind == "verify_attention":
-        _b, cap, _nkv, kq, group, hd = shape
-        cands = verify_attention_candidates(
-            cap, hd=hd, kq=kq, group=group, quantized="int8" in str(dtype))
-        # same pick rule as decode: largest tile = fewest sequential cache
-        # blocks per (slot, head); the per-row freeze still prunes reads
-        return max(cands, key=lambda b: b[0])
     if kind == "paged_attention":
-        _b, max_len, _nkv, group, hd = shape
+        _b, max_len, nkv, group, hd = shape
         cands = paged_attention_candidates(
-            max_len, hd=hd, group=group, quantized="int8" in str(dtype))
+            max_len, nkv=nkv, hd=hd, group=group,
+            quantized="int8" in str(dtype))
         # the pool block is also the allocation/prefix-sharing unit, so the
         # model pick balances kernel tile size against granularity: the
         # largest candidate that still gives a full-length request ≥ 4
@@ -399,24 +389,8 @@ def autotune_decode_attention(b: int, cap: int, nkv: int, group: int, hd: int,
     is the cache storage dtype ('int8' or 'bfloat16'); bits follow from it."""
     quantized = "int8" in str(dtype)
     cands = candidates or decode_attention_candidates(
-        cap, hd=hd, group=group, quantized=quantized)
+        cap, nkv=nkv, hd=hd, group=group, quantized=quantized)
     return _sweep("decode_attention", (b, cap, nkv, group, hd), dtype,
-                  8 if quantized else 16, "flash", backend, cands, run,
-                  repeats)
-
-
-def autotune_verify_attention(b: int, cap: int, nkv: int, kq: int,
-                              group: int, hd: int, *, backend: str,
-                              run: Callable[[tuple], object],
-                              dtype="int8", repeats: int = 2,
-                              candidates: Optional[List[tuple]] = None):
-    """Measured (bk,) sweep for the multi-token verify kernel.  ``kq`` (the
-    draft depth) is part of the key — the logit tile is kq·group rows, so
-    winners don't transfer across depths."""
-    quantized = "int8" in str(dtype)
-    cands = candidates or verify_attention_candidates(
-        cap, hd=hd, kq=kq, group=group, quantized=quantized)
-    return _sweep("verify_attention", (b, cap, nkv, kq, group, hd), dtype,
                   8 if quantized else 16, "flash", backend, cands, run,
                   repeats)
 
@@ -435,7 +409,7 @@ def autotune_paged_attention(b: int, max_len: int, nkv: int, group: int,
     up when no explicit ``block_size`` is given."""
     quantized = "int8" in str(dtype)
     cands = candidates or paged_attention_candidates(
-        max_len, hd=hd, group=group, quantized=quantized)
+        max_len, nkv=nkv, hd=hd, group=group, quantized=quantized)
     return _sweep("paged_attention", (b, max_len, nkv, group, hd), dtype,
                   8 if quantized else 16, "flash", backend, cands, run,
                   repeats)

@@ -274,6 +274,8 @@ def resolve_backend(name: Optional[str] = None) -> KernelBackend:
 
     Aliases: ``auto`` → pallas-tpu on TPU else the fast CPU reference;
     ``pallas`` → pallas-tpu on TPU else pallas-interpret; ``ref`` → xla-ref.
+    ``pallas-tpu`` named off-TPU (explicitly or through the environment)
+    raises here, before any kernel is traced.
     """
     if name is None or name == "auto":
         # 'auto' (and unset) defer to the environment before the platform
@@ -287,11 +289,18 @@ def resolve_backend(name: Optional[str] = None) -> KernelBackend:
     elif name == "ref":
         name = "xla-ref"
     try:
-        return _REGISTRY[name]
+        backend = _REGISTRY[name]
     except KeyError:
         raise ValueError(
             f"unknown kernel backend {name!r}; available: {available_backends()}"
         ) from None
+    if name == "pallas-tpu" and not _on_tpu():
+        raise RuntimeError(
+            "kernel backend 'pallas-tpu' compiles for a TPU, but JAX's "
+            f"default backend is {jax.default_backend()!r}; use "
+            "'pallas-interpret' (the same kernel bodies, interpreted) or "
+            "'xla-ref' off-TPU")
+    return backend
 
 
 def resolve_policy_backend(name: str) -> str:
@@ -440,19 +449,19 @@ def verify_attention(
     Query row t of slot b attends as if decoding at position ``pos[b]+t``
     (per-row causal mask and processed-block freeze), so accepted draft
     rows reproduce sequential decode's attention bitwise on the same tile.
-    Pallas backends autotune ``block=(bk,)`` under the kq·group-row working
-    set; xla-ref defaults to one whole-cap block — the same association
-    order as its one-token decode path, which is what the engine's
-    spec-decode stream-parity contract relies on off-TPU.
+    That tile is therefore the one ``decode_attention`` picks for this
+    cache, never one tuned for the verify shape: Pallas backends look up
+    the decode key, and xla-ref defaults to one whole-cap block, as its
+    decode path does (DESIGN.md §14).
     """
     be = resolve_backend(backend)
     if block is None and be.name.startswith("pallas"):
         b, cap, nkv, hd = k.shape
-        kq, group = q.shape[1], q.shape[3]
+        group = q.shape[3]
         bits = 8 if k.dtype == jnp.int8 else 16
-        block = autotune.best_block("verify_attention",
-                                    (b, cap, nkv, kq, group, hd),
-                                    str(k.dtype), bits, "flash", be.name)
+        block = autotune.best_block("decode_attention",
+                                    (b, cap, nkv, group, hd), str(k.dtype),
+                                    bits, "flash", be.name)
     return be.verify_attention(q, k, v, k_pos, pos, k_scale=k_scale,
                                v_scale=v_scale, window=window, block=block)
 

@@ -29,9 +29,6 @@ from repro.core import rounding
 
 __all__ = ["dither_matmul_kernel_call"]
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _quantize_tile(x, row0, col0, n_cols, *, scale, zero, bits, scheme, seed, n_pulses, fmt, counter):
     """Quantise one VMEM tile to codes (f32-valued integers, clipped)."""
@@ -175,12 +172,13 @@ def dither_matmul_kernel_call(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        name="dither_matmul",
         scratch_shapes=[
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, 1), jnp.float32),
             pltpu.VMEM((1, bn), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -17,6 +17,8 @@ from repro.kernels.quantize import quantize_kernel_call
 
 __all__ = ["quantize_2d", "dither_matmul", "on_tpu"]
 
+_LANE = 128  # TPU vector lane width: minor tile dims are multiples of it
+
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
@@ -83,17 +85,18 @@ def dither_matmul(
     slice the result — cross terms from padded K rows would bias the output
     when lo ≠ 0, so K padding pads A with a_lo-equivalent zeros AND masks by
     padding B's rows with b's zero-point.  To keep the kernel exact we
-    require K % bk == 0 after choosing bk = gcd-friendly block; the wrapper
-    shrinks bk to a divisor of K when needed.
+    require K % bk == 0: the wrapper shrinks bk to the largest multiple of
+    128 that divides K, or takes the whole of K when none does.
     """
     if interpret is None:
         interpret = not on_tpu()
     (m, k), (_, n) = a.shape, b.shape
     bm, bn, bk = block
-    # exact K handling: shrink bk to a divisor of K (no K padding ⇒ no bias)
-    bk = min(bk, k)
-    while k % bk:
-        bk -= 1
+    # exact K handling: a K tile that divides K (no K padding ⇒ no bias)
+    # and is lane-aligned, or else the whole of K — the A tile's minor dim
+    # must be a multiple of 128 or span the array on the chip
+    bk = next((t for t in range(min(bk, k) // _LANE * _LANE, 0, -_LANE)
+               if k % t == 0), k)
     ap = _pad2(a.astype(jnp.float32), bm, bk, value=a_range[0])
     bp = _pad2(b.astype(jnp.float32), bk, bn, value=b_range[0])
     counter = jnp.asarray(counter, jnp.int32).reshape(1, 1)

@@ -119,5 +119,6 @@ def quantize_kernel_call(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        name="dither_quantize",
         interpret=interpret,
     )(counter, x)

@@ -2,7 +2,10 @@
 
 Bit-exact references: the kernels and these oracles share the same integer
 hash / permutation / dither-bit math (repro.core.rounding), so tests assert
-exact equality of integer codes and tight allclose on float outputs.
+exact equality of integer codes and tight allclose on float outputs.  The
+attention oracles restate the kernels' online-softmax step op for op (they
+do not call the kernel module's step), so interpret-mode kernels match them
+bitwise while a slip in either copy shows up as a parity failure.
 """
 
 from __future__ import annotations
@@ -13,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import rounding
+from repro.kernels.decode_attention import shrink_block
 
 __all__ = [
     "quantize_codes_ref",
@@ -129,6 +133,79 @@ def dither_matmul_ref(
     return out
 
 
+def _flash_ref(q, k, v, tables, pos, k_scale, v_scale, k_pos, *,
+               window: int) -> jax.Array:
+    """The split-K online-softmax recurrence over a tiled cache, batched
+    over slots: a ``lax.scan`` over logical tiles j that gathers each
+    slot's physical tile ``tables[b, min(j, pos[b]//bk)]`` and updates the
+    running max / sum / value state.  k, v (n_tiles, bk, n_kv, hd);
+    k_scale, v_scale (n_tiles, bk, n_kv) or None; k_pos (n_tiles, bk) or
+    None for the pool's implicit positions ``j·bk + t``.
+
+    Per tile: int8→query-dtype upcast, an f32-accumulated dot batched over
+    slot and KV head, post-dot key-scale fold, -1e30 masking of unwritten,
+    future and out-of-window keys, then value scales on the unnormalised
+    weights — the Pallas kernel body's order, so the two agree bitwise
+    where both run on one backend."""
+    bsz, nkv, group, hd = q.shape
+    bk = k.shape[1]
+    inv = float(1.0 / math.sqrt(hd))
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (bsz,))
+    tables = jnp.asarray(tables, jnp.int32)
+    last = pos // bk
+    quantized = k_scale is not None
+
+    def step(carry, j):
+        m, s, acc = carry
+        jc = jnp.minimum(j, last)                          # clamped logical
+        phys = jnp.take_along_axis(tables, jc[:, None], axis=1)[:, 0]
+
+        def take(x):
+            return jnp.take(x, phys, axis=0)
+
+        logits = jax.lax.dot_general(
+            q, take(k).astype(q.dtype),
+            dimension_numbers=(((3,), (3,)), ((0, 1), (0, 2))),
+            preferred_element_type=jnp.float32,
+        ) * inv                                            # (B, n_kv, group, bk)
+        if quantized:
+            ks = take(k_scale).transpose(0, 2, 1)          # (B, n_kv, bk)
+            logits = logits * (ks[:, :, None, :] * (1.0 / 127.0))
+        kp = (take(k_pos) if k_pos is not None
+              else jc[:, None] * bk + jnp.arange(bk, dtype=jnp.int32)[None])
+        kp = kp[:, None, None, :]
+        pb = pos[:, None, None, None]
+        valid = (kp >= 0) & (kp <= pb)
+        if window:
+            valid = valid & (kp > pb - window)
+        logits = jnp.where(valid, logits, -1e30)
+
+        m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(logits - m_new)
+        s_new = s * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            vs = take(v_scale).transpose(0, 2, 1)
+            p = p * (vs[:, :, None, :] * (1.0 / 127.0))
+        acc_new = acc * alpha + jax.lax.dot_general(
+            p, take(v).astype(jnp.float32),
+            dimension_numbers=(((3,), (1,)), ((0, 1), (0, 2))),
+            preferred_element_type=jnp.float32,
+        )
+        act = (j <= last)[:, None, None, None]
+        return (jnp.where(act, m_new, m), jnp.where(act, s_new, s),
+                jnp.where(act, acc_new, acc)), None
+
+    init = (
+        jnp.full((bsz, nkv, group, 1), -jnp.inf, jnp.float32),
+        jnp.zeros((bsz, nkv, group, 1), jnp.float32),
+        jnp.zeros((bsz, nkv, group, hd), jnp.float32),
+    )
+    (m, s, acc), _ = jax.lax.scan(
+        step, init, jnp.arange(tables.shape[1], dtype=jnp.int32))
+    return acc / s
+
+
 def decode_attention_ref(
     q: jax.Array,        # (B, n_kv, group, hd) bf16/f32 — post-RoPE queries
     k: jax.Array,        # (B, cap, n_kv, hd) int8 codes or bf16
@@ -145,10 +222,9 @@ def decode_attention_ref(
 
     The dispatch-level contract for ``decode_attention`` is the *split-K
     online-softmax recurrence over cache-length blocks* — this function IS
-    that contract, in plain jnp: a ``lax.scan`` over cap/bk blocks whose
-    per-block ops (int8→query-dtype upcast, f32-accumulated dot, post-dot
-    scale folding, -1e30 masking, running max/sum/value state) mirror the
-    Pallas kernel body op-for-op, so ``pallas-interpret`` is bit-identical
+    that contract, in plain jnp: the ring is cut into cap/bk tiles (a pool
+    whose block table is ``b·n_tiles + j``) and ``_flash_ref`` runs the
+    kernel's step, restated, over them, so ``pallas-interpret`` is bit-identical
     to this oracle for the same ``block``.  Mathematically it equals the
     pre-kernel full-softmax einsum path (softmax over every valid slot);
     numerically it differs only by float-summation association — and it is
@@ -160,70 +236,19 @@ def decode_attention_ref(
     to a single masked softmax pass — the fast XLA path the serving engine
     uses off-TPU.
     """
-    # late import: the kernel module hosts shrink_block (both paths MUST
-    # shrink `block` to the same divisor of cap or the bit-parity contract
-    # silently breaks); it only depends on pallas at pallas_call time
-    from repro.kernels.decode_attention import shrink_block
-
-    bsz, cap, nkv, hd = k.shape
-    group = q.shape[2]
-    quantized = k_scale is not None
+    bsz, cap = k.shape[:2]
+    # both paths MUST shrink `block` to the same divisor of cap or the
+    # bit-parity contract silently breaks
     bk = shrink_block(cap if block is None else block[0], cap)
-    nb = cap // bk
-    inv = float(1.0 / math.sqrt(hd))
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (bsz,))
-    last = pos // bk
+    nt = cap // bk
 
-    def gather(x, start):
-        """Per-row (bk,)-long block of axis 1, starting at slot ``start``."""
-        return jax.vmap(
-            lambda xb, st: jax.lax.dynamic_slice_in_dim(xb, st, bk, axis=0)
-        )(x, start)
+    def tiles(x):
+        return None if x is None else x.reshape((bsz * nt, bk) + x.shape[2:])
 
-    def step(carry, j):
-        m, s, acc = carry
-        jc = jnp.minimum(j, last) * bk                     # clamped block start
-        kb = gather(k, jc)                                 # (B, bk, n_kv, hd)
-        vb = gather(v, jc)
-        kpb = gather(k_pos, jc)                            # (B, bk)
-        kc = kb.astype(q.dtype)
-        logits = jax.lax.dot_general(
-            q, kc, dimension_numbers=(((3,), (3,)), ((0, 1), (0, 2))),
-            preferred_element_type=jnp.float32,
-        ) * inv                                            # (B, n_kv, group, bk)
-        if quantized:
-            ksb = gather(k_scale, jc).transpose(0, 2, 1)   # (B, n_kv, bk)
-            logits = logits * (ksb[:, :, None, :] * (1.0 / 127.0))
-        kp = kpb[:, None, None, :]
-        pb = pos[:, None, None, None]
-        valid = (kp >= 0) & (kp <= pb)
-        if window:
-            valid = valid & (kp > pb - window)
-        logits = jnp.where(valid, logits, -1e30)
-
-        m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(logits - m_new)
-        s_new = s * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if quantized:
-            vsb = gather(v_scale, jc).transpose(0, 2, 1)
-            p = p * (vsb[:, :, None, :] * (1.0 / 127.0))
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p, vb.astype(jnp.float32),
-            dimension_numbers=(((3,), (1,)), ((0, 1), (0, 2))),
-            preferred_element_type=jnp.float32,
-        )
-        act = (j <= last)[:, None, None, None]
-        return (jnp.where(act, m_new, m), jnp.where(act, s_new, s),
-                jnp.where(act, acc_new, acc)), None
-
-    init = (
-        jnp.full((bsz, nkv, group, 1), -jnp.inf, jnp.float32),
-        jnp.zeros((bsz, nkv, group, 1), jnp.float32),
-        jnp.zeros((bsz, nkv, group, hd), jnp.float32),
-    )
-    (m, s, acc), _ = jax.lax.scan(step, init, jnp.arange(nb, dtype=jnp.int32))
-    return acc / s
+    return _flash_ref(
+        q, tiles(k), tiles(v),
+        jnp.arange(bsz * nt, dtype=jnp.int32).reshape(bsz, nt), pos,
+        tiles(k_scale), tiles(v_scale), tiles(k_pos), window=window)
 
 
 def paged_decode_attention_ref(
@@ -247,62 +272,8 @@ def paged_decode_attention_ref(
     the pool block size, so for bs == bk this is bit-identical to the ring
     recurrence on the same token stream — the reuse guarantee that makes
     prefix blocks shareable across requests (DESIGN.md §6)."""
-    nblk, bs, nkv, hd = k.shape
-    bsz = q.shape[0]
-    nbmax = block_tables.shape[1]
-    group = q.shape[2]
-    quantized = k_scale is not None
-    inv = float(1.0 / math.sqrt(hd))
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (bsz,))
-    block_tables = jnp.asarray(block_tables, jnp.int32)
-    last = pos // bs
-
-    def step(carry, j):
-        m, s, acc = carry
-        jc = jnp.minimum(j, last)                          # clamped logical
-        phys = jax.vmap(lambda bt, i: bt[i])(block_tables, jc)   # (B,)
-        kb = jnp.take(k, phys, axis=0)                     # (B, bs, n_kv, hd)
-        vb = jnp.take(v, phys, axis=0)
-        kc = kb.astype(q.dtype)
-        logits = jax.lax.dot_general(
-            q, kc, dimension_numbers=(((3,), (3,)), ((0, 1), (0, 2))),
-            preferred_element_type=jnp.float32,
-        ) * inv                                            # (B, n_kv, group, bs)
-        if quantized:
-            ksb = jnp.take(k_scale, phys, axis=0).transpose(0, 2, 1)
-            logits = logits * (ksb[:, :, None, :] * (1.0 / 127.0))
-        kp = (jc[:, None] * bs + jnp.arange(bs, dtype=jnp.int32)[None, :]
-              )[:, None, None, :]
-        pb = pos[:, None, None, None]
-        valid = kp <= pb
-        if window:
-            valid = valid & (kp > pb - window)
-        logits = jnp.where(valid, logits, -1e30)
-
-        m_new = jnp.maximum(m, jnp.max(logits, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(logits - m_new)
-        s_new = s * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if quantized:
-            vsb = jnp.take(v_scale, phys, axis=0).transpose(0, 2, 1)
-            p = p * (vsb[:, :, None, :] * (1.0 / 127.0))
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p, vb.astype(jnp.float32),
-            dimension_numbers=(((3,), (1,)), ((0, 1), (0, 2))),
-            preferred_element_type=jnp.float32,
-        )
-        act = (j <= last)[:, None, None, None]
-        return (jnp.where(act, m_new, m), jnp.where(act, s_new, s),
-                jnp.where(act, acc_new, acc)), None
-
-    init = (
-        jnp.full((bsz, nkv, group, 1), -jnp.inf, jnp.float32),
-        jnp.zeros((bsz, nkv, group, 1), jnp.float32),
-        jnp.zeros((bsz, nkv, group, hd), jnp.float32),
-    )
-    (m, s, acc), _ = jax.lax.scan(step, init,
-                                  jnp.arange(nbmax, dtype=jnp.int32))
-    return acc / s
+    return _flash_ref(q, k, v, block_tables, pos, k_scale, v_scale, None,
+                      window=window)
 
 
 def verify_attention_ref(

@@ -11,11 +11,7 @@ __all__ = ["make_production_mesh", "make_local_mesh", "make_serve_mesh",
 
 
 def _auto_kw(n):
-    """axis_types kwarg on jax versions that have AxisType; {} otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

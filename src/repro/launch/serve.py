@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.numerics.policy import QuantPolicy
 from repro.serve.engine import Engine, Request
@@ -116,6 +117,7 @@ def serve_main(argv=None):
                          "continue (bitwise for policy-free serving) "
                          "instead of submitting the synthetic workload")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

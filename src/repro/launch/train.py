@@ -20,6 +20,7 @@ from repro.configs import get_config
 from repro.data.pipeline import DataConfig, synthetic_batch
 from repro.dist import ctx
 from repro.dist.fault_tolerance import FailureInjector, StragglerWatchdog
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.numerics.policy import QuantPolicy
 from repro.optim.adamw import AdamW
@@ -111,6 +112,7 @@ def train_main(argv=None):
     ap.add_argument("--schedule", default="cosine", choices=["cosine", "wsd"])
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -1164,17 +1164,18 @@ def decode_step(
 
 def _attention_verify(params, cfg: ModelConfig, x, cache, pos, policy, counter,
                       kv_offset=None, alive=None, wcap=None,
-                      block_tables=None):
-    """k-token verify attention against the KV cache.  x: (B, K, d).
+                      block_tables=None, *, kq):
+    """k-token verify attention against the KV cache.  x: (B·K, 1, d),
+    slot-major: row b·K + t is slot b's draft row t.
 
     Row t scores draft position ``pos + t``; every op is the *row-pure*
     analogue of ``_attention_decode`` so row t is bitwise what a one-token
     decode at ``pos + t`` would compute (given the same inputs — the
-    bulk-commit contract, DESIGN.md §14).  The dense projections run fused
-    over (B, K, d) — XLA keeps plain matmuls row-pure across M — but the
-    attention dots go through the per-row verify kernels, and the dither
-    quantiser sees the same (value, position + offset, element index)
-    triples decode would.
+    bulk-commit contract, DESIGN.md §14).  The dense projections run on
+    the decode step's (rows, 1, d) shape with K× the rows, the attention
+    dots go through the per-row verify kernels, and the dither quantiser
+    sees the same (value, position + offset, element index) triples decode
+    would.
 
     All K draft positions are written up-front; the per-position causal mask
     (``k_pos``/implicit block positions ≤ query position) hides not-yet-
@@ -1185,7 +1186,7 @@ def _attention_verify(params, cfg: ModelConfig, x, cache, pos, policy, counter,
     or the paged trash block, so the verify forward never dirties cache
     state the commit cannot account for.
     """
-    b, kq = x.shape[0], x.shape[1]
+    b = x.shape[0] // kq
     hd, nh, nkv = cfg.hd(), cfg.n_heads, cfg.n_kv_heads
     paged = block_tables is not None
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
@@ -1275,23 +1276,25 @@ def _attention_verify(params, cfg: ModelConfig, x, cache, pos, policy, counter,
             v_scale=new_cache.get("v_scale"),
             window=cfg.window or 0,
         )
-    out = dist_ctx.gather_heads(attn.astype(x.dtype).reshape(b, kq, nh * hd))
+    out = dist_ctx.gather_heads(
+        attn.astype(x.dtype).reshape(b * kq, 1, nh * hd))
     return dense(out, params["wo"], policy, counter, seed=4), new_cache
 
 
 def _apply_verify_block(bp, cfg: ModelConfig, x, *, policy, counter,
                         cache_entry, pos, kv_offset, alive, wcap,
-                        block_tables):
-    """Verify-forward transformer block: attention-only archs (the
-    ``supports_spec_decode`` gate), so no SSM/RG-LRU branches.  MLP and
-    norms are row-pure as-is; MoE is excluded by the gate (its capacity
-    ranks cumsum over every token in the dispatch, so a verify row would
-    compete with its own future draft positions)."""
+                        block_tables, kq):
+    """Verify-forward transformer block over x (B·K, 1, d): attention-only
+    archs (the ``supports_spec_decode`` gate), so no SSM/RG-LRU branches.
+    MLP and norms see the decode step's (rows, 1, d) shape; MoE is excluded
+    by the gate (its capacity ranks cumsum over every token in the
+    dispatch, so a verify row would compete with its own future draft
+    positions)."""
     h = layers.rms_norm(x, bp["ln1"], cfg.norm_eps)
     out, new_cache = _attention_verify(bp["attn"], cfg, h, cache_entry, pos,
                                        policy, counter, kv_offset=kv_offset,
                                        alive=alive, wcap=wcap,
-                                       block_tables=block_tables)
+                                       block_tables=block_tables, kq=kq)
     x = x + out
     if "mlp" in bp or "moe" in bp:
         h2 = layers.rms_norm(x, bp["ln2"], cfg.norm_eps)
@@ -1323,11 +1326,17 @@ def verify_step(
     stream would have sampled (the accept condition the engine checks).
     ``cache["pos"]`` is *not* advanced: the caller commits the accepted
     prefix with ``spec_commit`` once accept lengths are known.
+
+    The forward runs on a (B·K, 1, d) view of the rows — the decode step's
+    own shape with K× the rows — not on (B, K, d): a TPU compiler lays a
+    (B, K, d) row reduction (RMSNorm's mean) out differently from a
+    (B, 1, d) one and sums its rows in another order, which breaks the
+    bitwise contract on the chip while every CPU test still passes.
     """
     pos = cache["pos"]
     block_tables = cache.get("block_tables")
-    x = jnp.take(params["embed"], tokens, axis=0)
-    b = x.shape[0]
+    b, kq = tokens.shape
+    x = jnp.take(params["embed"], tokens.reshape(b * kq, 1), axis=0)
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     p_ = _period(cfg)
 
@@ -1339,7 +1348,7 @@ def verify_step(
             h, ne = _apply_verify_block(
                 bp[pos_i], cfg, h, policy=policy, counter=counter,
                 cache_entry=ce[pos_i], pos=pos, kv_offset=kv_offset,
-                alive=alive, wcap=wcap, block_tables=block_tables,
+                alive=alive, wcap=wcap, block_tables=block_tables, kq=kq,
             )
             new_entries.append(ne)
         return h, tuple(new_entries)
@@ -1356,14 +1365,14 @@ def verify_step(
         x, ne = _apply_verify_block(
             bp, cfg, x, policy=policy, counter=counter,
             cache_entry=cache["remainder"][i], pos=pos, kv_offset=kv_offset,
-            alive=alive, wcap=wcap, block_tables=block_tables,
+            alive=alive, wcap=wcap, block_tables=block_tables, kq=kq,
         )
         new_rem.append(ne)
 
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = dense(x, head, policy, counter, seed=9).astype(jnp.float32)
-    logits = logits[..., : cfg.vocab_size]
+    logits = dense(x, head, policy, counter, seed=9).reshape(b, kq, -1)
+    logits = logits.astype(jnp.float32)[..., : cfg.vocab_size]
     new_cache = {
         "pos": pos,
         "layers": list(new_layer_caches),

@@ -59,6 +59,18 @@ __all__ = ["make_serve_fns", "make_decode_and_sample", "make_fused_decode",
            "Metrics", "Drafter", "PromptLookupDrafter"]
 
 
+def _jit(fn, **kw):
+    """``jax.jit`` for the engine's device programs.  On a TPU they compile
+    without excess precision: the compiler may otherwise keep a bf16 value
+    in f32 across the ops it fuses, and it fuses the verify forward, the
+    decode step and a sharded step differently, so the same row would round
+    differently in each and the bitwise contracts of DESIGN.md §9 and §14
+    would not hold there.  Off a TPU the option is left at its default."""
+    if jax.default_backend() == "tpu":
+        kw["compiler_options"] = {"xla_allow_excess_precision": False}
+    return jax.jit(fn, **kw)
+
+
 def make_serve_fns(cfg: ModelConfig, policy: Optional[QuantPolicy] = None, *,
                    max_len: int, kv_quant: bool = False, frames=None):
     """Build the two jit-able serving entry points (DESIGN.md §6).
@@ -578,8 +590,8 @@ class Engine:
         cfg_l = self._cfg_local
         prefill_step, decode_step = make_serve_fns(
             cfg_l, policy, max_len=max_len, kv_quant=kv_quant, frames=frames)
-        self._sample = jax.jit(sample_tokens)
-        self._merge = jax.jit(
+        self._sample = _jit(sample_tokens)
+        self._merge = _jit(
             lambda old, new, act: registry.merge_prefill(cfg, old, new, act),
             donate_argnums=(0,))
         self._paged_variants: dict = {}
@@ -590,13 +602,13 @@ class Engine:
         self._spec_variants: dict = {}
         self._commit_variants: dict = {}
         if mesh is None:
-            self._prefill = jax.jit(prefill_step)
+            self._prefill = _jit(prefill_step)
             if kv_layout == "paged":
-                self._prefill_paged = jax.jit(
+                self._prefill_paged = _jit(
                     make_paged_prefill(cfg_l, policy, kv_quant=kv_quant),
                     static_argnames=("prefix_blocks",), donate_argnums=(5,))
             elif self.prefill_chunk is not None:
-                self._prefill_chunked = jax.jit(
+                self._prefill_chunked = _jit(
                     make_chunked_prefill(cfg_l, policy, kv_quant=kv_quant),
                     donate_argnums=(4,))
         else:
@@ -611,7 +623,7 @@ class Engine:
             self._cspec = dist_sharding.cache_specs(self.cache, cfg, mesh)
             # the ring prefill's output cache mirrors the ring engine cache;
             # the paged engine prefills through _paged_prefill_call instead
-            self._prefill = (jax.jit(self._mesh_wrap(
+            self._prefill = (_jit(self._mesh_wrap(
                 prefill_step,
                 (self._pspec, tok2, row, row, sc),
                 (tok2, self._cspec))) if kv_layout == "ring" else None)
@@ -630,7 +642,7 @@ class Engine:
                                         self._cspec, row, sc)
                 self._out_specs_paged = (tok2, self._cspec)
             elif self.prefill_chunk is not None:
-                self._prefill_chunked = jax.jit(self._mesh_wrap(
+                self._prefill_chunked = _jit(self._mesh_wrap(
                     make_chunked_prefill(cfg_l, policy, kv_quant=kv_quant),
                     (self._pspec, tok2, row, row, self._cspec, row, sc),
                     (tok2, self._cspec)), donate_argnums=(4,))
@@ -683,8 +695,6 @@ class Engine:
         """Run ``fn`` per-shard under ``shard_map`` on the engine mesh, with
         the serve shard scope installed so model code maps its local batch
         rows / KV heads back to global coordinates (DESIGN.md §9)."""
-        from jax.experimental.shard_map import shard_map
-
         nkv_local = self._cfg_local.n_kv_heads
         heads_sharded = self.heads_sharded
 
@@ -695,8 +705,8 @@ class Engine:
                                             heads_sharded=heads_sharded):
                 return fn(*args)
 
-        return shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def _paged_prefill_call(self, *args, prefix_blocks: int):
         """Dispatch the paged prefill: the single-device engine keeps one
@@ -709,7 +719,7 @@ class Engine:
         if fn is None:
             base = make_paged_prefill(self._cfg_local, self.policy,
                                       kv_quant=self.kv_quant)
-            fn = jax.jit(self._mesh_wrap(
+            fn = _jit(self._mesh_wrap(
                 functools.partial(base, prefix_blocks=prefix_blocks),
                 self._in_specs_paged, self._out_specs_paged),
                 donate_argnums=(5,))
@@ -725,9 +735,9 @@ class Engine:
         if fn is None:
             base = make_fused_decode(self._cfg_local, self.policy, n_ticks=n)
             if self.mesh is None:
-                fn = jax.jit(base, donate_argnums=(2,))
+                fn = _jit(base, donate_argnums=(2,))
             else:
-                fn = jax.jit(self._mesh_wrap(base, self._in_specs_fused,
+                fn = _jit(self._mesh_wrap(base, self._in_specs_fused,
                                              self._out_specs_fused),
                              donate_argnums=(2,))
             self._fused_variants[n] = fn
@@ -740,9 +750,9 @@ class Engine:
         if fn is None:
             base = make_spec_verify(self._cfg_local, self.policy, draft_k=k)
             if self.mesh is None:
-                fn = jax.jit(base, donate_argnums=(2,))
+                fn = _jit(base, donate_argnums=(2,))
             else:
-                fn = jax.jit(self._mesh_wrap(base, self._in_specs_spec,
+                fn = _jit(self._mesh_wrap(base, self._in_specs_spec,
                                              self._out_specs_spec),
                              donate_argnums=(2,))
             self._spec_variants[k] = fn
@@ -755,9 +765,9 @@ class Engine:
         if fn is None:
             base = functools.partial(registry.spec_commit, draft_k=k)
             if self.mesh is None:
-                fn = jax.jit(base, donate_argnums=(0,))
+                fn = _jit(base, donate_argnums=(0,))
             else:
-                fn = jax.jit(self._mesh_wrap(base, self._in_specs_commit,
+                fn = _jit(self._mesh_wrap(base, self._in_specs_commit,
                                              self._cspec),
                              donate_argnums=(0,))
             self._commit_variants[k] = fn
@@ -2086,11 +2096,11 @@ class Engine:
             return new_cache
 
         if self.mesh is None:
-            fn = jax.jit(replay_step, donate_argnums=(2,))
+            fn = _jit(replay_step, donate_argnums=(2,))
         else:
             P = jax.sharding.PartitionSpec
             row, sc = P("data"), P()
-            fn = jax.jit(self._mesh_wrap(
+            fn = _jit(self._mesh_wrap(
                 replay_step,
                 (self._pspec, row, self._cspec, row, sc, row),
                 self._cspec), donate_argnums=(2,))

@@ -128,6 +128,60 @@ def test_oracle_matches_full_softmax_einsum(quantized, window):
                                rtol=2e-4, atol=2e-4)
 
 
+def _as_pool(x, bt, bs):
+    """Scatter an unwrapped ring array (B, cap, ...) into a paged pool
+    through block table ``bt``: logical block j of slot b lands at
+    physical block bt[b, j]."""
+    if x is None:
+        return None
+    b, cap = x.shape[:2]
+    blocks = x.reshape((b * (cap // bs), bs) + x.shape[2:])
+    order = np.argsort(np.asarray(bt).reshape(-1))
+    return blocks[order]
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("window", [0, 16], ids=["full", "window16"])
+@pytest.mark.parametrize("kind", ["paged-decode", "ring-verify",
+                                  "paged-verify"])
+def test_paged_and_verify_oracles_match_full_softmax_einsum(kind, quantized,
+                                                            window):
+    """Paged decode and each verify row (query at pos+t, keys up to pos+t)
+    equal the full-softmax einsum at that position up to float-summation
+    association — an independent check of the gather, mask, scale fold and
+    row position that the bitwise interpret ≡ xla-ref pins cannot give."""
+    kq, bs = 3, 16
+    # the cache is written through pos + kq - 1 and never wraps, so ring
+    # slot p holds position p and a pool block's implicit positions agree
+    q, k, v, k_pos, last, ks, vs = _ring_inputs(
+        17, quantized=quantized, pos_vals=(7, 42, 62))
+    pos = last - (kq - 1)
+    b, cap = k.shape[:2]
+    bt = jnp.asarray(np.random.default_rng(3).permutation(b * cap // bs)
+                     .reshape(b, cap // bs), jnp.int32)
+    pool = [_as_pool(x, bt, bs) for x in (k, v, ks, vs)]
+    qv = jnp.asarray(np.random.default_rng(5).normal(
+        size=(b, kq) + q.shape[1:]), jnp.bfloat16)
+    if kind == "paged-decode":
+        outs = [ref.paged_decode_attention_ref(q, *pool[:2], bt, last,
+                                               *pool[2:], window=window)]
+        qs, ps = [q], [last]
+    elif kind == "ring-verify":
+        out = ref.verify_attention_ref(qv, k, v, k_pos, pos, ks, vs,
+                                       window=window, block=(16,))
+        outs = [out[:, t] for t in range(kq)]
+        qs, ps = [qv[:, t] for t in range(kq)], [pos + t for t in range(kq)]
+    else:
+        out = ref.paged_verify_attention_ref(qv, *pool[:2], bt, pos,
+                                             *pool[2:], window=window)
+        outs = [out[:, t] for t in range(kq)]
+        qs, ps = [qv[:, t] for t in range(kq)], [pos + t for t in range(kq)]
+    for o, qt, pt in zip(outs, qs, ps):
+        base = _einsum_baseline(qt, k, v, k_pos, pt, ks, vs, window)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(base),
+                                   rtol=2e-4, atol=2e-4)
+
+
 def test_block_size_invariance_and_masked_slot_independence():
     """The recurrence result is block-size independent (to association
     noise), and slots hidden by the mask — unwritten, future, or outside

@@ -93,6 +93,19 @@ def test_resolve_platform_default_and_aliases():
         dispatch.resolve_backend("nonesuch")
 
 
+def test_explicit_pallas_tpu_needs_a_tpu(monkeypatch):
+    """Naming the compiled backend off-TPU fails at resolution with an
+    error that says so, not deep inside Mosaic lowering."""
+    if jax.default_backend() == "tpu":
+        assert dispatch.resolve_backend("pallas-tpu").name == "pallas-tpu"
+        return
+    with pytest.raises(RuntimeError, match="compiles for a TPU"):
+        dispatch.resolve_backend("pallas-tpu")
+    monkeypatch.setenv(dispatch.ENV_VAR, "pallas-tpu")
+    with pytest.raises(RuntimeError, match="compiles for a TPU"):
+        dispatch.quantize(jax.numpy.zeros((8, 128)), bits=8)
+
+
 def test_env_override(monkeypatch):
     monkeypatch.setenv(dispatch.ENV_VAR, "pallas-interpret")
     assert dispatch.resolve_backend().name == "pallas-interpret"
@@ -168,12 +181,12 @@ def test_paged_attention_candidates_and_model_pick():
     """(bs,) pool-block candidates stay under the VMEM budget; the model
     pick balances granularity — a full-length request spans ≥ 4 blocks
     whenever a candidate allows it, and tiny caps stay servable."""
-    cands = autotune.paged_attention_candidates(4096, hd=64, group=4,
+    cands = autotune.paged_attention_candidates(4096, nkv=8, hd=64, group=4,
                                                 quantized=True)
     assert cands
     for (bs,) in cands:
         assert autotune.decode_attention_vmem_bytes(
-            (bs,), hd=64, group=4, quantized=True) \
+            (bs,), nkv=8, hd=64, group=4, quantized=True) \
             <= autotune.VMEM_BUDGET_BYTES
     pick = autotune.best_block("paged_attention", (8, 4096, 8, 4, 64),
                                "int8", 8, "flash", "pallas-tpu")

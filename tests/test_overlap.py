@@ -66,7 +66,12 @@ def _mix(n=6):
     # split-softmax / prefix-join bf16 drift for every layout × kv_quant ×
     # chunk × decode_ticks combination below (conftest.assert_argmax_margin
     # philosophy — near-tie fixtures get reseeded, not worked around).
-    return [[(13 * r + i) % (CFG.vocab_size - 1) + 1
+    # Re-pinned from (13·r + i) under jax 0.9.0: the old mix's logits held
+    # exact bf16 top-2 ties (margin 0.0) where the chunked int8 streams
+    # parted from the whole-prompt ones (ring: request 0 token 3, request 4
+    # token 5, in the chunked path; paged: request 1 token 0, in the
+    # whole-prompt path).
+    return [[(17 * r + i + 2) % (CFG.vocab_size - 1) + 1
              for i in range(6 + 3 * r)] for r in range(n)]
 
 
@@ -172,8 +177,11 @@ def test_prompt_lengths_straddling_boundaries(kv_layout, chunk):
 def test_empty_prompt_and_oversized_chunk():
     """Empty prompts take the BOS substitution through the chunked path,
     and a chunk larger than max_len is clamped (ring) / the whole prompt
-    lands in one wave — both degenerate to whole-prompt prefill."""
-    prompts = [[], [5, 6, 7], []]
+    lands in one wave — both degenerate to whole-prompt prefill.  (The
+    middle prompt was [5, 6, 7] until jax 0.9.0, whose whole-prompt logits
+    put tokens 217 and 499 in an exact top-2 tie, margin 0.0, at that
+    request's second step.)"""
+    prompts = [[], [5, 6, 8], []]
     want, _ = _serve(prompts)
     for chunk in (2, 10 ** 6):
         got, _ = _serve(prompts, prefill_chunk=chunk)

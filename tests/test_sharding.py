@@ -9,15 +9,8 @@ from repro.configs import ARCH_IDS, get_config
 from repro.dist import sharding as shd
 from repro.models import registry
 
-def _abstract_mesh(shape, names):
-    try:
-        return AbstractMesh(shape, names)  # jax >= 0.5 signature
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, shape)))
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH_MP = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _axis_size(mesh, entry):
@@ -83,9 +76,9 @@ def test_attention_sharding_respects_head_counts():
 # ---------------------------------------------------------------------------
 
 SERVE_MESHES = {
-    (1, 1): _abstract_mesh((1, 1), ("data", "model")),
-    (2, 1): _abstract_mesh((2, 1), ("data", "model")),
-    (1, 2): _abstract_mesh((1, 2), ("data", "model")),
+    (1, 1): AbstractMesh((1, 1), ("data", "model")),
+    (2, 1): AbstractMesh((2, 1), ("data", "model")),
+    (1, 2): AbstractMesh((1, 2), ("data", "model")),
 }
 
 

@@ -211,11 +211,9 @@ def test_verify_interpret_bit_identical_to_xla_ref(quantized, window, group):
 def test_paged_verify_interpret_bit_identical_to_xla_ref(quantized, group):
     """Paged verify: the tile is the pool block on every backend, so
     interpret must match the oracle bit-for-bit (including junk rows in
-    partially-filled and out-of-table blocks, which masking hides).
-    group == 1 pins allclose-at-ulp instead, inheriting the one-token
-    paged kernel's documented GEMV-shape association caveat
-    (tests/test_paged_attention.py) — the verify body runs the exact same
-    per-row dot shapes, so it deviates exactly where decode does."""
+    partially-filled and out-of-table blocks, which masking hides), for
+    group == 1 too — both sides run the same head-batched dots
+    (tests/test_paged_attention.py)."""
     rng = np.random.default_rng(11 + group)
     b, bs, nbmax, nblocks, nkv, hd, kq = 2, 4, 6, 16, 2, 16, 3
     q = jnp.asarray(rng.normal(size=(b, kq, nkv, group, hd)), jnp.bfloat16)
@@ -240,11 +238,7 @@ def test_paged_verify_interpret_bit_identical_to_xla_ref(quantized, group):
     out_r = dispatch.paged_verify_attention(
         q, k, v, bt, pos, k_scale=ks, v_scale=vs, backend="xla-ref")
     assert out_i.dtype == jnp.float32
-    if group == 1:
-        np.testing.assert_allclose(np.asarray(out_i), np.asarray(out_r),
-                                   rtol=1e-6, atol=1e-7)
-    else:
-        assert jnp.array_equal(out_i, out_r), (quantized, group)
+    assert jnp.array_equal(out_i, out_r), (quantized, group)
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
