@@ -29,7 +29,6 @@ single-device stream (tests/test_sharded_serve.py).
 
 from __future__ import annotations
 
-import functools
 import json
 import os
 import time
@@ -591,9 +590,11 @@ class Engine:
         prefill_step, decode_step = make_serve_fns(
             cfg_l, policy, max_len=max_len, kv_quant=kv_quant, frames=frames)
         self._sample = _jit(sample_tokens)
-        self._merge = _jit(
-            lambda old, new, act: registry.merge_prefill(cfg, old, new, act),
-            donate_argnums=(0,))
+
+        def merge_prefill(old, new, act):
+            return registry.merge_prefill(cfg, old, new, act)
+
+        self._merge = _jit(merge_prefill, donate_argnums=(0,))
         self._paged_variants: dict = {}
         # windowed decode dispatches compile once per distinct window length
         # (decode_ticks plus any shorter drain tails) — see _fused_for
@@ -678,7 +679,8 @@ class Engine:
                         else Metrics(sink=metrics))
         # per-request tracing (DESIGN.md §13): span timelines + latency
         # attribution, host-timestamped only where the engine already syncs
-        # — zero extra device dispatches, disabled entirely by default.
+        # — zero extra device dispatches, off by default (the engine
+        # phases' profiler annotations, ``Tracer.span``, stay on).
         # Accepts a Tracer, a spec string ('mem', 'perfetto:<path>',
         # 'jsonl:<path>', comma-combinable), a sink object, or None (off).
         self.trace = Tracer.from_spec(trace)
@@ -694,7 +696,9 @@ class Engine:
     def _mesh_wrap(self, fn, in_specs, out_specs):
         """Run ``fn`` per-shard under ``shard_map`` on the engine mesh, with
         the serve shard scope installed so model code maps its local batch
-        rows / KV heads back to global coordinates (DESIGN.md §9)."""
+        rows / KV heads back to global coordinates (DESIGN.md §9).  The
+        wrapper carries ``fn``'s name, so the program compiles under the
+        same module name (``jit_fused_decode``, ...) as on one device."""
         nkv_local = self._cfg_local.n_kv_heads
         heads_sharded = self.heads_sharded
 
@@ -705,6 +709,7 @@ class Engine:
                                             heads_sharded=heads_sharded):
                 return fn(*args)
 
+        body.__name__ = body.__qualname__ = fn.__name__
         return jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)
 
@@ -719,10 +724,13 @@ class Engine:
         if fn is None:
             base = make_paged_prefill(self._cfg_local, self.policy,
                                       kv_quant=self.kv_quant)
-            fn = _jit(self._mesh_wrap(
-                functools.partial(base, prefix_blocks=prefix_blocks),
-                self._in_specs_paged, self._out_specs_paged),
-                donate_argnums=(5,))
+
+            def paged_prefill(*a):
+                return base(*a, prefix_blocks=prefix_blocks)
+
+            fn = _jit(self._mesh_wrap(paged_prefill, self._in_specs_paged,
+                                      self._out_specs_paged),
+                      donate_argnums=(5,))
             self._paged_variants[prefix_blocks] = fn
         return fn(*args)
 
@@ -763,13 +771,16 @@ class Engine:
         windows: ``fn(cache, new_pos, written) -> cache`` (cache donated)."""
         fn = self._commit_variants.get(k)
         if fn is None:
-            base = functools.partial(registry.spec_commit, draft_k=k)
+            def spec_commit(cache, new_pos, written):
+                return registry.spec_commit(cache, new_pos, written,
+                                            draft_k=k)
+
             if self.mesh is None:
-                fn = _jit(base, donate_argnums=(0,))
+                fn = _jit(spec_commit, donate_argnums=(0,))
             else:
-                fn = _jit(self._mesh_wrap(base, self._in_specs_commit,
-                                             self._cspec),
-                             donate_argnums=(0,))
+                fn = _jit(self._mesh_wrap(spec_commit, self._in_specs_commit,
+                                          self._cspec),
+                          donate_argnums=(0,))
             self._commit_variants[k] = fn
         return fn
 
@@ -848,29 +859,44 @@ class Engine:
         return self.trace.explain(rid, now=self._now())
 
     def step(self) -> List[Request]:
-        """One engine window: expire deadlines, admit + batched-prefill,
-        decode every active slot, observe the window wall time, persist a
-        snapshot.  Returns the requests still active after the window.
-        The five ``injector`` crash points fire in this order (keyed on
-        the tick at window start); a crashed engine's host state is
-        mid-mutation — discard it and restore a fresh engine from the
-        snapshot (``run_serve_with_restarts``)."""
-        self._step_tick = self.tick
-        t0 = self._now()
-        self._maybe_fail("pre_admit")
-        self._expire_deadlines()
-        self._update_pressure()
-        self._admit_and_prefill()
-        if any(s is not None for s in self.slots):
-            if self.spec_decode:
-                self._spec_decode_tick()
-            else:
-                self._decode_tick()
-        self._observe_window(self._now() - t0)
-        self._maybe_fail("sink_write")
-        self._record_tick_metrics()
-        self._maybe_fail("post_drain")
-        self._maybe_snapshot()
+        """One engine window: expire deadlines, admit, run one batched
+        prefill wave, decode every active slot, observe the window wall
+        time, persist a snapshot.  Returns the requests still active after
+        the window.  The five ``injector`` crash points fire in this order
+        (keyed on the tick at window start); a crashed engine's host state
+        is mid-mutation — discard it and restore a fresh engine from the
+        snapshot (``run_serve_with_restarts``).
+
+        Each phase runs inside a fixed-name ``Tracer.span`` under
+        ``engine.step`` (DESIGN.md §13): ``engine.admit``, then
+        ``engine.prefill.{prep,dispatch,wait,drain}``, the decode window's
+        ``engine.decode.*`` (or ``engine.spec.*``) and
+        ``engine.bookkeeping``; only the ``*.wait`` spans block on the
+        device."""
+        span = self.trace.span
+        active = sum(1 for s in self.slots if s is not None)
+        with span("engine.step", tick=self.tick, active=active) as whole:
+            self._step_tick = self.tick
+            with span("engine.admit"):
+                self._maybe_fail("pre_admit")
+                self._expire_deadlines()
+                self._update_pressure()
+                if self.kv_layout == "paged":
+                    self._admit_paged()
+                else:
+                    self._admit_ring()
+            self._prefill_wave()
+            if any(s is not None for s in self.slots):
+                if self.spec_decode:
+                    self._spec_decode_tick()
+                else:
+                    self._decode_tick()
+            with span("engine.bookkeeping"):
+                self._observe_window(time.time() - whole.t0)
+                self._maybe_fail("sink_write")
+                self._record_tick_metrics()
+                self._maybe_fail("post_drain")
+                self._maybe_snapshot()
         return [s for s in self.slots if s is not None]
 
     def run(self, ticks: int) -> List[Request]:
@@ -1039,153 +1065,116 @@ class Engine:
             }
             self._dev_dirty = False
 
-    def _admit_and_prefill(self):
-        if self.kv_layout == "paged":
-            return self._admit_and_prefill_paged()
-        if self.prefill_chunk is not None:
-            return self._admit_and_prefill_ring_chunked()
+    def _admit_ring(self):
+        """Admit queued requests into free ring slots in state
+        ``prefilling``; :meth:`_prefill_wave` then writes their prompts —
+        whole, or in ``prefill_chunk``-token chunks, one chunk wave per
+        engine step (Sarathi-style piggyback prefill, DESIGN.md §11), so a
+        long prompt never stalls running decodes for its full length."""
         free = [i for i, s in enumerate(self.slots) if s is None]
         if not free:
             return
-        admitted = []
+        now = time.time()
         for req in self.scheduler.admit(len(free)):
             if len(req.prompt) > self.max_len:
-                req.done, req.finish_reason, req.state = True, "rejected", "done"
-                self.finished.append(req)
-                self.metrics.inc("finished_requests")
-                self.metrics.inc("finish_rejected")
-                self.trace.finish(req.rid, self._now(), "rejected")
+                self._finish_queued(req, "rejected")
                 continue
-            admitted.append(req)
-        if not admitted:
-            return
-
-        now = time.time()
-        lens = np.zeros((self.batch,), np.int32)
-        prompts = {}
-        for req in admitted:
             i = free.pop(0)
-            sp = req.sampling
             self.slots[i] = req
-            req.state, req.t_admit = "active", now
+            req.state, req.t_admit = "prefilling", now
             self.trace.phase(req.rid, "prefill", now, slot=i)
-            prompts[i] = list(req.prompt) or [1]          # empty prompt → BOS
-            lens[i] = len(prompts[i])
-            self._temps[i] = sp.temperature
-            self._topks[i] = sp.top_k
-            self._seeds[i] = sp.seed
-            self._offsets[i] = sp.counter_offset
-            self._counters[i] = sp.counter_offset
-            self._slot_pos[i] = lens[i]
-
-        s_bucket = _bucket(int(lens.max()))
-        toks = np.zeros((self.batch, s_bucket), np.int32)
-        for i, p in prompts.items():
-            toks[i, : len(p)] = p
-
-        self._dev_dirty = True            # admission changed per-slot state
-        self._refresh_device_state()
-        t0 = time.time()
-        last_logits, pf_cache = self._prefill(
-            self.params, jnp.asarray(toks), jnp.asarray(lens),
-            self._dev["offsets"], self.tick)
-        self.cache = self._merge(self.cache, pf_cache,
-                                 jnp.asarray(lens > 0))
-        first = np.asarray(self._sample(
-            last_logits, self._dev["temps"], self._dev["topks"],
-            self._dev["seeds"], self._dev["counters"]))
-        dt = time.time() - t0
-        self.stats["prefill_s"] += dt
-        self.stats["prefill_tokens"] += int(lens.sum())
-        self.stats["prefill_calls"] += 1
-        self.trace.wave(
-            "prefill_wave", t0, t0 + dt,
-            [(self.slots[i].rid, "prefill[0]",
-              {"slot": i, "tokens": int(lens[i])}) for i in prompts],
-            tick=self._step_tick)
-
-        now = time.time()
-        for i, req in list(prompts.items()):
-            self._emit(i, self.slots[i], int(first[i]), now)
-        # _emit advanced host counters / last tokens for the admitted slots;
-        # re-sync the device copies before the first decode tick reads them
-        self._dev_dirty = True
-
-    def _admit_and_prefill_ring_chunked(self):
-        """Sarathi-style piggyback prefill on the ring engine (DESIGN.md
-        §11): admitted prompts enter in ``prefill_chunk``-token chunks, one
-        chunk wave per engine step, so a long prompt never stalls running
-        decodes for its full length.  Slots sit in state ``prefilling`` —
-        excluded from the decode window's alive mask — until their last
-        chunk lands, which also samples their first token.  Because the
-        dither KV codes key on absolute position (``starts + t``) and the
-        first sampled token on the prefill-final logits, the chunked stream
-        is the whole-prompt stream (tests/test_overlap.py)."""
-        chunk = self.prefill_chunk
-        free = [i for i, s in enumerate(self.slots) if s is None]
-        admitted_now = time.time()
-        for req in self.scheduler.admit(len(free)):
-            if len(req.prompt) > self.max_len:
-                req.done, req.finish_reason, req.state = True, "rejected", "done"
-                self.finished.append(req)
-                self.metrics.inc("finished_requests")
-                self.metrics.inc("finish_rejected")
-                self.trace.finish(req.rid, admitted_now, "rejected")
-                continue
-            i = free.pop(0)
-            self.slots[i] = req
-            req.state, req.t_admit = "prefilling", admitted_now
-            self.trace.phase(req.rid, "prefill", admitted_now, slot=i)
             req._pf_pos = 0
             self._set_slot_sampling(i, req)
             self._slot_pos[i] = 0
             self._dev_dirty = True
 
+    def _prefill_wave(self):
+        """One batched prefill wave over every ``prefilling`` slot
+        (DESIGN.md §6, §11).  Each slot writes its next ``prefill_chunk``
+        tokens from ``_pf_pos`` (all of them with ``prefill_chunk=None``);
+        a slot whose last chunk lands samples its first token from the
+        wave's logits and turns ``active``.  The ring writes a whole prompt
+        through ``prefill_step`` and merges it into the live cache, and a
+        chunk through the chunked prefill, which joins the slot's ring
+        history inside attention; the paged prefill writes into the pool
+        blocks named by the block table.  Paged chunks are block-aligned,
+        so every continuation chunk rides the prefix-hit path — earlier
+        chunks' K/V is gathered from the slot's own blocks.  Because the
+        dither KV codes key on absolute position (``starts + t``) and the
+        first token on the prefill-final logits, the chunked stream is the
+        whole-prompt stream (tests/test_overlap.py)."""
         waving = [(i, s) for i, s in enumerate(self.slots)
                   if s is not None and s.state == "prefilling"]
         if not waving:
             return
-        lens = np.zeros((self.batch,), np.int32)
-        starts = np.zeros((self.batch,), np.int32)
-        pieces = {}
-        for i, req in waving:
-            prompt = list(req.prompt) or [1]          # empty prompt → BOS
-            pieces[i] = prompt[req._pf_pos:req._pf_pos + chunk]
-            lens[i] = len(pieces[i])
-            starts[i] = req._pf_pos
-
-        s_bucket = _bucket(int(lens.max()))
-        toks = np.zeros((self.batch, s_bucket), np.int32)
-        for i, p in pieces.items():
-            toks[i, : len(p)] = p
-
-        self._dev_dirty = True
-        self._refresh_device_state()
-        t0 = time.time()
-        last_logits, self.cache = self._prefill_chunked(
-            self.params, jnp.asarray(toks), jnp.asarray(lens),
-            jnp.asarray(starts), self.cache, self._dev["offsets"], self.tick)
-        first = np.asarray(self._sample(
-            last_logits, self._dev["temps"], self._dev["topks"],
-            self._dev["seeds"], self._dev["counters"]))
-        dt = time.time() - t0
-        self.stats["prefill_s"] += dt
-        self.stats["prefill_tokens"] += int(lens.sum())
-        self.stats["prefill_calls"] += 1
-        self.trace.wave(
-            "prefill_wave", t0, t0 + dt,
-            [(req.rid, f"prefill[{int(starts[i]) // chunk}]",
-              {"slot": i, "tokens": int(lens[i])}) for i, req in waving],
-            tick=self._step_tick)
-
-        now = time.time()
-        for i, req in waving:
-            req._pf_pos += len(pieces[i])
-            self._slot_pos[i] = req._pf_pos
-            if req._pf_pos >= len(list(req.prompt) or [1]):
-                req.state = "active"
-                self._emit(i, req, int(first[i]), now)
-        self._dev_dirty = True
+        span = self.trace.span
+        paged = self.kv_layout == "paged"
+        chunk = self.prefill_chunk or (self.max_len + 1)
+        with span("engine.prefill.prep"):
+            lens = np.zeros((self.batch,), np.int32)
+            starts = np.zeros((self.batch,), np.int32)
+            pieces = {}
+            for i, req in waving:
+                pf = req._pf_pos
+                pieces[i] = self._tokens_written(req)[pf:pf + chunk]
+                lens[i] = len(pieces[i])
+                starts[i] = pf
+            toks = np.zeros((self.batch, _bucket(int(lens.max()))), np.int32)
+            for i, p in pieces.items():
+                toks[i, : len(p)] = p
+            self._dev_dirty = True
+            self._refresh_device_state()
+            toks_d, lens_d = jnp.asarray(toks), jnp.asarray(lens)
+            # what the layout's prefill takes besides tokens and lengths
+            if paged:
+                layout_args = (jnp.asarray(starts), jnp.asarray(self._bt))
+                self._bt_dirty = False
+            elif self.prefill_chunk is not None:
+                layout_args = (jnp.asarray(starts),)
+            else:
+                layout_args = (jnp.asarray(lens > 0),)      # merge mask
+        dev = self._dev
+        with span("engine.prefill.dispatch") as dispatch:
+            if paged:
+                logits, self.cache = self._paged_prefill_call(
+                    self.params, toks_d, lens_d, *layout_args, self.cache,
+                    dev["offsets"], self.tick,
+                    prefix_blocks=self.nbmax if starts.any() else 0)
+            elif self.prefill_chunk is not None:
+                logits, self.cache = self._prefill_chunked(
+                    self.params, toks_d, lens_d, *layout_args, self.cache,
+                    dev["offsets"], self.tick)
+            else:
+                logits, fresh = self._prefill(
+                    self.params, toks_d, lens_d, dev["offsets"], self.tick)
+                self.cache = self._merge(self.cache, fresh, *layout_args)
+            first_dev = self._sample(logits, dev["temps"], dev["topks"],
+                                     dev["seeds"], dev["counters"])
+        with span("engine.prefill.wait") as wait:
+            first = np.asarray(first_dev)
+        with span("engine.prefill.drain"):
+            self.stats["prefill_s"] += wait.t1 - dispatch.t0
+            self.stats["prefill_tokens"] += int(lens.sum())
+            self.stats["prefill_calls"] += 1
+            self.trace.wave(
+                "prefill_wave", dispatch.t0, wait.t1,
+                [(req.rid, f"prefill[{int(starts[i]) // chunk}]",
+                  {"slot": i, "tokens": int(lens[i])}) for i, req in waving],
+                tick=self._step_tick)
+            # the prefill dispatch is ordered before any later gather, so
+            # the chunk's full blocks are now safely publishable for hits
+            now = time.time()
+            for i, req in waving:
+                req._pf_pos += len(pieces[i])
+                self._slot_pos[i] = req._pf_pos
+                if paged:
+                    self._seal_full_blocks(req, req._pf_pos)
+                if req._pf_pos >= len(self._tokens_written(req)):
+                    req.state = "active"
+                    self._emit(i, req, int(first[i]), now)
+            # _emit advanced host counters / last tokens for these slots;
+            # re-sync the device copies before the decode window reads them
+            self._dev_dirty = True
 
     # ----------------------------------------------------- paged internals
 
@@ -1303,7 +1292,7 @@ class Engine:
             pool.seal_block(req.rid, j, seq[j * bs:(j + 1) * bs])
             req._sealed += 1
 
-    def _admit_and_prefill_paged(self):
+    def _admit_paged(self):
         """Continuous-batching admission (DESIGN.md §6/§9): admit while a
         slot *and* that slot's data-shard pool allow — prefix-hit requests
         only need blocks (and prefill compute) for their unshared suffix;
@@ -1312,12 +1301,10 @@ class Engine:
         with the longest cached prefix, then the most free blocks.
         Head-of-line order is preserved: the first request no eligible
         shard can serve stops admission (after the deadlock breaker below
-        has had its chance)."""
+        has had its chance).  Admitted requests wait in ``prefilling`` for
+        :meth:`_prefill_wave`."""
         free = [i for i, s in enumerate(self.slots) if s is None]
         if not free:
-            # no admission this step, but half-prefilled slots still push
-            # their next chunk (DESIGN.md §11)
-            self._prefill_wave_paged()
             return
         bs = self.block_size
         free_by_shard: dict = {}
@@ -1365,12 +1352,7 @@ class Engine:
                 # a reprefill-resumed request whose grown history no longer
                 # fits was *served* up to the pool's capacity — that is a
                 # 'length' stop, not a rejection of an unserved request
-                reason = "length" if req.out else "rejected"
-                req.done, req.finish_reason, req.state = True, reason, "done"
-                self.finished.append(req)
-                self.metrics.inc("finished_requests")
-                self.metrics.inc(f"finish_{reason}")
-                self.trace.finish(req.rid, self._now(), reason)
+                self._finish_queued(req, "length" if req.out else "rejected")
                 continue
             seed = req.sampling.counter_offset if self.kv_quant else 0
             # rank eligible shards: longest cached prefix first, then most
@@ -1423,74 +1405,6 @@ class Engine:
             self._slot_pos[i] = start
             self._set_bt_row(i, self._pool_of(req.rid).table(req.rid))
             self.stats["prefix_hit_tokens"] += start
-
-        self._prefill_wave_paged()
-
-    def _prefill_wave_paged(self):
-        """One chunked-prefill wave over every ``prefilling`` paged slot
-        (DESIGN.md §11).  ``prefill_chunk`` is block-aligned, so every
-        continuation chunk starts at a block boundary and rides the
-        prefix-hit path of the paged prefill — earlier chunks' K/V is
-        gathered from the slot's own pool blocks inside attention.  With
-        ``prefill_chunk=None`` the whole suffix lands in one wave (the
-        pre-overlap behaviour).  A slot's last chunk samples its first
-        token and flips it ``active``."""
-        chunk = self.prefill_chunk or (self.max_len + 1)
-        waving = [(i, s) for i, s in enumerate(self.slots)
-                  if s is not None and s.state == "prefilling"]
-        if not waving:
-            return
-        lens = np.zeros((self.batch,), np.int32)
-        starts = np.zeros((self.batch,), np.int32)
-        pieces = {}
-        any_prefix = False
-        for i, req in waving:
-            seq = self._tokens_written(req)
-            pf = req._pf_pos
-            pieces[i] = seq[pf:pf + chunk]
-            lens[i] = len(pieces[i])
-            starts[i] = pf
-            any_prefix = any_prefix or pf > 0
-
-        s_bucket = _bucket(int(lens.max()))
-        toks = np.zeros((self.batch, s_bucket), np.int32)
-        for i, p in pieces.items():
-            toks[i, : len(p)] = p
-
-        self._dev_dirty = True
-        self._refresh_device_state()
-        bt_dev = jnp.asarray(self._bt)
-        self._bt_dirty = False
-        t0 = time.time()
-        last_logits, self.cache = self._paged_prefill_call(
-            self.params, jnp.asarray(toks), jnp.asarray(lens),
-            jnp.asarray(starts), bt_dev, self.cache,
-            self._dev["offsets"], self.tick,
-            prefix_blocks=self.nbmax if any_prefix else 0)
-        first = np.asarray(self._sample(
-            last_logits, self._dev["temps"], self._dev["topks"],
-            self._dev["seeds"], self._dev["counters"]))
-        dt = time.time() - t0
-        self.stats["prefill_s"] += dt
-        self.stats["prefill_tokens"] += int(lens.sum())
-        self.stats["prefill_calls"] += 1
-        self.trace.wave(
-            "prefill_wave", t0, t0 + dt,
-            [(req.rid, f"prefill[{int(starts[i]) // chunk}]",
-              {"slot": i, "tokens": int(lens[i])}) for i, req in waving],
-            tick=self._step_tick)
-
-        # the prefill dispatch is ordered before any later gather, so the
-        # chunk's full blocks are now safely publishable for prefix hits
-        now = time.time()
-        for i, req in waving:
-            req._pf_pos += len(pieces[i])
-            self._slot_pos[i] = req._pf_pos
-            self._seal_full_blocks(req, req._pf_pos)
-            if req._pf_pos >= len(self._tokens_written(req)):
-                req.state = "active"
-                self._emit(i, req, int(first[i]), now)
-        self._dev_dirty = True
 
     def _break_deadlock(self, head: Request, blocks_short: int,
                         shard: int = 0) -> bool:
@@ -1610,6 +1524,30 @@ class Engine:
             jax.tree.map(lambda a: a.at[dst].set(a[src]), e)
             for e in self.cache["remainder"]]
 
+    def _window_budgets(self, n: int):
+        """The active slots and their per-slot window budgets (max_new /
+        max_len / paged block coverage, at most ``n``), with the ``alive``
+        mask the window's dispatch takes."""
+        active = [(i, s) for i, s in enumerate(self.slots)
+                  if s is not None and s.state == "active"]
+        alive = np.zeros((self.batch,), bool)
+        budgets = np.zeros((self.batch,), np.int32)
+        for i, req in active:
+            b = min(n, req.effective_max_new() - len(req.out),
+                    self.max_len - int(self._slot_pos[i]))
+            if self.kv_layout == "paged":
+                b = min(b, self._paged_cap[i])
+            alive[i] = True
+            budgets[i] = b
+        return active, alive, budgets
+
+    @staticmethod
+    def _stop_set(req: Request) -> set:
+        ss = set(req.sampling.stop_set())
+        if req.sampling.eos_id is not None:
+            ss.add(req.sampling.eos_id)
+        return ss
+
     def _decode_tick(self):
         """One decode *window*: ``decode_ticks`` fused scan ticks in a
         single device dispatch, then one host drain of the (n, B) token
@@ -1618,88 +1556,67 @@ class Engine:
         arrays so finish detection never syncs mid-window; the drain walks
         each slot's column up to its first stop hit and re-runs the exact
         per-token finish logic of the one-tick engine (``_emit``)."""
+        span = self.trace.span
         n = self._window_ticks()
-        self._paged_cap = {}
-        if self.kv_layout == "paged":
-            self._pre_decode_paged()
-            self._sync_block_tables()
-        active = [(i, s) for i, s in enumerate(self.slots)
-                  if s is not None and s.state == "active"]
-        if not active:
-            return
-        alive = np.zeros((self.batch,), bool)
-        budgets = np.zeros((self.batch,), np.int32)
-        stop_sets = {}
-        wmax = 1
-        for i, req in active:
-            b = min(n, req.effective_max_new() - len(req.out),
-                    self.max_len - int(self._slot_pos[i]))
+        with span("engine.decode.prep"):
+            self._paged_cap = {}
             if self.kv_layout == "paged":
-                b = min(b, self._paged_cap[i])
-            alive[i] = True
-            budgets[i] = b
-            ss = set(req.sampling.stop_set())
-            if req.sampling.eos_id is not None:
-                ss.add(req.sampling.eos_id)
-            stop_sets[i] = ss
-            wmax = max(wmax, len(ss))
-        # bucket the stop-set width so the (B, W) stops array compiles per
-        # power-of-two width, not per distinct stop-set size
-        W = 1
-        while W < wmax:
-            W *= 2
-        stops = np.full((self.batch, W), -1, np.int32)   # -1 never sampled
-        for i, ss in stop_sets.items():
-            for j, t in enumerate(sorted(ss)):
-                stops[i, j] = t
-
-        self._refresh_device_state()
-        t0 = time.time()
-        toks_dev, last_dev, counters_dev, self.cache = self._fused_for(n)(
-            self.params, self._dev["last_token"], self.cache,
-            self._dev["offsets"], self.tick,
-            self._dev["temps"], self._dev["topks"], self._dev["seeds"],
-            self._dev["counters"], jnp.asarray(alive),
-            jnp.asarray(budgets), jnp.asarray(stops))
-        toks = np.asarray(toks_dev)           # (n, B) — the window drain
-        # crash point between the device window and the host drain: the
-        # window's tokens are lost with the process, never half-emitted
-        self._maybe_fail("mid_window")
-        dt = time.time() - t0
-        # the fused window advanced counters and produced the next input
-        # token on device — keep those copies resident (no re-upload next
-        # window; dead rows froze, matching the host mirrors below)
-        self._dev["counters"] = counters_dev
-        self._dev["last_token"] = last_dev
-        self.tick += n
-        self.stats["decode_s"] += dt
-        self.stats["decode_calls"] += 1
-
-        now = time.time()
-        kept = {}
-        for i, req in active:
-            col = toks[:, i]
-            ss = stop_sets[i]
-            m = int(budgets[i])               # tokens this slot really kept
-            for j in range(m):
-                if int(col[j]) in ss:
-                    m = j + 1
-                    break
-            kept[i] = m
-            # windowed-drain ITL attribution: m tokens arrived over one
-            # host drain interval — attribute the per-token inter-arrival
-            # as interval/m instead of one m-sized observation per drain
-            t_prev = req.t_last if req.t_last is not None else now
-            share = (now - t_prev) / m
-            for j in range(m):
-                self._slot_pos[i] += 1
-                self._emit(i, req, int(col[j]), t_prev + share * (j + 1))
-            self.stats["decode_tokens"] += m
-        self.trace.wave(
-            "decode_window", t0, t0 + dt,
-            [(req.rid, f"decode[w{self._step_tick}]",
-              {"slot": i, "tokens": kept[i]}) for i, req in active],
-            tick=self._step_tick, n_ticks=n)
+                self._pre_decode_paged()
+                self._sync_block_tables()
+            active, alive, budgets = self._window_budgets(n)
+            if not active:
+                return
+            stop_sets = {i: self._stop_set(req) for i, req in active}
+            # bucket the stop-set width so the (B, W) stops array compiles
+            # per power-of-two width, not per distinct stop-set size
+            wmax = max(1, max(len(ss) for ss in stop_sets.values()))
+            W = 1
+            while W < wmax:
+                W *= 2
+            stops = np.full((self.batch, W), -1, np.int32)  # -1 never sampled
+            for i, ss in stop_sets.items():
+                for j, t in enumerate(sorted(ss)):
+                    stops[i, j] = t
+            self._refresh_device_state()
+            window_args = (jnp.asarray(alive), jnp.asarray(budgets),
+                           jnp.asarray(stops))
+        dev = self._dev
+        with span("engine.decode.dispatch", n_ticks=n, W=W) as dispatch:
+            toks_dev, last_dev, counters_dev, self.cache = self._fused_for(n)(
+                self.params, dev["last_token"], self.cache, dev["offsets"],
+                self.tick, dev["temps"], dev["topks"], dev["seeds"],
+                dev["counters"], *window_args)
+        with span("engine.decode.wait") as wait:
+            toks = np.asarray(toks_dev)           # (n, B) — the window drain
+        with span("engine.decode.drain"):
+            # crash point between the device window and the host drain: the
+            # window's tokens are lost with the process, never half-emitted
+            self._maybe_fail("mid_window")
+            # the fused window advanced counters and produced the next input
+            # token on device — keep those copies resident (no re-upload next
+            # window; dead rows froze, matching the host mirrors below)
+            self._dev["counters"] = counters_dev
+            self._dev["last_token"] = last_dev
+            self.tick += n
+            self.stats["decode_s"] += wait.t1 - dispatch.t0
+            self.stats["decode_calls"] += 1
+            now = time.time()
+            kept = {}
+            for i, req in active:
+                col = toks[:, i]
+                ss = stop_sets[i]
+                m = int(budgets[i])               # tokens this slot really kept
+                for j in range(m):
+                    if int(col[j]) in ss:
+                        m = j + 1
+                        break
+                kept[i] = m
+                self._emit_window(i, req, col[:m], now)
+            self.trace.wave(
+                "decode_window", dispatch.t0, wait.t1,
+                [(req.rid, f"decode[w{self._step_tick}]",
+                  {"slot": i, "tokens": kept[i]}) for i, req in active],
+                tick=self._step_tick, n_ticks=n)
 
     def _spec_decode_tick(self):
         """One speculative window (DESIGN.md §14): draft ``draft_k - 1``
@@ -1716,124 +1633,123 @@ class Engine:
         in the same commit dispatch (scrub to never-written bytes); paged
         slots then return surplus draft-coverage blocks via
         ``KVPool.truncate``, leaving pool state as if never drafted."""
+        span = self.trace.span
         K = self.draft_k
-        self._paged_cap = {}
-        if self.kv_layout == "paged":
-            self._pre_decode_paged(window=K)
-            self._sync_block_tables()
-        active = [(i, s) for i, s in enumerate(self.slots)
-                  if s is not None and s.state == "active"]
-        if not active:
-            return
-        td0 = time.time()
-        alive = np.zeros((self.batch,), bool)
-        budgets = np.zeros((self.batch,), np.int32)
-        drafts = np.zeros((self.batch, K), np.int32)
-        n_drafted = {}
-        for i, req in active:
-            b = min(K, req.effective_max_new() - len(req.out),
-                    self.max_len - int(self._slot_pos[i]))
+        with span("engine.decode.prep"):
+            self._paged_cap = {}
             if self.kv_layout == "paged":
-                b = min(b, self._paged_cap[i])
-            alive[i] = True
-            budgets[i] = b
-            drafts[i, 0] = self._last_token[i]
-            prop = self.drafter.propose(list(req.prompt) + req.out, K - 1)
-            nd = min(len(prop), K - 1)
-            if nd:
-                drafts[i, 1:1 + nd] = prop[:nd]
-            n_drafted[i] = nd
-        td1 = time.time()
+                self._pre_decode_paged(window=K)
+                self._sync_block_tables()
+            active, alive, budgets = self._window_budgets(K)
+            if not active:
+                return
+            self._refresh_device_state()
+            alive_d, budgets_d = jnp.asarray(alive), jnp.asarray(budgets)
+        with span("engine.spec.draft") as draft:
+            drafts = np.zeros((self.batch, K), np.int32)
+            n_drafted = {}
+            for i, req in active:
+                drafts[i, 0] = self._last_token[i]
+                prop = self.drafter.propose(list(req.prompt) + req.out, K - 1)
+                nd = min(len(prop), K - 1)
+                if nd:
+                    drafts[i, 1:1 + nd] = prop[:nd]
+                n_drafted[i] = nd
+            drafts_d = jnp.asarray(drafts)
+        dev = self._dev
+        with span("engine.spec.verify.dispatch", n_ticks=K) as verify:
+            toks_dev, self.cache = self._spec_for(K)(
+                self.params, drafts_d, self.cache, dev["offsets"], self.tick,
+                dev["temps"], dev["topks"], dev["seeds"], dev["counters"],
+                alive_d, budgets_d)
+        with span("engine.spec.verify.wait") as wait:
+            toks = np.asarray(toks_dev)               # (B, K) sampled per row
+        with span("engine.spec.accept") as accept_span:
+            self._maybe_fail("mid_window")
+            self.tick += 1
+            self.stats["decode_s"] += wait.t1 - verify.t0
+            self.stats["decode_calls"] += 1
+            # host accept walk: row t committed iff its *input* draft matched
+            # row t-1's sampled token (= what sequential decode would have
+            # fed), cut at the first stop/EOS hit like the plain window drain
+            accept = {}
+            for i, req in active:
+                b = int(budgets[i])
+                m = 1
+                while m < b and drafts[i, m] == toks[i, m - 1]:
+                    m += 1
+                ss = self._stop_set(req)
+                for j in range(m):
+                    if int(toks[i, j]) in ss:
+                        m = j + 1
+                        break
+                accept[i] = m
+        with span("engine.spec.commit") as commit:
+            # bulk commit + rejected-suffix scrub in one dispatch, against
+            # the *pre-truncation* block tables (the scrub needs the draft
+            # mapping)
+            new_pos = np.asarray(self._slot_pos, np.int32)
+            for i, _ in active:
+                new_pos[i] += accept[i]
+            self.cache = self._spec_commit_for(K)(
+                self.cache, jnp.asarray(new_pos), budgets_d)
+            if self.kv_layout == "paged":
+                bs = self.block_size
+                # reverse slot order, truncate returning newest-first: the
+                # appends to each pool's free list exactly mirror the pop
+                # order of this window's draft-coverage allocation across
+                # *all* slots, so the free list (order included) is restored
+                # to its never-drafted state — the pool-state parity the
+                # rollback tests pin (DESIGN.md §14)
+                for i, req in reversed(active):
+                    pool = self.pools[self._slot_shard(i)]
+                    keep = max(1, -(-int(new_pos[i]) // bs))
+                    have = len(pool.table(req.rid))
+                    if have > keep:
+                        pool.truncate(req.rid, keep)
+                        self._bt[i, keep:have] = self._trash
+                        self._bt_dirty = True
+        with span("engine.decode.drain"):
+            now = time.time()
+            for i, req in active:
+                m = accept[i]
+                self._emit_window(i, req, toks[i, :m], now)
+                self.metrics.inc("spec_draft_tokens", int(budgets[i]) - 1)
+                self.metrics.inc("spec_accepted_tokens", m - 1)
+                self.metrics.inc("spec_emitted_tokens", m)
+            self.metrics.inc("spec_windows")
+            # the device sampling counters ran ahead (all K rows drew); the
+            # host mirrors advanced by the accept length in _emit — re-upload
+            self._dev_dirty = True
+            self.trace.wave(
+                "spec_draft", draft.t0, draft.t1,
+                [(req.rid, f"draft[w{self._step_tick}]",
+                  {"slot": i, "drafted": n_drafted[i]}) for i, req in active],
+                tick=self._step_tick)
+            self.trace.wave(
+                "spec_verify", verify.t0, wait.t1,
+                [(req.rid, f"verify[w{self._step_tick}]",
+                  {"slot": i, "k": K, "budget": int(budgets[i])})
+                 for i, req in active],
+                tick=self._step_tick, n_ticks=K)
+            self.trace.wave(
+                "spec_commit", accept_span.t0, commit.t1,
+                [(req.rid, f"commit[w{self._step_tick}]",
+                  {"slot": i, "accepted": accept[i]}) for i, req in active],
+                tick=self._step_tick)
 
-        self._refresh_device_state()
-        t0 = time.time()
-        toks_dev, self.cache = self._spec_for(K)(
-            self.params, jnp.asarray(drafts), self.cache,
-            self._dev["offsets"], self.tick,
-            self._dev["temps"], self._dev["topks"], self._dev["seeds"],
-            self._dev["counters"], jnp.asarray(alive), jnp.asarray(budgets))
-        toks = np.asarray(toks_dev)               # (B, K) sampled per row
-        self._maybe_fail("mid_window")
-        dt = time.time() - t0
-        self.tick += 1
-        self.stats["decode_s"] += dt
-        self.stats["decode_calls"] += 1
-
-        # host accept walk: row t committed iff its *input* draft matched
-        # row t-1's sampled token (= what sequential decode would have fed),
-        # cut at the first stop/EOS hit like the plain window drain
-        tc0 = time.time()
-        accept = {}
-        for i, req in active:
-            b = int(budgets[i])
-            m = 1
-            while m < b and drafts[i, m] == toks[i, m - 1]:
-                m += 1
-            ss = set(req.sampling.stop_set())
-            if req.sampling.eos_id is not None:
-                ss.add(req.sampling.eos_id)
-            for j in range(m):
-                if int(toks[i, j]) in ss:
-                    m = j + 1
-                    break
-            accept[i] = m
-        # bulk commit + rejected-suffix scrub in one dispatch, against the
-        # *pre-truncation* block tables (the scrub needs the draft mapping)
-        new_pos = np.asarray(self._slot_pos, np.int32)
-        for i, _ in active:
-            new_pos[i] += accept[i]
-        self.cache = self._spec_commit_for(K)(
-            self.cache, jnp.asarray(new_pos), jnp.asarray(budgets))
-        if self.kv_layout == "paged":
-            bs = self.block_size
-            # reverse slot order, truncate returning newest-first: the
-            # appends to each pool's free list exactly mirror the pop order
-            # of this window's draft-coverage allocation across *all* slots,
-            # so the free list (order included) is restored to its
-            # never-drafted state — the pool-state parity the rollback
-            # tests pin (DESIGN.md §14)
-            for i, req in reversed(active):
-                pool = self.pools[self._slot_shard(i)]
-                keep = max(1, -(-int(new_pos[i]) // bs))
-                have = len(pool.table(req.rid))
-                if have > keep:
-                    pool.truncate(req.rid, keep)
-                    self._bt[i, keep:have] = self._trash
-                    self._bt_dirty = True
-        tc1 = time.time()
-
-        now = time.time()
-        for i, req in active:
-            m = accept[i]
-            t_prev = req.t_last if req.t_last is not None else now
-            share = (now - t_prev) / m
-            for j in range(m):
-                self._slot_pos[i] += 1
-                self._emit(i, req, int(toks[i, j]), t_prev + share * (j + 1))
-            self.stats["decode_tokens"] += m
-            self.metrics.inc("spec_draft_tokens", int(budgets[i]) - 1)
-            self.metrics.inc("spec_accepted_tokens", m - 1)
-            self.metrics.inc("spec_emitted_tokens", m)
-        self.metrics.inc("spec_windows")
-        # the device sampling counters ran ahead (all K rows drew); the
-        # host mirrors advanced by the accept length in _emit — re-upload
-        self._dev_dirty = True
-        self.trace.wave(
-            "spec_draft", td0, td1,
-            [(req.rid, f"draft[w{self._step_tick}]",
-              {"slot": i, "drafted": n_drafted[i]}) for i, req in active],
-            tick=self._step_tick)
-        self.trace.wave(
-            "spec_verify", t0, t0 + dt,
-            [(req.rid, f"verify[w{self._step_tick}]",
-              {"slot": i, "k": K, "budget": int(budgets[i])})
-             for i, req in active],
-            tick=self._step_tick, n_ticks=K)
-        self.trace.wave(
-            "spec_commit", tc0, tc1,
-            [(req.rid, f"commit[w{self._step_tick}]",
-              {"slot": i, "accepted": accept[i]}) for i, req in active],
-            tick=self._step_tick)
+    def _emit_window(self, i: int, req: Request, toks, now: float):
+        """Emit the ``m`` tokens a window kept for slot ``i``.  They arrived
+        over one host drain interval, so each is stamped at an equal share
+        of it (ITL = interval/m per token instead of one m-sized
+        observation per drain)."""
+        m = len(toks)
+        t_prev = req.t_last if req.t_last is not None else now
+        share = (now - t_prev) / m
+        for j in range(m):
+            self._slot_pos[i] += 1
+            self._emit(i, req, int(toks[j]), t_prev + share * (j + 1))
+        self.stats["decode_tokens"] += m
 
     def _emit(self, i: int, req: Request, tok: int, now: float):
         req.out.append(tok)

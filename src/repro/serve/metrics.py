@@ -320,10 +320,6 @@ class Metrics:
     def sink_errors(self) -> int:
         return self._sb.sink_errors
 
-    @property
-    def flush_every(self) -> int:
-        return self._sb.flush_every
-
     # -- lifecycle
 
     def reset(self) -> None:
@@ -350,7 +346,7 @@ class Metrics:
     def observe_itl(self, seconds: float) -> None:
         """One observation **per completed token**, not per host drain: a
         windowed-decode engine draining m tokens at once must attribute
-        drain_interval / m to each (the engine's _decode_tick does exactly
+        drain_interval / m to each (the engine's _emit_window does exactly
         that), so the itl histogram's count matches the token count at any
         decode_ticks setting (tests/test_metrics.py)."""
         self.itl_s.record(seconds)
@@ -365,16 +361,6 @@ class Metrics:
                 self._gauge_n[k] = self._gauge_n.get(k, 0) + 1
                 self._gauge_last[k] = v
         self.ticks += 1
-        self._sb.add(rec)
-
-    def event(self, kind: str, **fields) -> None:
-        """Buffer one out-of-band event record for the sink (same stream as
-        the tick records, distinguished by an ``event`` key).  Since PR 9
-        the engine's lifecycle events (degraded/restored/slow_window)
-        travel on the tracer's feed instead (DESIGN.md §13); this remains
-        for ad-hoc callers that want events interleaved with gauges."""
-        rec = {"t": time.time(), "event": kind}
-        rec.update(fields)
         self._sb.add(rec)
 
     # -- sink plumbing

@@ -6,13 +6,22 @@ points where the engine already synchronises with the device (submit,
 admission, prefill waves, window drains, preempt/resume, finish), so enabling
 it adds **zero device dispatches** and cannot perturb the token stream.
 
+Engine phases (``engine.step`` and its children, ``engine.admit`` …
+``engine.bookkeeping``) go through :meth:`Tracer.span`, which always opens a
+``jax.profiler.TraceAnnotation``: under a ``jax.profiler`` capture the phases
+land on the host thread of the trace, on the same clock as the device's
+operations, whether or not the tracer is enabled.  Every span also goes to
+the process's flight recorder (:func:`recent_spans`), stamped on
+``time.perf_counter_ns()``, so code in the same process can read the last
+phases after a capture without parsing it.
+
 Three export surfaces share one record stream:
 
 - a streaming jsonl event feed through the crash-isolated sink machinery
   from :mod:`repro.serve.metrics` (``SinkBuffer``),
 - a Chrome-trace/Perfetto JSON export (``perfetto()`` / ``write_perfetto()``)
   with per-request tracks (pid 1, one thread per rid) and engine tracks
-  (pid 0: waves, counters, degradation instants),
+  (pid 0: engine phases, waves, counters, degradation instants),
 - ``explain(rid)`` — a latency-attribution report decomposing a request's
   wall time into queue / prefill / decode / preempt_stall / degraded /
   recovery shares that sum to 100% by construction.
@@ -27,18 +36,30 @@ timelines stay continuous (and still sum to 100%) across snapshot/restore.
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import time
 
+from jax.profiler import TraceAnnotation
+
 from .metrics import SinkBuffer, make_sink
 
-__all__ = ["Tracer", "format_explain"]
+__all__ = ["Tracer", "format_explain", "recent_spans"]
 
-# Lifecycle phases a request moves through.  ``queued`` covers both initial
-# queue wait and requeued wait after a preempt-stall; ``recovery`` only
-# appears on timelines that crossed a snapshot/restore.
-PHASES = ("queued", "prefill", "decode", "preempt_stall", "recovery")
+# The flight recorder: ``(name, start_ns, end_ns)`` of the process's last
+# engine phases on ``time.perf_counter_ns()``, appended as each closes.
+_RECENT = collections.deque(maxlen=1 << 16)
+
+
+def recent_spans():
+    """The last engine phases of this process (at most 65,536), in the
+    order they closed (a child before its parent): ``(name, start_ns,
+    end_ns)`` on ``time.perf_counter_ns()``, from every engine, tracer on or
+    off.  Joined to a ``jax.profiler`` capture through an annotation
+    stamped on both clocks, they give the capture's host phases in process
+    (the benchmark's host-loop metrics read them so)."""
+    return list(_RECENT)
 
 # explain() buckets.  ``queued`` reports as ``queue``; prefill/decode
 # segments overlapping a degradation interval report as ``degraded``.
@@ -84,12 +105,41 @@ class _ReqTrace:
         return tr
 
 
+class _Span:
+    """One :meth:`Tracer.span`: a profiler annotation, two stamps on each of
+    ``time.time()`` and ``time.perf_counter_ns()``, a flight-recorder entry
+    and, on an enabled tracer, one engine-track record."""
+
+    __slots__ = ("_tracer", "name", "args", "_ann", "t0", "t1", "_ns")
+
+    def __init__(self, tracer, name, args):
+        self._tracer, self.name, self.args = tracer, name, args
+        self._ann = TraceAnnotation(name, **args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._ns = time.perf_counter_ns()
+        self.t0 = time.time()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.time()
+        _RECENT.append((self.name, self._ns, time.perf_counter_ns()))
+        self._ann.__exit__(*exc)
+        if self._tracer.enabled:
+            self._tracer._rec({"kind": "span", "cat": "engine",
+                               "name": self.name, "rid": None,
+                               "t0": self.t0, "t1": self.t1, **self.args})
+        return False
+
+
 class Tracer:
     """Span/event tracer for the serving engine (DESIGN.md §13).
 
     Construct via :meth:`from_spec` (what ``Engine(trace=...)`` and the
     ``--trace`` flag do).  A disabled tracer (``enabled=False``) turns every
-    method into an early-return no-op so the untraced hot path stays free.
+    method into an early-return no-op so the untraced hot path stays free,
+    except :meth:`span`, which keeps its profiler annotation and stamps.
     """
 
     def __init__(self, sink=None, perfetto_path=None, *, enabled=True,
@@ -249,6 +299,18 @@ class Tracer:
                 self._open(tr, phase, t, tags)
 
     # ------------------------------------------------- engine-side feeds --
+    def span(self, name, **args):
+        """Context manager timing one engine phase (DESIGN.md §13).
+
+        It always opens ``jax.profiler.TraceAnnotation(name, **args)``,
+        about 1 µs when no profiler runs; under a capture the phase lands on
+        the trace's host thread beside the device operations.  An enabled
+        tracer also records it as an engine-track span (``cat: "engine"``);
+        every tracer puts it in the flight recorder (:func:`recent_spans`).
+        The returned object carries ``t0``/``t1`` (``time.time()``), which
+        the engine's wave records and ``stats`` timings read."""
+        return _Span(self, name, args)
+
     def event(self, name, t=None, rid=None, **fields):
         """Instant event (degraded/restored/slow_window/shed/pool provenance/...)."""
         if not self.enabled:
@@ -335,9 +397,6 @@ class Tracer:
                 for p, a, b, d in segs
             ],
         }
-
-    def request_ids(self):
-        return list(self._reqs)
 
     # ------------------------------------------------ snapshot / restore --
     def snapshot(self, t=None):

@@ -177,3 +177,60 @@ def test_paged_pool_partitioned_per_shard():
     assert len(done) == 4
     assert {eng._slot_shard(i) for i in range(4)} == {0, 1}
     assert eng.pool_stats()["live"] == 0       # all released on finish
+
+
+# ------------------------------------------------------- program names
+
+
+PROGRAM_CASES = {
+    # engine settings -> the programs its serving and restore paths compile
+    "paged": (dict(kv_layout="paged", block_size=8, decode_ticks=2,
+                   prefill_chunk=8, kv_quant=True),
+              {"fused_decode", "paged_prefill", "sample_tokens", "replay_step"}),
+    "ring-spec": (dict(spec_decode=True, draft_k=3),
+                  {"prefill_step", "merge_prefill", "sample_tokens",
+                   "spec_verify", "spec_commit"}),
+    "ring-chunked": (dict(decode_ticks=2, prefill_chunk=4),
+                     {"chunked_prefill", "fused_decode", "sample_tokens"}),
+}
+
+
+@pytest.mark.parametrize("shape", [None, (1, 1), pytest.param((2, 2), marks=needs4)],
+                         ids=["one-device", "mesh1x1", "mesh2x2"])
+@pytest.mark.parametrize("case", sorted(PROGRAM_CASES))
+def test_programs_compile_under_their_role_names(case, shape, monkeypatch):
+    """Every program the engine compiles lowers to ``jit_<role>`` on one
+    device and under a mesh alike, so a profiler trace's ``XLA Modules``
+    line names it (the benchmark finds ``fused_decode`` and
+    ``paged_prefill`` there by name)."""
+    import re
+
+    import repro.serve.engine as engine_mod
+
+    names, real = set(), engine_mod._jit
+
+    def spy(fn, **kw):
+        jitted, seen = real(fn, **kw), []
+
+        def call(*a, **k):
+            if not seen:
+                seen.append(True)
+                text = jitted.lower(*a, **k).as_text()
+                names.add(re.match(r"module @(\w+)", text).group(1))
+            return jitted(*a, **k)
+
+        return call
+
+    monkeypatch.setattr(engine_mod, "_jit", spy)
+    kw, roles = PROGRAM_CASES[case]
+    mesh = make_serve_mesh(*shape) if shape else None
+    make = lambda: Engine(_params(CFG), CFG, batch=2, max_len=32, mesh=mesh, **kw)
+    eng = make()
+    for r in range(2):
+        eng.submit(Request(rid=r, prompt=[3 + r, 5, 7, 9, 11],
+                           sampling=SamplingParams(max_new=5, seed=r)))
+    eng.step()
+    if "replay_step" in roles:                  # slots hold generated tokens
+        make().restore(eng.snapshot())
+    eng.run(8)
+    assert names == {f"jit_{role}" for role in roles}

@@ -15,12 +15,20 @@ The load-bearing pins:
 * **crash continuity** — timelines carried through snapshot/restore stay
   contiguous: spans open at the crash close with a recovery marker and a
   ``recovery`` segment bridges crash → resume.
+* **engine phases on the profiler's clock** — ``Engine.step``'s
+  ``engine.*`` spans reach a ``jax.profiler`` capture with or without an
+  enabled tracer, nest under one ``engine.step`` each, and name the
+  device-blocking calls ``*.wait`` and nothing else.
 """
 
+import glob
 import itertools
 import json
+import os
+import time
 
 import jax
+import numpy as np
 import pytest
 
 from repro.configs import get_config
@@ -29,7 +37,7 @@ from repro.kernels import autotune
 from repro.models import registry
 from repro.serve import (Engine, JsonlSink, NullSink, Request, SamplingParams,
                          Tracer, format_explain)
-from repro.serve.trace import CATEGORIES
+from repro.serve.trace import CATEGORIES, recent_spans
 
 CFG = get_config("smollm_135m").reduced()
 PARAMS = registry.init_model(jax.random.PRNGKey(0), CFG)
@@ -411,3 +419,142 @@ def test_dropped_tracer_unregisters_from_autotune():
     del tr
     assert ref() is None and all(o is not None
                                  for o in autotune._OBSERVERS)
+
+
+# ------------------------------------------------- engine phase spans
+
+
+WAITS = {"engine.prefill.wait", "engine.decode.wait", "engine.spec.verify.wait"}
+PHASE_CONFIGS = {
+    "paged": dict(kv_layout="paged", block_size=8, decode_ticks=2,
+                  prefill_chunk=8, kv_quant=True),
+    "ring": dict(decode_ticks=2),
+    "spec": dict(kv_layout="paged", block_size=8, spec_decode=True,
+                 draft_k=3),
+}
+
+
+class _BlockSpy:
+    """``numpy`` for the engine module that marks every device-to-host copy
+    with a ``test.block`` annotation on the profiler's clock."""
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    @staticmethod
+    def asarray(x, *a, **k):
+        if isinstance(x, jax.Array):
+            with jax.profiler.TraceAnnotation("test.block"):
+                return np.asarray(x, *a, **k)
+        return np.asarray(x, *a, **k)
+
+
+def _host_events(trace_dir):
+    """(name, start, end) of every engine span and blocking-copy mark in
+    the newest xplane under ``trace_dir``."""
+    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True), key=os.path.getmtime)[-1]
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("engine.", "test.block")):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns))
+    return out
+
+
+def _serve_steps(eng):
+    for k in range(4):
+        eng.submit(_request(k, key=k, temperature=0.8, max_new=6))
+    steps = 0
+    while len(eng.scheduler) or any(s is not None for s in eng.slots):
+        eng.step()
+        steps += 1
+    return steps, {r.rid: (list(r.out), r.finish_reason) for r in eng.finished}
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+@pytest.mark.parametrize("name", sorted(PHASE_CONFIGS))
+def test_engine_phases_land_on_the_profiler_clock(name, tmp_path, monkeypatch):
+    """Under ``jax.profiler`` an untraced engine writes one ``engine.step``
+    per step, every other ``engine.*`` span nests inside one, the ``.wait``
+    spans are exactly its blocking device-to-host copies, and the token
+    streams are bitwise those of a run without the profiler."""
+    import repro.serve.engine as engine_mod
+
+    kw = dict(batch=2, max_len=MAX_LEN, **PHASE_CONFIGS[name])
+    _, want = _serve_steps(Engine(PARAMS, CFG, **kw))
+
+    eng = Engine(PARAMS, CFG, **kw)
+    monkeypatch.setattr(engine_mod, "np", _BlockSpy())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        steps, got = _serve_steps(eng)
+    finally:
+        jax.profiler.stop_trace()
+    assert got == want
+
+    events = _host_events(str(tmp_path))
+    whole = [e for e in events if e[0] == "engine.step"]
+    parts = [e for e in events if e[0].startswith("engine.") and e not in whole]
+    blocks = [e for e in events if e[0] == "test.block"]
+    assert len(whole) == steps and parts and blocks
+    assert all(sum(_inside(p, w) for w in whole) == 1 for p in parts)
+    waits = [p for p in parts if p[0].endswith(".wait")]
+    assert {w[0] for w in waits} <= WAITS
+    assert all(sum(_inside(b, w) for w in waits) == 1 for b in blocks)
+    assert all(sum(_inside(b, w) for b in blocks) == 1 for w in waits)
+    names = {p[0] for p in parts}
+    assert {"engine.admit", "engine.bookkeeping", "engine.prefill.wait",
+            "engine.decode.prep", "engine.decode.drain"} <= names
+    assert ("engine.spec.verify.wait" in names) == (name == "spec")
+
+
+def test_enabled_tracer_records_engine_spans():
+    """An enabled tracer also writes the phases as engine-track records,
+    and the wave records and ``stats`` timings read the same dispatch and
+    wait spans: each phase is timed in one place."""
+    eng = Engine(PARAMS, CFG, batch=2, max_len=MAX_LEN, decode_ticks=2,
+                 trace="mem")
+    steps, _ = _serve_steps(eng)
+    recs = eng.trace.records()
+    spans = [r for r in recs if r.get("cat") == "engine"]
+    assert sum(r["name"] == "engine.step" for r in spans) == steps
+    assert all(r["rid"] is None and r["t1"] >= r["t0"] for r in spans)
+    assert all("tick" in r and "active" in r for r in spans
+               if r["name"] == "engine.step")
+    waves = {name: [r for r in recs if r.get("cat") == "wave"
+                    and r["name"] == name and r["rid"] is None]
+             for name in ("prefill_wave", "decode_window")}
+    for wave, stat, phase in (("prefill_wave", "prefill_s", "prefill"),
+                              ("decode_window", "decode_s", "decode")):
+        assert len(waves[wave]) == eng.stats[stat.replace("_s", "_calls")]
+        assert sum(r["t1"] - r["t0"] for r in waves[wave]) == \
+            pytest.approx(eng.stats[stat])
+        dispatch = [r for r in spans if r["name"] == f"engine.{phase}.dispatch"]
+        wait = [r for r in spans if r["name"] == f"engine.{phase}.wait"]
+        assert [(r["t0"], r["t1"]) for r in waves[wave]] == \
+            [(d["t0"], w["t1"]) for d, w in zip(dispatch, wait)]
+    perfetto = eng.trace.perfetto()["traceEvents"]
+    assert any(e["ph"] == "X" and e["pid"] == 0 and e["cat"] == "engine"
+               for e in perfetto)
+
+
+def test_flight_recorder_keeps_the_phases_of_an_untraced_engine():
+    """Every phase also goes to the process's flight recorder on
+    ``perf_counter_ns``, tracer off: one ``engine.step`` per step, each
+    other phase inside one, in the order they closed."""
+    start = time.perf_counter_ns()
+    steps, _ = _serve_steps(Engine(PARAMS, CFG, batch=2, max_len=MAX_LEN,
+                                   decode_ticks=2))
+    spans = [s for s in recent_spans() if s[1] >= start]
+    whole = [s for s in spans if s[0] == "engine.step"]
+    assert len(whole) == steps
+    assert all(s[0].startswith("engine.") and s[1] <= s[2] for s in spans)
+    assert all(sum(w[1] <= p[1] and p[2] <= w[2] for w in whole) == 1
+               for p in spans if p not in whole)
+    assert [s[2] for s in spans] == sorted(s[2] for s in spans)
