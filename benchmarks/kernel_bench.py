@@ -89,7 +89,10 @@ def _ring_cache(rng, b, cap, nkv, hd, pos_frac=0.75):
     v = jnp.asarray(rng.integers(-127, 128, size=(b, cap, nkv, hd)), jnp.int8)
     ks = jnp.asarray(rng.uniform(0.1, 2.0, size=(b, cap, nkv)), jnp.float32)
     vs = jnp.asarray(rng.uniform(0.1, 2.0, size=(b, cap, nkv)), jnp.float32)
-    return (q, k, v, jnp.asarray(kpos, jnp.int32),
+    # stored as the engine stores the ring (heads-major, positions minor;
+    # transformer.kv_shapes), as a layer-stacked cache of one layer
+    k, v, ks, vs = (jnp.moveaxis(x, 1, -1)[None] for x in (k, v, ks, vs))
+    return (q, k, v, jnp.asarray(kpos, jnp.int32)[None],
             jnp.full((b,), pos_val, jnp.int32), ks, vs), pos_val
 
 
@@ -114,7 +117,11 @@ def decode_attn_bytes_per_token(cap, nkv, hd, *, pos, bk, quantized=True,
 @jax.jit
 def _einsum_decode_baseline(q, k, v, k_pos, pos, ks, vs):
     """The retired pre-PR-3 decode path: upcast the entire int8 ring cache
-    to fp, full (cap,) logits + softmax, scales folded outside the kernel."""
+    to fp, full (cap,) logits + softmax, scales folded outside the kernel
+    (on the stored positions-minor stack of one layer, read
+    position-major)."""
+    k, v, ks, vs = (jnp.moveaxis(x[0], -1, 1) for x in (k, v, ks, vs))
+    k_pos = k_pos[0]
     b, cap, nkv, hd = k.shape
     x_dtype = q.dtype
     logits = jnp.einsum("bhgd,bkhd->bhgk", q,
@@ -137,7 +144,7 @@ def sweep_decode_attention(caps, backends=None, do_autotune: bool = False):
     for cap in caps:
         (q, k, v, k_pos, pos, ks, vs), pos_val = _ring_cache(rng, b, cap, nkv, hd)
         ref_out = ref.decode_attention_ref(q, k, v, k_pos, pos, ks, vs,
-                                           block=(16,))
+                                           layer=0, block=(16,))
         base_us = _time_call(lambda: _einsum_decode_baseline(
             q, k, v, k_pos, pos, ks, vs))
         base_bytes = decode_attn_bytes_per_token(cap, nkv, hd, pos=pos_val,
@@ -148,11 +155,11 @@ def sweep_decode_attention(caps, backends=None, do_autotune: bool = False):
             blocks = [None] if backend == "xla-ref" else [None] + cands[:3]
             for blk in blocks:
                 out = dispatch.decode_attention(
-                    q, k, v, k_pos, pos, k_scale=ks, v_scale=vs, block=blk,
+                    q, k, v, k_pos, pos, k_scale=ks, v_scale=vs, layer=0, block=blk,
                     backend=backend)
                 err = float(jnp.max(jnp.abs(out - ref_out)))
                 us = _time_call(lambda: dispatch.decode_attention(
-                    q, k, v, k_pos, pos, k_scale=ks, v_scale=vs, block=blk,
+                    q, k, v, k_pos, pos, k_scale=ks, v_scale=vs, layer=0, block=blk,
                     backend=backend))
                 eff_bk = (cap if blk is None and backend == "xla-ref"
                           else (blk or autotune.best_block(
@@ -183,7 +190,7 @@ def sweep_decode_attention(caps, backends=None, do_autotune: bool = False):
                     b, cap, nkv, group, hd, backend=backend, repeats=1,
                     run=lambda blk: dispatch.decode_attention(
                         q, k, v, k_pos, pos, k_scale=ks, v_scale=vs,
-                        block=tuple(blk), backend=backend),
+                        layer=0, block=tuple(blk), backend=backend),
                     candidates=autotune.decode_attention_candidates(
                         cap, nkv=nkv, hd=hd, group=group, quantized=True)[:3])
                 key = autotune.cache_key(

@@ -12,8 +12,9 @@ of standard output is one JSON object naming the device.
 One chip, smollm-135m (30 layers, d 576, 9/3 heads, head dim 64, ff 1536,
 vocab 49152; random weights from ``--seed``, since no checkpoint ships with
 the repository), each phase through ``Engine`` as ``launch/serve.py`` builds
-it — two waves of 8 greedy requests with 128-token prompts that share a
-64-token prefix, 32 new tokens each, max_len 1024:
+it — two waves of 8 greedy requests with 256-token prompts that share a
+128-token prefix (one pool block of 128 positions, the smallest the chip
+takes), 32 new tokens each, max_len 1024:
 
   ring-bf16, ring-int8          the dense per-slot ring cache
   paged-int8-ticks4-prefix      paged pool, 4-tick windows, wave 2 hits the
@@ -50,7 +51,7 @@ import time
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
-WAVE, PROMPT, SHARED, MAX_NEW, MAX_LEN, BLOCK = 8, 128, 64, 32, 1024, 16
+WAVE, PROMPT, SHARED, MAX_NEW, MAX_LEN, BLOCK = 8, 256, 128, 32, 1024, 128
 
 
 class PhaseFailure(Exception):
@@ -78,6 +79,7 @@ def device_report(chips: int):
         sys.exit(f"chip_smoke: --chips {chips} needs {chips} TPUs, JAX "
                  f"found {len(devs)}")
     from repro.kernels import dispatch
+    from repro.models.transformer import kv_shapes
 
     backend = dispatch.resolve_backend().name
     print(f"kernel_backend={backend}", flush=True)
@@ -263,6 +265,7 @@ def kernel_parity(seed):
     import numpy as np
 
     from repro.kernels import dispatch
+    from repro.models.transformer import kv_shapes
 
     b, nkv, group, hd, kq = WAVE, 3, 3, 64, 4
     rng = np.random.default_rng(seed)
@@ -272,19 +275,23 @@ def kernel_parity(seed):
     nbmax = MAX_LEN // BLOCK
     tables = rng.permutation(b * nbmax).reshape(b, nbmax).astype(np.int32)
 
-    def cache(lead, quantized):
-        shape = lead + (nkv, hd)
+    def cache(n, length, quantized):
+        # the engine's layout (kv_shapes), as a stack of one layer
+        shapes = kv_shapes((1, n), nkv, hd, length, quantized=quantized)
         if quantized:
-            codes = rng.integers(-127, 128, shape).astype(np.int8)
-            scales = rng.uniform(0.1, 2.0, lead + (nkv,)).astype(np.float32)
-            return jnp.asarray(codes), jnp.asarray(scales)
-        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16), None
+            codes = rng.integers(-127, 128, shapes["k"]).astype(np.int8)
+            scales = rng.uniform(0.1, 2.0, shapes["k_scale"])
+            return jnp.asarray(codes), jnp.asarray(scales, jnp.float32)
+        return jnp.asarray(rng.standard_normal(shapes["k"]),
+                           jnp.bfloat16), None
 
     for quantized in (False, True):
         for layout in ("ring", "paged"):
-            lead = (b, MAX_LEN) if layout == "ring" else (b * nbmax + 1, BLOCK)
-            (k, ks), (v, vs) = cache(lead, quantized), cache(lead, quantized)
-            aux = k_pos if layout == "ring" else tables
+            n, length = ((b, MAX_LEN) if layout == "ring"
+                         else (b * nbmax + 1, BLOCK))
+            (k, ks), (v, vs) = (cache(n, length, quantized),
+                                cache(n, length, quantized))
+            aux = k_pos[None] if layout == "ring" else tables
             # the oracle's f32 value dot runs at XLA's default TPU precision
             # (bf16 operands on the MXU) and the kernel's may too: one bf16
             # rounding of the softmax weights moves a convex combination of
@@ -292,7 +299,7 @@ def kernel_parity(seed):
             # may differ by 2^-8·max|V|; every other step is the same f32
             # arithmetic
             vmax = float(jnp.max(jnp.abs(
-                v.astype(jnp.float32) * (vs[..., None] / 127.0
+                v.astype(jnp.float32) * (vs[:, :, :nkv, None, :] / 127.0
                                          if quantized else 1.0))))
             tol = 2.0 ** -8 * vmax
             for rows in (1, kq):
@@ -307,8 +314,8 @@ def kernel_parity(seed):
 
                 def run(backend, fn=fn):
                     return jax.jit(lambda *a: fn(
-                        *a, k_scale=ks, v_scale=vs, backend=backend))(
-                            q, k, v, aux, pos)
+                        *a, k_scale=ks, v_scale=vs, layer=0,
+                        backend=backend))(q, k, v, aux, pos)
 
                 got, want = run("pallas-tpu"), run("xla-ref")
                 check(bool(jnp.all(jnp.isfinite(got))),

@@ -167,12 +167,16 @@ def serve_param_specs(params: Any, cfg, mesh) -> Any:
     return jax.tree_util.tree_map_with_path(rule, params)
 
 
-# cache leaves whose *entry-local* dim 2 is a (KV) head dim: (B, S, H, hd)
-# ring KV, quantised KV scales, cross-attention caches — and, in the paged
-# layout, (n_blocks+1, bs, H, hd) pool arrays; SSM state "h" carries heads at
-# entry-local dim 1: (B, nh, hd, n).
-_HEADS_AT_2 = {"k", "v", "k_scale", "v_scale", "cross_k", "cross_v"}
-_HEADS_AT_1 = {"h"}
+# cache leaves whose *entry-local* dim 1 is a (KV) head dim: the
+# heads-major, positions-minor self-attention KV — ring (B, H, hd, cap) and
+# paged pool (n_blocks+1, H, hd, bs) arrays and their (…, rows, cap|bs)
+# scales, whose rows are the heads padded per head shard
+# (transformer.scale_rows) — and the SSM state "h" (B, nh, hd, n); the
+# enc-dec cross-attention caches (B, S, H, hd) carry heads at entry-local
+# dim 2.
+_HEADS_AT_2 = {"cross_k", "cross_v"}
+_HEADS_AT_1 = {"h", "k", "v", "k_scale", "v_scale"}
+_SCALE_LEAVES = {"k_scale", "v_scale"}
 # tree keys under which cache entries carry a leading stack axis (scanned
 # layer repeats / the enc-dec per-layer stacks); "remainder" entries do not
 _STACKED_KEYS = {"layers", "self", "cross_k", "cross_v"}
@@ -186,7 +190,7 @@ def cache_specs(cache: Any, cfg, mesh) -> Any:
     Stacked entries (under ``layers`` / the enc-dec per-layer stacks) carry
     a leading repeat axis which is *never* sharded — the batch/head rules
     shift right by one.  A cache carrying ``block_tables`` is the paged
-    layout: per-layer pool arrays are ``(n_shards·(n_blocks+1), bs, ...)``
+    layout: per-layer pool arrays are ``(n_shards·(n_blocks+1), H, ...)``
     and shard on their leading block axis (each data shard owns its blocks
     plus its own trash block); ``block_tables`` / ``pos`` shard on the slot
     dim.
@@ -199,7 +203,8 @@ def cache_specs(cache: Any, cfg, mesh) -> Any:
     # paged caches (identified by a "block_tables" key) need no special
     # branch: dim 0 of an entry is the batch dim on ring layouts and the
     # pool-block axis on paged ones, and both shard on the DP axes; the
-    # entry-local head dim is 2 in both layouts.
+    # entry-local head dim is 1 in both layouts.
+    heads_split = serve_heads_shardable(cfg, tp)
 
     def rule(path, leaf):
         shape = leaf.shape
@@ -219,7 +224,13 @@ def cache_specs(cache: Any, cfg, mesh) -> Any:
                     1 if name in _HEADS_AT_1 else None)
         if head_dim is not None:
             head_dim += off
-            if (head_dim < len(shape) and tp > 1
+            if name in _SCALE_LEAVES:
+                # scale rows follow the KV heads: the engine builds them
+                # padded per head shard whenever the heads split
+                if heads_split:
+                    assert shape[head_dim] % tp == 0, (name, shape, tp)
+                    spec[head_dim] = "model"
+            elif (head_dim < len(shape) and tp > 1
                     and shape[head_dim] % tp == 0):
                 spec[head_dim] = "model"
         return P(*spec)

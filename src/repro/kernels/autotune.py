@@ -110,16 +110,17 @@ def decode_attention_vmem_bytes(block: Tuple[int], *, nkv: int, hd: int,
                                 group: int, quantized: bool,
                                 kq: int = 1) -> int:
     """Working-set model for the flash-decode / verify kernel at one grid
-    step, with every (…, n_kv, hd) and (…, group, bk) plane padded to its
-    VMEM tile: double-buffered K and V tiles (bk, n_kv, hd) in their
+    step, with every minor plane padded to its VMEM tile: double-buffered
+    K and V tiles (n_kv, hd, bk) — positions on the lanes — in their
     storage dtype (int8 codes or bf16), their in-kernel upcasts (K to bf16,
-    V to f32), the (n_kv, group, bk) logit and weight tiles, the per-position
-    scale and k_pos rows, and the online-softmax state, query and output
-    for the ``kq`` query rows scored per program."""
+    V to f32), the (n_kv, group, bk) logit and weight tiles, the
+    per-position (n_kv, bk) scale and (1, bk) k_pos rows, and the
+    online-softmax state, query and output for the ``kq`` query rows
+    scored per program."""
     (bk,) = block
     elem = 1 if quantized else 2
-    kv_tiles = 2 * 2 * bk * _plane_bytes(nkv, hd, elem)
-    upcast = bk * (_plane_bytes(nkv, hd, 2) + _plane_bytes(nkv, hd, _F32))
+    kv_tiles = 2 * 2 * nkv * _plane_bytes(hd, bk, elem)
+    upcast = nkv * (_plane_bytes(hd, bk, 2) + _plane_bytes(hd, bk, _F32))
     logits = 2 * nkv * _plane_bytes(group, bk, _F32)
     scales = 2 * 2 * _plane_bytes(nkv, bk, _F32) if quantized else 0
     kpos = 2 * _plane_bytes(1, bk, 4)
@@ -171,22 +172,26 @@ def quantize_candidates(m: int, n: int) -> List[Tuple[int, int]]:
 
 def decode_attention_candidates(cap: int, *, nkv: int, hd: int, group: int,
                                 quantized: bool) -> List[Tuple[int]]:
-    """(bk,) cache-length tile candidates under the VMEM budget.  Lane-quantum
-    multiples up to the cap; tiny caps (CPU-scale serving tests) fall back to
-    the cap itself so every shape stays tunable."""
+    """(bk,) cache-length tile candidates under the VMEM budget.  A ring
+    tile lies on the lane axis of the positions-minor cache, so candidates
+    are lane-quantum multiples that divide the cap; tiny caps (CPU-scale
+    serving tests) fall back to the cap itself so every shape stays
+    tunable."""
     budget = VMEM_BUDGET_BYTES * _VMEM_USABLE_FRACTION
     cands = [
         (bk,)
         for bk in _tile_sizes(cap, _LANE, 4096)
-        if decode_attention_vmem_bytes((bk,), nkv=nkv, hd=hd, group=group,
-                                       quantized=quantized) <= budget
+        if cap % bk == 0
+        and decode_attention_vmem_bytes((bk,), nkv=nkv, hd=hd, group=group,
+                                        quantized=quantized) <= budget
     ]
     return cands or [(cap,)]
 
 
 def paged_attention_candidates(max_len: int, *, nkv: int, hd: int,
-                               group: int,
-                               quantized: bool) -> List[Tuple[int]]:
+                               group: int, quantized: bool,
+                               lane_aligned: bool = False
+                               ) -> List[Tuple[int]]:
     """(bs,) pool-block-size candidates for the paged KV cache.
 
     Unlike the ring kernel's per-call cache tile, the paged split-K tile is
@@ -196,16 +201,20 @@ def paged_attention_candidates(max_len: int, *, nkv: int, hd: int,
     sublane-quantum multiples: small enough that a short request wastes
     little of its last block, large enough that the per-block VMEM tile
     keeps the MXU fed; the same working-set model as the ring kernel
-    rejects oversized blocks."""
+    rejects oversized blocks.  ``lane_aligned`` (the chip's kernels) keeps
+    only multiples of the 128-wide lane axis that positions lie on
+    (``decode_attention.check_pool_block``), at least one lane tile even
+    when ``max_len`` is shorter."""
     budget = VMEM_BUDGET_BYTES * _VMEM_USABLE_FRACTION
+    quantum = _LANE if lane_aligned else _SUBLANE
     cands = [
         (bs,)
-        for bs in _tile_sizes(max_len, _SUBLANE, 1024)
-        if bs <= max_len
+        for bs in _tile_sizes(max_len, quantum, 1024)
+        if bs <= max_len and not (lane_aligned and bs % _LANE)
         and decode_attention_vmem_bytes((bs,), nkv=nkv, hd=hd, group=group,
                                         quantized=quantized) <= budget
     ]
-    return cands or [(max(1, max_len),)]
+    return cands or [(_LANE if lane_aligned else max(1, max_len),)]
 
 
 # ---------------------------------------------------------------------------
@@ -308,7 +317,8 @@ def best_block(kind: str, shape: tuple, dtype, bits: int, scheme: str,
         _b, max_len, nkv, group, hd = shape
         cands = paged_attention_candidates(
             max_len, nkv=nkv, hd=hd, group=group,
-            quantized="int8" in str(dtype))
+            quantized="int8" in str(dtype),
+            lane_aligned=backend == "pallas-tpu")
         # the pool block is also the allocation/prefix-sharing unit, so the
         # model pick balances kernel tile size against granularity: the
         # largest candidate that still gives a full-length request ≥ 4
@@ -409,7 +419,8 @@ def autotune_paged_attention(b: int, max_len: int, nkv: int, group: int,
     up when no explicit ``block_size`` is given."""
     quantized = "int8" in str(dtype)
     cands = candidates or paged_attention_candidates(
-        max_len, nkv=nkv, hd=hd, group=group, quantized=quantized)
+        max_len, nkv=nkv, hd=hd, group=group, quantized=quantized,
+        lane_aligned=backend == "pallas-tpu")
     return _sweep("paged_attention", (b, max_len, nkv, group, hd), dtype,
                   8 if quantized else 16, "flash", backend, cands, run,
                   repeats)

@@ -71,15 +71,16 @@ class KernelBackend:
     ``matmul(a, b, *, bits, scheme, counter, seed, a_range, b_range, fmt,
     block)`` → (M, N) f32;  ``quantize(x, *, bits, lo, hi, scheme, counter,
     seed, n_pulses, fmt, block)`` → (M, N) int32 codes;
-    ``decode_attention(q, k, v, k_pos, pos, *, k_scale, v_scale, window,
-    block)`` → (B, n_kv, group, hd) f32 flash-decode attention over the ring
-    KV cache.  ``block`` may be ignored by backends without a tiling concept
+    ``decode_attention(q, k, v, k_pos, pos, *, k_scale, v_scale, layer,
+    window, block)`` → (B, n_kv, group, hd) f32 flash-decode attention over
+    the ring KV cache (``layer`` indexes the cache's leading layer axis).
+    ``block`` may be ignored by backends without a tiling concept
     — except for ``decode_attention``, where the block *is* part of the
     split-K recurrence contract and every backend honours it (xla-ref
     defaults to one whole-cap block).  ``paged_decode_attention(q, k, v,
-    block_tables, pos, *, k_scale, v_scale, window)`` is the paged-pool
-    variant (DESIGN.md §6): the cache tile is pinned to the pool block size
-    by the array layout, so it takes no ``block`` argument.
+    block_tables, pos, *, k_scale, v_scale, layer, window)`` is the
+    paged-pool variant (DESIGN.md §6): the cache tile is pinned to the pool
+    block size by the array layout, so it takes no ``block`` argument.
     """
 
     name: str
@@ -88,8 +89,8 @@ class KernelBackend:
     decode_attention: Optional[Callable] = None
     paged_decode_attention: Optional[Callable] = None
     # multi-token verify variants (speculative decoding, DESIGN.md §14):
-    # ``verify_attention(q, k, v, k_pos, pos, *, k_scale, v_scale, window,
-    # block)`` scores (B, kq, n_kv, group, hd) draft queries; the paged
+    # ``verify_attention(q, k, v, k_pos, pos, *, k_scale, v_scale, layer,
+    # window, block)`` scores (B, kq, n_kv, group, hd) draft queries; the paged
     # variant again takes no block (tile = pool block)
     verify_attention: Optional[Callable] = None
     paged_verify_attention: Optional[Callable] = None
@@ -127,29 +128,29 @@ def _make_pallas(name: str, interpret: bool) -> KernelBackend:
             seed=seed, n_pulses=n_pulses, fmt=fmt, block=block,
             interpret=interpret)
 
-    def _decode_attention(q, k, v, k_pos, pos, *, k_scale, v_scale, window,
-                          block):
+    def _decode_attention(q, k, v, k_pos, pos, *, k_scale, v_scale, layer,
+                          window, block):
         return decode_attention_call(
-            q, k, v, k_pos, pos, k_scale, v_scale, window=window,
+            q, k, v, k_pos, pos, k_scale, v_scale, layer=layer, window=window,
             block=tuple(block), interpret=interpret)
 
     def _paged_decode_attention(q, k, v, block_tables, pos, *, k_scale,
-                                v_scale, window):
+                                v_scale, layer, window):
         return paged_decode_attention_call(
-            q, k, v, block_tables, pos, k_scale, v_scale, window=window,
-            interpret=interpret)
+            q, k, v, block_tables, pos, k_scale, v_scale, layer=layer,
+            window=window, interpret=interpret)
 
-    def _verify_attention(q, k, v, k_pos, pos, *, k_scale, v_scale, window,
-                          block):
+    def _verify_attention(q, k, v, k_pos, pos, *, k_scale, v_scale, layer,
+                          window, block):
         return verify_attention_call(
-            q, k, v, k_pos, pos, k_scale, v_scale, window=window,
+            q, k, v, k_pos, pos, k_scale, v_scale, layer=layer, window=window,
             block=tuple(block), interpret=interpret)
 
     def _paged_verify_attention(q, k, v, block_tables, pos, *, k_scale,
-                                v_scale, window):
+                                v_scale, layer, window):
         return paged_verify_attention_call(
-            q, k, v, block_tables, pos, k_scale, v_scale, window=window,
-            interpret=interpret)
+            q, k, v, block_tables, pos, k_scale, v_scale, layer=layer,
+            window=window, interpret=interpret)
 
     return register_backend(
         KernelBackend(name, _matmul, _quantize, _decode_attention,
@@ -197,57 +198,64 @@ def _make_xla_ref() -> KernelBackend:
                              fmt=fmt)
 
     @functools.partial(jax.jit, static_argnames=("window", "block"))
-    def _decattn_jit(q, k, v, k_pos, pos, k_scale, v_scale, *, window, block):
+    def _decattn_jit(q, k, v, k_pos, pos, k_scale, v_scale, layer, *,
+                     window, block):
         return ref.decode_attention_ref(
-            q, k, v, k_pos, pos, k_scale, v_scale, window=window, block=block)
+            q, k, v, k_pos, pos, k_scale, v_scale, layer=layer, window=window,
+            block=block)
 
-    def _decode_attention(q, k, v, k_pos, pos, *, k_scale, v_scale, window,
-                          block):
+    def _decode_attention(q, k, v, k_pos, pos, *, k_scale, v_scale, layer,
+                          window, block):
         # the oracle honours `block` (it is part of the split-K contract);
         # None collapses to one whole-cap block — the fast XLA serving path
         return _decattn_jit(q, k, v, k_pos, jnp.asarray(pos, jnp.int32),
-                            k_scale, v_scale, window=window,
+                            k_scale, v_scale, layer, window=window,
                             block=None if block is None else tuple(block))
 
     @functools.partial(jax.jit, static_argnames=("window",))
-    def _paged_jit(q, k, v, block_tables, pos, k_scale, v_scale, *, window):
+    def _paged_jit(q, k, v, block_tables, pos, k_scale, v_scale, layer, *,
+                   window):
         return ref.paged_decode_attention_ref(
-            q, k, v, block_tables, pos, k_scale, v_scale, window=window)
+            q, k, v, block_tables, pos, k_scale, v_scale, layer=layer,
+            window=window)
 
     def _paged_decode_attention(q, k, v, block_tables, pos, *, k_scale,
-                                v_scale, window):
+                                v_scale, layer, window):
         # the paged recurrence's tile is the pool block itself, so the
         # oracle runs the exact kernel recurrence — no whole-cap collapse
         return _paged_jit(q, k, v, block_tables,
                           jnp.asarray(pos, jnp.int32), k_scale, v_scale,
-                          window=window)
+                          layer, window=window)
 
     @functools.partial(jax.jit, static_argnames=("window", "block"))
-    def _verify_jit(q, k, v, k_pos, pos, k_scale, v_scale, *, window, block):
+    def _verify_jit(q, k, v, k_pos, pos, k_scale, v_scale, layer, *, window,
+                    block):
         return ref.verify_attention_ref(
-            q, k, v, k_pos, pos, k_scale, v_scale, window=window, block=block)
+            q, k, v, k_pos, pos, k_scale, v_scale, layer=layer, window=window,
+            block=block)
 
-    def _verify_attention(q, k, v, k_pos, pos, *, k_scale, v_scale, window,
-                          block):
+    def _verify_attention(q, k, v, k_pos, pos, *, k_scale, v_scale, layer,
+                          window, block):
         # same block semantics as decode: None collapses to one whole-cap
         # block, which is also what the serving decode path uses off-TPU —
         # keeping verify and decode on the same association order is what
         # makes the spec-decode stream bitwise ≡ plain decode (DESIGN.md §14)
         return _verify_jit(q, k, v, k_pos, jnp.asarray(pos, jnp.int32),
-                           k_scale, v_scale, window=window,
+                           k_scale, v_scale, layer, window=window,
                            block=None if block is None else tuple(block))
 
     @functools.partial(jax.jit, static_argnames=("window",))
-    def _paged_verify_jit(q, k, v, block_tables, pos, k_scale, v_scale, *,
-                          window):
+    def _paged_verify_jit(q, k, v, block_tables, pos, k_scale, v_scale,
+                          layer, *, window):
         return ref.paged_verify_attention_ref(
-            q, k, v, block_tables, pos, k_scale, v_scale, window=window)
+            q, k, v, block_tables, pos, k_scale, v_scale, layer=layer,
+            window=window)
 
     def _paged_verify_attention(q, k, v, block_tables, pos, *, k_scale,
-                                v_scale, window):
+                                v_scale, layer, window):
         return _paged_verify_jit(q, k, v, block_tables,
                                  jnp.asarray(pos, jnp.int32), k_scale,
-                                 v_scale, window=window)
+                                 v_scale, layer, window=window)
 
     return register_backend(
         KernelBackend("xla-ref", _matmul, _quantize, _decode_attention,
@@ -365,15 +373,25 @@ def quantize(
                        block=block)
 
 
+def _ring_autotune_block(k, group: int, backend: str) -> tuple:
+    """The autotuned ring tile for an (L, B, n_kv, hd, cap) cache, keyed
+    by its per-layer (B, cap, n_kv, group, hd) shape."""
+    b, nkv, hd, cap = k.shape[-4:]
+    bits = 8 if k.dtype == jnp.int8 else 16
+    return autotune.best_block("decode_attention", (b, cap, nkv, group, hd),
+                               str(k.dtype), bits, "flash", backend)
+
+
 def decode_attention(
     q: jax.Array,        # (B, n_kv_heads, group, hd) — post-RoPE queries
-    k: jax.Array,        # (B, cap, n_kv_heads, hd) int8 codes or bf16
-    v: jax.Array,        # (B, cap, n_kv_heads, hd)
-    k_pos: jax.Array,    # (B, cap) int32 absolute position per ring slot
+    k: jax.Array,        # (L, B, n_kv_heads, hd, cap) int8 codes or bf16
+    v: jax.Array,        # (L, B, n_kv_heads, hd, cap)
+    k_pos: jax.Array,    # (L, B, cap) int32 absolute position per slot
     pos: jax.Array,      # (B,) int32 per-slot decode position
     *,
-    k_scale: Optional[jax.Array] = None,   # (B, cap, n_kv_heads) f32 when int8
+    k_scale: Optional[jax.Array] = None,   # (L, B, n_kv, cap) f32 int8
     v_scale: Optional[jax.Array] = None,
+    layer,               # index into the cache's leading layer axis
     window: int = 0,
     block: Optional[tuple] = None,
     backend: Optional[str] = None,
@@ -385,59 +403,60 @@ def decode_attention(
     tile in VMEM, scales folded in after the dot — so the decode path never
     materialises a full-cap fp copy of the cache.  ``block=(bk,)`` is the
     cache-length tile of the split-K online-softmax recurrence; Pallas
-    backends autotune it, xla-ref defaults to one whole-cap block.
+    backends autotune it, xla-ref defaults to one whole-cap block.  The
+    cache arrays carry a leading layer axis and the kernel reads layer
+    ``layer`` in place.
     """
     be = resolve_backend(backend)
     if block is None and be.name.startswith("pallas"):
-        b, cap, nkv, hd = k.shape
-        group = q.shape[2]
-        bits = 8 if k.dtype == jnp.int8 else 16
-        block = autotune.best_block("decode_attention",
-                                    (b, cap, nkv, group, hd), str(k.dtype),
-                                    bits, "flash", be.name)
+        block = _ring_autotune_block(k, q.shape[2], be.name)
     return be.decode_attention(q, k, v, k_pos, pos, k_scale=k_scale,
-                               v_scale=v_scale, window=window, block=block)
+                               v_scale=v_scale, layer=layer, window=window,
+                               block=block)
 
 
 def paged_decode_attention(
     q: jax.Array,        # (B, n_kv_heads, group, hd) — post-RoPE queries
-    k: jax.Array,        # (n_blocks, bs, n_kv_heads, hd) int8 codes or bf16
-    v: jax.Array,        # (n_blocks, bs, n_kv_heads, hd)
+    k: jax.Array,        # (L, n_blocks, n_kv_heads, hd, bs) int8 or bf16
+    v: jax.Array,        # (L, n_blocks, n_kv_heads, hd, bs)
     block_tables: jax.Array,  # (B, nbmax) int32 physical block per logical
     pos: jax.Array,      # (B,) int32 per-slot decode position
     *,
-    k_scale: Optional[jax.Array] = None,  # (n_blocks, bs, n_kv) f32 when int8
+    k_scale: Optional[jax.Array] = None,  # (L, n_blocks, n_kv, bs) f32
     v_scale: Optional[jax.Array] = None,
+    layer,               # index into the pool's leading layer axis
     window: int = 0,
     backend: Optional[str] = None,
 ) -> jax.Array:
     """Paged flash-decode attention over the block-pool KV cache →
     (B, n_kv, group, hd) f32, through the selected backend (DESIGN.md §6).
 
-    The split-K tile is the pool block itself (``bs = k.shape[1]``, chosen
+    The split-K tile is the pool block itself (``bs = k.shape[-1]``, chosen
     at pool-creation time from ``autotune.best_block('paged_attention',
     ...)``), so unlike the ring entry point there is no per-call ``block``:
     every backend runs the same per-block recurrence, and ``xla-ref`` is
     the bit-exact oracle rather than a whole-cap collapse.  The Pallas
-    backends gather cache tiles through the scalar-prefetched block table,
-    which is what makes refcount-shared prefix blocks readable by several
-    requests at once without any copy.
+    backends gather cache tiles through the scalar-prefetched block table
+    and layer index, which is what makes refcount-shared prefix blocks
+    readable by several requests at once, and a layer-stacked pool readable
+    by layer, without any copy.
     """
     be = resolve_backend(backend)
     return be.paged_decode_attention(q, k, v, block_tables, pos,
                                      k_scale=k_scale, v_scale=v_scale,
-                                     window=window)
+                                     layer=layer, window=window)
 
 
 def verify_attention(
     q: jax.Array,        # (B, kq, n_kv_heads, group, hd) — draft queries
-    k: jax.Array,        # (B, cap, n_kv_heads, hd) int8 codes or bf16
-    v: jax.Array,        # (B, cap, n_kv_heads, hd)
-    k_pos: jax.Array,    # (B, cap) int32 absolute position per ring slot
+    k: jax.Array,        # (L, B, n_kv_heads, hd, cap) int8 codes or bf16
+    v: jax.Array,        # (L, B, n_kv_heads, hd, cap)
+    k_pos: jax.Array,    # (L, B, cap) int32 absolute position per slot
     pos: jax.Array,      # (B,) int32 per-slot base (first-row) position
     *,
-    k_scale: Optional[jax.Array] = None,   # (B, cap, n_kv_heads) f32
+    k_scale: Optional[jax.Array] = None,   # (L, B, n_kv, cap) f32
     v_scale: Optional[jax.Array] = None,
+    layer,
     window: int = 0,
     block: Optional[tuple] = None,
     backend: Optional[str] = None,
@@ -456,25 +475,22 @@ def verify_attention(
     """
     be = resolve_backend(backend)
     if block is None and be.name.startswith("pallas"):
-        b, cap, nkv, hd = k.shape
-        group = q.shape[3]
-        bits = 8 if k.dtype == jnp.int8 else 16
-        block = autotune.best_block("decode_attention",
-                                    (b, cap, nkv, group, hd), str(k.dtype),
-                                    bits, "flash", be.name)
+        block = _ring_autotune_block(k, q.shape[3], be.name)
     return be.verify_attention(q, k, v, k_pos, pos, k_scale=k_scale,
-                               v_scale=v_scale, window=window, block=block)
+                               v_scale=v_scale, layer=layer, window=window,
+                               block=block)
 
 
 def paged_verify_attention(
     q: jax.Array,        # (B, kq, n_kv_heads, group, hd) — draft queries
-    k: jax.Array,        # (n_blocks, bs, n_kv_heads, hd) int8 codes or bf16
-    v: jax.Array,        # (n_blocks, bs, n_kv_heads, hd)
+    k: jax.Array,        # (L, n_blocks, n_kv_heads, hd, bs) int8 or bf16
+    v: jax.Array,        # (L, n_blocks, n_kv_heads, hd, bs)
     block_tables: jax.Array,  # (B, nbmax) int32 physical block per logical
     pos: jax.Array,      # (B,) int32 per-slot base (first-row) position
     *,
-    k_scale: Optional[jax.Array] = None,  # (n_blocks, bs, n_kv) f32
+    k_scale: Optional[jax.Array] = None,  # (L, n_blocks, n_kv, bs) f32
     v_scale: Optional[jax.Array] = None,
+    layer,
     window: int = 0,
     backend: Optional[str] = None,
 ) -> jax.Array:
@@ -488,4 +504,4 @@ def paged_verify_attention(
     be = resolve_backend(backend)
     return be.paged_verify_attention(q, k, v, block_tables, pos,
                                      k_scale=k_scale, v_scale=v_scale,
-                                     window=window)
+                                     layer=layer, window=window)

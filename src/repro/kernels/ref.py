@@ -134,21 +134,23 @@ def dither_matmul_ref(
 
 
 def _flash_ref(q, k, v, tables, pos, k_scale, v_scale, k_pos, *,
-               window: int) -> jax.Array:
+               window: int, layer) -> jax.Array:
     """The split-K online-softmax recurrence over a tiled cache, batched
     over slots: a ``lax.scan`` over logical tiles j that gathers each
-    slot's physical tile ``tables[b, min(j, pos[b]//bk)]`` and updates the
-    running max / sum / value state.  k, v (n_tiles, bk, n_kv, hd);
-    k_scale, v_scale (n_tiles, bk, n_kv) or None; k_pos (n_tiles, bk) or
-    None for the pool's implicit positions ``j·bk + t``.
+    slot's physical tile ``tables[b, min(j, pos[b]//bk)]`` of layer
+    ``layer`` and updates the running max / sum / value state.  k, v
+    (L, n_tiles, n_kv, hd, bk); k_scale, v_scale (L, n_tiles, rows, bk) or
+    None, rows ≥ n_kv (rows past n_kv are padding); k_pos (n_tiles, bk), the
+    layer's own, or None for the pool's implicit positions ``j·bk + t``.
 
-    Per tile: int8→query-dtype upcast, an f32-accumulated dot batched over
-    slot and KV head, post-dot key-scale fold, -1e30 masking of unwritten,
-    future and out-of-window keys, then value scales on the unnormalised
-    weights — the Pallas kernel body's order, so the two agree bitwise
-    where both run on one backend."""
+    Per tile: int8→query-dtype upcast, an f32-accumulated
+    (n_kv, group, hd)·(n_kv, hd, bk) dot batched over slot and KV head,
+    post-dot key-scale fold, -1e30 masking of unwritten, future and
+    out-of-window keys, then value scales on the unnormalised weights and
+    a value dot over the position (lane) axis — the Pallas kernel body's
+    order, so the two agree bitwise where both run on one backend."""
     bsz, nkv, group, hd = q.shape
-    bk = k.shape[1]
+    bk = k.shape[-1]
     inv = float(1.0 / math.sqrt(hd))
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (bsz,))
     tables = jnp.asarray(tables, jnp.int32)
@@ -161,17 +163,17 @@ def _flash_ref(q, k, v, tables, pos, k_scale, v_scale, k_pos, *,
         phys = jnp.take_along_axis(tables, jc[:, None], axis=1)[:, 0]
 
         def take(x):
-            return jnp.take(x, phys, axis=0)
+            return x[layer, phys]
 
         logits = jax.lax.dot_general(
             q, take(k).astype(q.dtype),
-            dimension_numbers=(((3,), (3,)), ((0, 1), (0, 2))),
+            dimension_numbers=(((3,), (2,)), ((0, 1), (0, 1))),
             preferred_element_type=jnp.float32,
         ) * inv                                            # (B, n_kv, group, bk)
         if quantized:
-            ks = take(k_scale).transpose(0, 2, 1)          # (B, n_kv, bk)
+            ks = take(k_scale)[:, :nkv]                    # (B, n_kv, bk)
             logits = logits * (ks[:, :, None, :] * (1.0 / 127.0))
-        kp = (take(k_pos) if k_pos is not None
+        kp = (k_pos[phys] if k_pos is not None
               else jc[:, None] * bk + jnp.arange(bk, dtype=jnp.int32)[None])
         kp = kp[:, None, None, :]
         pb = pos[:, None, None, None]
@@ -185,11 +187,11 @@ def _flash_ref(q, k, v, tables, pos, k_scale, v_scale, k_pos, *,
         p = jnp.exp(logits - m_new)
         s_new = s * alpha + jnp.sum(p, axis=-1, keepdims=True)
         if quantized:
-            vs = take(v_scale).transpose(0, 2, 1)
+            vs = take(v_scale)[:, :nkv]
             p = p * (vs[:, :, None, :] * (1.0 / 127.0))
         acc_new = acc * alpha + jax.lax.dot_general(
             p, take(v).astype(jnp.float32),
-            dimension_numbers=(((3,), (1,)), ((0, 1), (0, 2))),
+            dimension_numbers=(((3,), (3,)), ((0, 1), (0, 1))),
             preferred_element_type=jnp.float32,
         )
         act = (j <= last)[:, None, None, None]
@@ -208,13 +210,14 @@ def _flash_ref(q, k, v, tables, pos, k_scale, v_scale, k_pos, *,
 
 def decode_attention_ref(
     q: jax.Array,        # (B, n_kv, group, hd) bf16/f32 — post-RoPE queries
-    k: jax.Array,        # (B, cap, n_kv, hd) int8 codes or bf16
-    v: jax.Array,        # (B, cap, n_kv, hd)
-    k_pos: jax.Array,    # (B, cap) int32
+    k: jax.Array,        # (L, B, n_kv, hd, cap) int8 codes or bf16
+    v: jax.Array,        # (L, B, n_kv, hd, cap)
+    k_pos: jax.Array,    # (L, B, cap) int32
     pos: jax.Array,      # (B,) int32 per-slot absolute decode position
-    k_scale: jax.Array | None = None,   # (B, cap, n_kv) f32 when int8
+    k_scale: jax.Array | None = None,   # (L, B, rows, cap) f32 when int8
     v_scale: jax.Array | None = None,
     *,
+    layer,               # index into the leading layer axis
     window: int = 0,
     block: tuple | None = None,
 ) -> jax.Array:
@@ -236,30 +239,36 @@ def decode_attention_ref(
     to a single masked softmax pass — the fast XLA path the serving engine
     uses off-TPU.
     """
-    bsz, cap = k.shape[:2]
+    bsz, cap = k.shape[1], k.shape[-1]
     # both paths MUST shrink `block` to the same divisor of cap or the
     # bit-parity contract silently breaks
     bk = shrink_block(cap if block is None else block[0], cap)
     nt = cap // bk
 
-    def tiles(x):
-        return None if x is None else x.reshape((bsz * nt, bk) + x.shape[2:])
+    def tiles(x):     # layer's (B, ..., cap) → (1, B·nt, ..., bk), a relayout
+        if x is None:
+            return None
+        t = jax.lax.dynamic_index_in_dim(x, layer, keepdims=False)
+        t = jnp.moveaxis(t.reshape(t.shape[:-1] + (nt, bk)), -2, 1)
+        return t.reshape((1, bsz * nt) + t.shape[2:])
 
+    kp = tiles(k_pos)
     return _flash_ref(
         q, tiles(k), tiles(v),
         jnp.arange(bsz * nt, dtype=jnp.int32).reshape(bsz, nt), pos,
-        tiles(k_scale), tiles(v_scale), tiles(k_pos), window=window)
+        tiles(k_scale), tiles(v_scale), kp[0], window=window, layer=0)
 
 
 def paged_decode_attention_ref(
     q: jax.Array,        # (B, n_kv, group, hd) bf16/f32 — post-RoPE queries
-    k: jax.Array,        # (n_blocks, bs, n_kv, hd) int8 codes or bf16 pool
-    v: jax.Array,        # (n_blocks, bs, n_kv, hd)
+    k: jax.Array,        # (L, n_blocks, n_kv, hd, bs) int8 codes or bf16
+    v: jax.Array,        # (L, n_blocks, n_kv, hd, bs)
     block_tables: jax.Array,  # (B, nbmax) int32 physical block per logical
     pos: jax.Array,      # (B,) int32 per-slot absolute decode position
-    k_scale: jax.Array | None = None,   # (n_blocks, bs, n_kv) f32 when int8
+    k_scale: jax.Array | None = None,   # (L, n_blocks, rows, bs) f32
     v_scale: jax.Array | None = None,
     *,
+    layer,               # index into the leading layer axis
     window: int = 0,
 ) -> jax.Array:
     """Oracle for the paged flash-decode kernel → (B, n_kv, group, hd) f32.
@@ -273,18 +282,19 @@ def paged_decode_attention_ref(
     recurrence on the same token stream — the reuse guarantee that makes
     prefix blocks shareable across requests (DESIGN.md §6)."""
     return _flash_ref(q, k, v, block_tables, pos, k_scale, v_scale, None,
-                      window=window)
+                      window=window, layer=layer)
 
 
 def verify_attention_ref(
     q: jax.Array,        # (B, kq, n_kv, group, hd) — post-RoPE draft queries
-    k: jax.Array,        # (B, cap, n_kv, hd) int8 codes or bf16
-    v: jax.Array,        # (B, cap, n_kv, hd)
-    k_pos: jax.Array,    # (B, cap) int32
+    k: jax.Array,        # (L, B, n_kv, hd, cap) int8 codes or bf16
+    v: jax.Array,        # (L, B, n_kv, hd, cap)
+    k_pos: jax.Array,    # (L, B, cap) int32
     pos: jax.Array,      # (B,) int32 per-slot base (first-row) position
-    k_scale: jax.Array | None = None,   # (B, cap, n_kv) f32 when int8
+    k_scale: jax.Array | None = None,   # (L, B, rows, cap) f32 when int8
     v_scale: jax.Array | None = None,
     *,
+    layer,
     window: int = 0,
     block: tuple | None = None,
 ) -> jax.Array:
@@ -305,19 +315,21 @@ def verify_attention_ref(
     pos = jnp.asarray(pos, jnp.int32)
     return jnp.stack(
         [decode_attention_ref(q[:, t], k, v, k_pos, pos + t,
-                              k_scale, v_scale, window=window, block=block)
+                              k_scale, v_scale, layer=layer, window=window,
+                              block=block)
          for t in range(kq)], axis=1)
 
 
 def paged_verify_attention_ref(
     q: jax.Array,        # (B, kq, n_kv, group, hd) — post-RoPE draft queries
-    k: jax.Array,        # (n_blocks, bs, n_kv, hd) int8 codes or bf16 pool
-    v: jax.Array,        # (n_blocks, bs, n_kv, hd)
+    k: jax.Array,        # (L, n_blocks, n_kv, hd, bs) int8 codes or bf16
+    v: jax.Array,        # (L, n_blocks, n_kv, hd, bs)
     block_tables: jax.Array,  # (B, nbmax) int32 physical block per logical
     pos: jax.Array,      # (B,) int32 per-slot base (first-row) position
-    k_scale: jax.Array | None = None,   # (n_blocks, bs, n_kv) f32 when int8
+    k_scale: jax.Array | None = None,   # (L, n_blocks, rows, bs) f32
     v_scale: jax.Array | None = None,
     *,
+    layer,
     window: int = 0,
 ) -> jax.Array:
     """Oracle for the paged multi-token verify kernel →
@@ -330,5 +342,6 @@ def paged_verify_attention_ref(
     pos = jnp.asarray(pos, jnp.int32)
     return jnp.stack(
         [paged_decode_attention_ref(q[:, t], k, v, block_tables, pos + t,
-                                    k_scale, v_scale, window=window)
+                                    k_scale, v_scale, layer=layer,
+                                    window=window)
          for t in range(kq)], axis=1)

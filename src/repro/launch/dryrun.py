@@ -234,9 +234,12 @@ def build_lowered(arch: str, shape: str, mesh, *, policy=None, microbatch: int =
     if cfg.is_encdec:
         frames_sds = jax.ShapeDtypeStruct(
             (batch, cfg.n_enc_tokens, cfg.d_model), jnp.bfloat16)
+    tp = mesh.shape.get("model", 1)
+    heads = tp if shd.serve_heads_shardable(cfg, tp) else 1
     cache_shapes = jax.eval_shape(
         lambda p, f: registry.make_cache(p, cfg, batch, seq, frames=f,
-                                         kv_quant=kv_quant),
+                                         kv_quant=kv_quant,
+                                         head_shards=heads),
         params_shapes, frames_sds,
     )
     cspecs = shd.cache_specs(cache_shapes, cfg, mesh)

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from repro.models import layers
 from repro.models.config import ModelConfig
-from repro.models.transformer import _attention_decode
+from repro.models.transformer import _attention_decode, kv_shapes, ring_len
 from repro.numerics.policy import QuantPolicy, dense
 
 Params = Dict[str, Any]
@@ -138,12 +138,12 @@ def init_encdec_cache(params, cfg: ModelConfig, frames, batch: int, max_len: int
     enc = encode(params, cfg, frames, policy=policy)
     hd, nkv = cfg.hd(), cfg.n_kv_heads
     xk, xv = _stacked_xkv(params, enc, cfg, batch)
-    self_kv = {
-        "k": jnp.zeros((cfg.n_layers, batch, max_len, nkv, hd), jnp.bfloat16),
-        "v": jnp.zeros((cfg.n_layers, batch, max_len, nkv, hd), jnp.bfloat16),
-        "k_pos": jnp.broadcast_to(jnp.full((batch, max_len), -1, jnp.int32),
-                                  (cfg.n_layers, batch, max_len)),
-    }
+    # self-KV in the decoder ring's layout (transformer.kv_shapes)
+    cap = ring_len(cfg, max_len)
+    self_kv = {name: jnp.zeros(shape, jnp.bfloat16) for name, shape in
+               kv_shapes((cfg.n_layers, batch), nkv, hd, cap,
+                         quantized=False).items()}
+    self_kv["k_pos"] = jnp.full((cfg.n_layers, batch, cap), -1, jnp.int32)
     return {"pos": jnp.zeros((batch,), jnp.int32), "self": self_kv,
             "cross_k": xk, "cross_v": xv}
 
@@ -197,12 +197,13 @@ def decode_step_encdec(
     b = x.shape[0]
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd()
 
-    def body(h, xs):
-        bp, ck, cv, ckpos, xk, xv = xs
-        entry = {"k": ck, "v": cv, "k_pos": ckpos}
-        a, ne = _attention_decode(
+    def body(carry, xs):
+        # the self-KV stack rides in the loop state, written in place
+        h, entry = carry
+        bp, layer, xk, xv = xs
+        a, entry = _attention_decode(
             bp["attn"], cfg, _lnorm(h, bp["ln1"], cfg.norm_eps), entry, pos,
-            policy, counter,
+            policy, counter, layer=layer,
         )
         h = h + a
         # cross attention against the precomputed encoder KV
@@ -215,20 +216,17 @@ def decode_step_encdec(
         h = h + dense(c, bp["xattn"]["wo"], policy, counter, seed=4)
         h = h + layers.mlp(bp["mlp"], _lnorm(h, bp["ln2"], cfg.norm_eps), "gelu",
                            policy, counter)
-        return h, (ne["k"], ne["v"], ne["k_pos"])
+        return (h, entry), None
 
-    xs = (
-        params["dec_blocks"],
-        cache["self"]["k"], cache["self"]["v"], cache["self"]["k_pos"],
-        cache["cross_k"], cache["cross_v"],
-    )
-    x, (nk, nv, nkpos) = jax.lax.scan(body, x, xs)
+    xs = (params["dec_blocks"], jnp.arange(cfg.n_layers, dtype=jnp.int32),
+          cache["cross_k"], cache["cross_v"])
+    (x, self_kv), _ = jax.lax.scan(body, (x, cache["self"]), xs)
     x = _lnorm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.matmul(x, params["embed"].T)[:, 0].astype(jnp.float32)
     logits = logits[:, : cfg.vocab_size]  # drop vocab padding for sampling
     new_cache = {
         "pos": pos + 1,
-        "self": {"k": nk, "v": nv, "k_pos": nkpos},
+        "self": self_kv,
         "cross_k": cache["cross_k"], "cross_v": cache["cross_v"],
     }
     return logits, new_cache

@@ -63,12 +63,13 @@ def make_cache(params: Params, cfg: ModelConfig, batch_size: int, max_len: int,
                kv_quant: bool = False, kv_layout: str = "ring",
                block_size: Optional[int] = None,
                num_blocks: Optional[int] = None,
-               data_shards: int = 1) -> Params:
+               data_shards: int = 1, head_shards: int = 1) -> Params:
     """Decode-cache constructor.  ``data_shards`` > 1 lays the paged block
     pool out as shard-local sub-pools (one trash block each) for the sharded
     serving engine — ``num_blocks`` then counts blocks per shard
     (DESIGN.md §9); ring caches need no layout change (the slot dim shards
-    directly)."""
+    directly).  ``head_shards`` is the number of shards the KV heads split
+    into, for the per-shard padding of int8 scale rows."""
     if cfg.is_encdec:
         assert frames is not None
         if kv_layout != "ring":
@@ -82,7 +83,8 @@ def make_cache(params: Params, cfg: ModelConfig, batch_size: int, max_len: int,
     return transformer.init_cache(cfg, batch_size, max_len, kv_quant=kv_quant,
                                   kv_layout=kv_layout, block_size=block_size,
                                   num_blocks=num_blocks,
-                                  data_shards=data_shards)
+                                  data_shards=data_shards,
+                                  head_shards=head_shards)
 
 
 def apply_decode(params: Params, cfg: ModelConfig, token: jax.Array, cache: Params,

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.dist import ctx as dist_ctx
+from repro.kernels.decode_attention import LANE
 from repro.models import hybrid, layers, moe, ssm
 from repro.models.config import ModelConfig
 from repro.numerics.policy import QuantPolicy, dense
@@ -155,28 +156,79 @@ def init_params(key, cfg: ModelConfig) -> Params:
 # ---------------------------------------------------------------------------
 
 
+def scale_rows(nkv: int, head_shards: int = 1) -> int:
+    """Rows of a positions-minor int8 scale array: its KV heads padded, per
+    head shard, to a multiple of 8 — the f32 sublane tile.  A (n_kv, bs)
+    plane of fewer rows pads on the chip anyway, so the compiler would
+    store the array transposed and re-lay it out around every kernel call;
+    padded, it is stored as the kernel reads it.  Padding rows hold zeros
+    and are never read."""
+    per = nkv // head_shards
+    return head_shards * (-(-per // 8) * 8)
+
+
+def ring_len(cfg: ModelConfig, max_len: int) -> int:
+    """Positions a ring cache holds: the window or ``max_len``, rounded up
+    to whole 128-position lane tiles beyond the first.  Positions are the
+    ring's minor axis, so a longer ring off a lane multiple would leave
+    the chip's kernel no tile that divides it but the whole ring, which
+    need not fit VMEM.  The extra positions change nothing: a position
+    lands at ``pos % ring_len``, and every read masks keys by position and
+    window (``k_pos``), so an unwritten or out-of-window slot is never
+    attended."""
+    cap = min(cfg.window, max_len) if cfg.window else max_len
+    return cap if cap <= LANE else -(-cap // LANE) * LANE
+
+
+def kv_shapes(lead: tuple, nkv: int, hd: int, length: int, *,
+              quantized: bool, head_shards: int = 1) -> dict:
+    """Shapes of one attention layer's cache arrays — the one statement of
+    the cache layout, for the model, the kernels' tests and tools.  Ring
+    ``lead`` is (B,), the pool's (n_blocks,); ``length`` is the ring's
+    positions or the pool block.  Heads-major with positions minor: K/V
+    (*lead, n_kv, hd, length) and, int8, per-position scales (*lead,
+    ``scale_rows``, length), so every (hd, positions) and (rows, positions)
+    plane is the chip's dense tile and the decode kernel reads the cache
+    where it lies (kernels/decode_attention.py)."""
+    lead = tuple(lead)
+    kv = lead + (nkv, hd, length)
+    shapes = {"k": kv, "v": kv}
+    if quantized:
+        sc = lead + (scale_rows(nkv, head_shards), length)
+        shapes.update(k_scale=sc, v_scale=sc)
+    return shapes
+
+
+def _kv_zeros(lead, cfg: ModelConfig, length: int, kv_quant: bool,
+              head_shards: int):
+    """Zeroed K/V (and int8 scale) arrays in the ``kv_shapes`` layout."""
+    kv_dt = jnp.int8 if kv_quant else jnp.bfloat16
+    return {name: jnp.zeros(shape, kv_dt if name in ("k", "v")
+                            else jnp.float32)
+            for name, shape in kv_shapes(
+                lead, cfg.n_kv_heads, cfg.hd(), length, quantized=kv_quant,
+                head_shards=head_shards).items()}
+
+
+def _pad_rows(t, rows: int):
+    """Pad axis -2 of a (…, n_kv, positions) scale array to ``rows``."""
+    pad = [(0, 0)] * t.ndim
+    pad[-2] = (0, rows - t.shape[-2])
+    return jnp.pad(t, pad)
+
+
 def _cache_entry(cfg: ModelConfig, kind: str, batch: int, max_len: int,
-                 kv_quant: bool = False):
+                 kv_quant: bool = False, head_shards: int = 1):
     if kind == "attn":
-        cap = min(cfg.window, max_len) if cfg.window else max_len
-        if kv_quant:
-            # Dither-quantised int8 cache (DESIGN.md §6 — the paper's
-            # unbiased rounding applied to KV compression): codes + one
-            # per-position, per-head scale; written with counter = pos (plus
-            # an optional per-request offset, DESIGN.md §6), so re-decodes
-            # of the same slot over time average out (§VII).
-            return {
-                "k": jnp.zeros((batch, cap, cfg.n_kv_heads, cfg.hd()), jnp.int8),
-                "v": jnp.zeros((batch, cap, cfg.n_kv_heads, cfg.hd()), jnp.int8),
-                "k_scale": jnp.zeros((batch, cap, cfg.n_kv_heads), jnp.float32),
-                "v_scale": jnp.zeros((batch, cap, cfg.n_kv_heads), jnp.float32),
-                "k_pos": jnp.full((batch, cap), -1, jnp.int32),
-            }
-        return {
-            "k": jnp.zeros((batch, cap, cfg.n_kv_heads, cfg.hd()), jnp.bfloat16),
-            "v": jnp.zeros((batch, cap, cfg.n_kv_heads, cfg.hd()), jnp.bfloat16),
-            "k_pos": jnp.full((batch, cap), -1, jnp.int32),
-        }
+        # Dither-quantised int8 cache when kv_quant (DESIGN.md §6 — the
+        # paper's unbiased rounding applied to KV compression): codes + one
+        # per-position, per-head scale; written with counter = pos (plus
+        # an optional per-request offset, DESIGN.md §6), so re-decodes of
+        # the same slot over time average out (§VII).
+        cap = ring_len(cfg, max_len)
+        entry = _kv_zeros((batch,), cfg, cap, kv_quant, head_shards)
+        entry["k_pos"] = jnp.full((batch, cap), -1, jnp.int32)
+        return entry
     if kind == "rglru":
         return hybrid.init_rglru_state(cfg, batch)
     if kind == "ssm":
@@ -186,7 +238,7 @@ def _cache_entry(cfg: ModelConfig, kind: str, batch: int, max_len: int,
 
 def _paged_cache_entry(cfg: ModelConfig, kind: str, num_blocks: int,
                        block_size: int, kv_quant: bool,
-                       data_shards: int = 1):
+                       data_shards: int = 1, head_shards: int = 1):
     """One attention layer's share of the paged block pool (DESIGN.md §6):
     ``num_blocks`` usable blocks of ``block_size`` token slots each, plus a
     trailing *trash* block (physical id ``num_blocks``) that absorbs writes
@@ -197,34 +249,28 @@ def _paged_cache_entry(cfg: ModelConfig, kind: str, num_blocks: int,
     the leading block axis holds ``data_shards`` shard-local pools of
     ``num_blocks + 1`` blocks back to back, each with its *own* trash block,
     so block-table entries stay shard-local physical ids and every shard's
-    scatter/gather runs on its local (num_blocks+1, ...) slice."""
+    scatter/gather runs on its local (num_blocks+1, ...) slice.
+
+    Blocks are laid out by ``kv_shapes``; the quantiser still runs on the
+    logical (..., n_kv, hd) values, and only the store is transposed."""
     if kind != "attn":
         raise ValueError("paged KV layout requires attention-only layers")
-    nbp = data_shards * (num_blocks + 1)
-    nkv, hd = cfg.n_kv_heads, cfg.hd()
-    if kv_quant:
-        return {
-            "k": jnp.zeros((nbp, block_size, nkv, hd), jnp.int8),
-            "v": jnp.zeros((nbp, block_size, nkv, hd), jnp.int8),
-            "k_scale": jnp.zeros((nbp, block_size, nkv), jnp.float32),
-            "v_scale": jnp.zeros((nbp, block_size, nkv), jnp.float32),
-        }
-    return {
-        "k": jnp.zeros((nbp, block_size, nkv, hd), jnp.bfloat16),
-        "v": jnp.zeros((nbp, block_size, nkv, hd), jnp.bfloat16),
-    }
+    return _kv_zeros((data_shards * (num_blocks + 1),), cfg, block_size,
+                     kv_quant, head_shards)
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                kv_quant: bool = False, kv_layout: str = "ring",
                block_size: Optional[int] = None,
                num_blocks: Optional[int] = None,
-               data_shards: int = 1) -> Params:
+               data_shards: int = 1, head_shards: int = 1) -> Params:
     """Build the decode cache.  For the paged layout ``num_blocks`` counts
     usable blocks *per data shard* (``data_shards`` = 1 outside sharded
     serving, so it is simply the pool capacity) and ``block_tables`` entries
     are shard-local physical ids whose unset value is the shard-local trash
-    block ``num_blocks`` (DESIGN.md §6/§9)."""
+    block ``num_blocks`` (DESIGN.md §6/§9).  ``head_shards`` is the number
+    of shards the KV heads split into; int8 scale rows are padded per
+    shard (``scale_rows``)."""
     paged = kv_layout == "paged"
     if kv_layout not in ("ring", "paged"):
         raise ValueError(f"unknown kv_layout {kv_layout!r}")
@@ -244,16 +290,18 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         for pos in range(p_):
             kind = cfg.layer_kind(pos)
             one = (_paged_cache_entry(cfg, kind, num_blocks, block_size,
-                                      kv_quant, data_shards) if paged
-                   else _cache_entry(cfg, kind, batch, max_len, kv_quant))
+                                      kv_quant, data_shards, head_shards)
+                   if paged else _cache_entry(cfg, kind, batch, max_len,
+                                              kv_quant, head_shards))
             stacked.append(
                 jax.tree.map(lambda x: jnp.broadcast_to(x, (rep,) + x.shape), one)
             )
     remainder = [
         (_paged_cache_entry(cfg, cfg.layer_kind(rep * p_ + i), num_blocks,
-                            block_size, kv_quant, data_shards) if paged
-         else _cache_entry(cfg, cfg.layer_kind(rep * p_ + i), batch, max_len,
-                           kv_quant))
+                            block_size, kv_quant, data_shards, head_shards)
+         if paged else
+         _cache_entry(cfg, cfg.layer_kind(rep * p_ + i), batch, max_len,
+                      kv_quant, head_shards))
         for i in range(rem)
     ]
     # "pos" is *per-slot* (B,): the serving engine admits requests into slots
@@ -273,8 +321,56 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 # ---------------------------------------------------------------------------
 
 
+def _layer_of(stack, layer):
+    """One layer's entry of a layer-stacked cache entry (a dynamic slice)."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+        stack)
+
+
+def _with_layer(stack, layer, entry):
+    """``stack`` with layer ``layer`` replaced by ``entry``, in place."""
+    return jax.tree.map(
+        lambda a, e: jax.lax.dynamic_update_index_in_dim(
+            a, e.astype(a.dtype), layer, 0), stack, entry)
+
+
+def _write_positions(arr, layer, rows, slots, vals):
+    """Write per-position columns into layer ``layer`` of a layer-stacked,
+    positions-minor cache array, in place: ``vals[b, r]`` — an
+    (n_kv[, hd]) column, or a scalar for ``k_pos`` — lands at position
+    ``slots[b, r]`` of pool block / ring row ``rows[b, r]``.  A slot equal
+    to the array's length drops its write.  rows, slots (B, R) int32.
+
+    Scattering single positions into the lane axis, or gathering a window
+    at a dynamic lane offset, makes the chip's compiler re-lay the whole
+    array out around the op (each column is strided across tiles).  So
+    each write reads the whole block or ring row that holds its position,
+    sets the columns of every write of its slot that lands in it, and the
+    whole blocks are scattered back: a gather and a scatter over the major
+    (layer, block) axes only, in the array's own layout.  Copies of one
+    block therefore carry the same bytes; distinct slots never share a
+    block they write (a decoding slot owns its tail block, copy-on-write,
+    DESIGN.md §6) except the trash block, which is never read."""
+    length = arr.shape[-1]
+    blk = arr[layer, rows]                                # (B, R, *inner, len)
+    nin = blk.ndim - 3
+    # write u lands in block copy t when both name the same block
+    same = ((rows[:, :, None] == rows[:, None, :])
+            & (slots < length)[:, None, :])                  # (B, Rt, Ru)
+    lane = jnp.arange(length, dtype=jnp.int32)
+    spread = (slice(None), slice(None)) + (None,) * nin + (slice(None),)
+    vals = jnp.asarray(vals, arr.dtype)
+    vals = jnp.pad(vals, [(0, 0), (0, 0)] + [
+        (0, a - v) for a, v in zip(blk.shape[2:-1], vals.shape[2:])])
+    for u in range(rows.shape[1]):
+        hit = same[:, :, u, None] & (lane == slots[:, None, u, None])
+        blk = jnp.where(hit[spread], vals[:, u][:, None, ..., None], blk)
+    return arr.at[layer, rows].set(blk)
+
+
 def _attention_decode(params, cfg: ModelConfig, x, cache, pos, policy, counter,
-                      kv_offset=None, block_tables=None):
+                      kv_offset=None, block_tables=None, *, layer):
     """One-token attention against the KV cache.  x: (B, 1, d).
 
     ``pos`` is the per-slot absolute position — scalar or (B,) — so slots
@@ -284,9 +380,13 @@ def _attention_decode(params, cfg: ModelConfig, x, cache, pos, policy, counter,
     concurrent requests walk independent pulse sequences, DESIGN.md §6).
     ``block_tables`` (B, nbmax) selects the *paged* cache layout: the new
     token scatters into pool block ``block_tables[b, pos//bs]`` at in-block
-    slot ``pos % bs`` and attention gathers through the table
+    position ``pos % bs`` and attention gathers through the table
     (``dispatch.paged_decode_attention``); without it the cache is the
-    dense per-slot ring.
+    dense per-slot ring.  The cache entry is layer-stacked (a leading layer
+    axis; a single layer is a stack of one): the new token's codes and
+    scales are scattered into layer ``layer`` in place and the kernel reads
+    the layer where it lies, so no layer's cache is ever sliced out or
+    rewritten whole.
     """
     b = x.shape[0]
     hd, nh, nkv = cfg.hd(), cfg.n_heads, cfg.n_kv_heads
@@ -304,20 +404,24 @@ def _attention_decode(params, cfg: ModelConfig, x, cache, pos, policy, counter,
     q = layers.rope(q, posv, cfg.rope_theta)
     k = layers.rope(k, posv, cfg.rope_theta)
 
+    # position is the minor axis of every layout: K/V are
+    # (..., n_kv, hd, bs|cap), scales (..., n_kv, bs|cap)
+    length = cache["k"].shape[-1]
     if paged:
-        bs = cache["k"].shape[1]
         # physical block holding this token; engine guarantees it is
         # allocated (and uniquely owned — copy-on-write happens host-side)
         # before the tick, or points at the trash block for idle slots
-        phys = jnp.take_along_axis(block_tables, (pos // bs)[:, None],
-                                   axis=1)[:, 0]
-        slot = jnp.mod(pos, bs)
+        row = jnp.take_along_axis(block_tables, (pos // length)[:, None],
+                                  axis=1)[:, 0]
     else:
-        cap = cache["k"].shape[1]
-        rows = jnp.arange(b)
-        slot = jnp.mod(pos, cap)
-    quantized = cache["k"].dtype == jnp.int8
-    if quantized:
+        row = jnp.arange(b)
+    rows, slots = row[:, None], jnp.mod(pos, length)[:, None]   # (B, 1)
+
+    def write(name, vals):
+        return _write_positions(cache[name], layer, rows, slots, vals)
+
+    new_cache = {}
+    if cache["k"].dtype == jnp.int8:
         # dither-round the new K/V token into int8 codes; the counter is the
         # per-slot absolute position (+ per-request offset)
         ctr = pos if kv_offset is None else pos + jnp.broadcast_to(
@@ -326,31 +430,14 @@ def _attention_decode(params, cfg: ModelConfig, x, cache, pos, policy, counter,
         idx4 = _kv_elem_idx(nkv, hd)
         kq, ks = _kv_q8(k, ctr4, idx4, 101)
         vq, vs = _kv_q8(v, ctr4, idx4, 102)
-        if paged:
-            new_cache = {
-                "k": cache["k"].at[phys, slot].set(kq[:, 0]),
-                "v": cache["v"].at[phys, slot].set(vq[:, 0]),
-                "k_scale": cache["k_scale"].at[phys, slot].set(ks[:, 0]),
-                "v_scale": cache["v_scale"].at[phys, slot].set(vs[:, 0]),
-            }
-        else:
-            new_cache = {
-                "k": cache["k"].at[rows, slot].set(kq[:, 0]),
-                "v": cache["v"].at[rows, slot].set(vq[:, 0]),
-                "k_scale": cache["k_scale"].at[rows, slot].set(ks[:, 0]),
-                "v_scale": cache["v_scale"].at[rows, slot].set(vs[:, 0]),
-                "k_pos": cache["k_pos"].at[rows, slot].set(pos),
-            }
-    elif paged:
-        new_cache = {
-            "k": cache["k"].at[phys, slot].set(k[:, 0].astype(cache["k"].dtype)),
-            "v": cache["v"].at[phys, slot].set(v[:, 0].astype(cache["v"].dtype)),
-        }
+        new_cache["k_scale"] = write("k_scale", ks)
+        new_cache["v_scale"] = write("v_scale", vs)
     else:
-        ck = cache["k"].at[rows, slot].set(k[:, 0].astype(cache["k"].dtype))
-        cv = cache["v"].at[rows, slot].set(v[:, 0].astype(cache["v"].dtype))
-        k_pos = cache["k_pos"].at[rows, slot].set(pos)
-        new_cache = {"k": ck, "v": cv, "k_pos": k_pos}
+        kq, vq = k, v
+    new_cache["k"] = write("k", kq)
+    new_cache["v"] = write("v", vq)
+    if not paged:
+        new_cache["k_pos"] = write("k_pos", pos[:, None])
 
     # flash-decode over the cache through the kernel dispatcher (DESIGN.md
     # §2/§3): int8 codes stay codes — upcast tile-by-tile in VMEM,
@@ -367,14 +454,14 @@ def _attention_decode(params, cfg: ModelConfig, x, cache, pos, policy, counter,
             qg, new_cache["k"], new_cache["v"], block_tables, pos,
             k_scale=new_cache.get("k_scale"),
             v_scale=new_cache.get("v_scale"),
-            window=cfg.window or 0,
+            layer=layer, window=cfg.window or 0,
         )
     else:
         attn = _dispatch.decode_attention(
             qg, new_cache["k"], new_cache["v"], new_cache["k_pos"], pos,
             k_scale=new_cache.get("k_scale"),
             v_scale=new_cache.get("v_scale"),
-            window=cfg.window or 0,
+            layer=layer, window=cfg.window or 0,
         )
     # sharded serving: heads all-gather before the replicated W_O so the
     # output contraction stays whole (bitwise contract, DESIGN.md §9);
@@ -403,7 +490,11 @@ def _apply_block(
     kv_offset=None,
     collect_kv=False,
     block_tables=None,
+    layer=None,
 ):
+    """One block.  A ``cache_entry`` is layer-stacked and ``layer`` indexes
+    it: the attention writes and reads that layer in place; a recurrent
+    state is small, and is sliced out and written back."""
     h = layers.rms_norm(x, bp["ln1"], cfg.norm_eps)
     new_cache = cache_entry
     if kind == "attn":
@@ -412,7 +503,8 @@ def _apply_block(
             out, new_cache = _attention_decode(bp["attn"], cfg, h, cache_entry,
                                                pos, policy, counter,
                                                kv_offset=kv_offset,
-                                               block_tables=block_tables)
+                                               block_tables=block_tables,
+                                               layer=layer)
         else:
             out, kv = layers.attention(
                 bp["attn"], cfg, h, positions, causal=True, window=window,
@@ -420,16 +512,16 @@ def _apply_block(
             )
             if collect_kv:
                 new_cache = kv
-    elif kind == "rglru":
+    elif kind in ("rglru", "ssm"):
+        step, block_fn, key = ((hybrid.rglru_decode_step, hybrid.rglru_block,
+                                "rec") if kind == "rglru" else
+                               (ssm.ssm_decode_step, ssm.ssm_block, "ssm"))
         if cache_entry is not None:
-            out, new_cache = hybrid.rglru_decode_step(bp["rec"], cfg, h, cache_entry, policy, counter)
+            state = _layer_of(cache_entry, layer)
+            out, new_state = step(bp[key], cfg, h, state, policy, counter)
+            new_cache = _with_layer(cache_entry, layer, new_state)
         else:
-            out = hybrid.rglru_block(bp["rec"], cfg, h, policy, counter)
-    elif kind == "ssm":
-        if cache_entry is not None:
-            out, new_cache = ssm.ssm_decode_step(bp["ssm"], cfg, h, cache_entry, policy, counter)
-        else:
-            out = ssm.ssm_block(bp["ssm"], cfg, h, policy, counter)
+            out = block_fn(bp[key], cfg, h, policy, counter)
     else:
         raise ValueError(kind)
     x = x + out
@@ -504,6 +596,18 @@ def prefill(params, cfg, tokens, *, embeds=None, policy=None, counter=0):
                    counter=counter, remat=False)
 
 
+def _positions_minor(t):
+    """(B, L, n_kv[, hd]) values or scales, position-major as the model
+    computes them → the cache's stored (B, n_kv[, hd], L)."""
+    return jnp.moveaxis(t, 1, -1)
+
+
+def _positions_major(t):
+    """Inverse of ``_positions_minor``: a stored (B, n_kv[, hd], L) cache
+    array → (B, L, n_kv[, hd])."""
+    return jnp.moveaxis(t, -1, 1)
+
+
 def _prefill_entry(cfg: ModelConfig, kv, lengths, cap: int, kv_quant: bool,
                    kv_offset):
     """Scatter one attention layer's full-sequence K/V into a ring cache entry.
@@ -528,8 +632,10 @@ def _prefill_entry(cfg: ModelConfig, kv, lengths, cap: int, kv_quant: bool,
     if not kv_quant:
         zero = jnp.zeros((), jnp.bfloat16)
         return {
-            "k": jnp.where(valid[:, :, None, None], gk.astype(jnp.bfloat16), zero),
-            "v": jnp.where(valid[:, :, None, None], gv.astype(jnp.bfloat16), zero),
+            "k": _positions_minor(jnp.where(valid[:, :, None, None],
+                                            gk.astype(jnp.bfloat16), zero)),
+            "v": _positions_minor(jnp.where(valid[:, :, None, None],
+                                            gv.astype(jnp.bfloat16), zero)),
             "k_pos": k_pos,
         }
 
@@ -548,7 +654,11 @@ def _prefill_entry(cfg: ModelConfig, kv, lengths, cap: int, kv_quant: bool,
 
     kq, ks = q8(gk, 101)
     vq, vs = q8(gv, 102)
-    return {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs, "k_pos": k_pos}
+    srows = scale_rows(nkv)
+    return {"k": _positions_minor(kq), "v": _positions_minor(vq),
+            "k_scale": _pad_rows(_positions_minor(ks), srows),
+            "v_scale": _pad_rows(_positions_minor(vs), srows),
+            "k_pos": k_pos}
 
 
 def prefill_with_cache(
@@ -610,8 +720,8 @@ def prefill_with_cache(
     logits = dense(x, head, policy, counter, seed=9).astype(jnp.float32)
     logits = logits[:, :, : cfg.vocab_size]
 
-    cap = min(cfg.window, max_len) if cfg.window else max_len
-    entry = functools.partial(_prefill_entry, cfg, lengths=lengths, cap=cap,
+    entry = functools.partial(_prefill_entry, cfg, lengths=lengths,
+                              cap=ring_len(cfg, max_len),
                               kv_quant=kv_quant, kv_offset=kv_offset)
     # stacked pattern positions carry a leading repeat axis — vmap over it
     stacked = [jax.vmap(lambda kv: entry(kv))(kv) for kv in kv_stacked]
@@ -637,8 +747,9 @@ def _ring_scatter_chunk(entry, k, v, lengths, starts, kv_quant: bool,
     quantises with counter = absolute position (+ per-request offset) and
     the decode-step element indices, so a chunk writes codes bit-identical
     to what whole-prompt prefill or token-by-token decode would have left
-    at the same positions (DESIGN.md §6/§11)."""
-    cap = entry["k"].shape[1]
+    at the same positions (DESIGN.md §6/§11).  The entry is stored with
+    positions minor; the select runs in the stored layout."""
+    cap = entry["k"].shape[-1]
     b, s = k.shape[0], k.shape[1]
     nkv, hd = k.shape[2], k.shape[3]
     j = jnp.arange(cap, dtype=jnp.int32)[None, :]              # (1, cap)
@@ -650,12 +761,14 @@ def _ring_scatter_chunk(entry, k, v, lengths, starts, kv_quant: bool,
     gk = jnp.take_along_axis(k, idx[:, :, None, None], axis=1)
     gv = jnp.take_along_axis(v, idx[:, :, None, None], axis=1)
     k_pos = jnp.where(covered, pj, entry["k_pos"]).astype(jnp.int32)
-    c4 = covered[:, :, None, None]
+    c4 = covered[:, None, None, :]                             # (B, 1, 1, cap)
 
     if not kv_quant:
         dt = entry["k"].dtype
-        return {"k": jnp.where(c4, gk.astype(dt), entry["k"]),
-                "v": jnp.where(c4, gv.astype(dt), entry["v"]),
+        return {"k": jnp.where(c4, _positions_minor(gk.astype(dt)),
+                               entry["k"]),
+                "v": jnp.where(c4, _positions_minor(gv.astype(dt)),
+                               entry["v"]),
                 "k_pos": k_pos}
 
     off = (jnp.zeros((b,), jnp.int32) if kv_offset is None
@@ -664,11 +777,16 @@ def _ring_scatter_chunk(entry, k, v, lengths, starts, kv_quant: bool,
     idx4 = _kv_elem_idx(nkv, hd)
     kq, ks = _kv_q8(gk, ctr, idx4, 101)
     vq, vs = _kv_q8(gv, ctr, idx4, 102)
-    c3 = covered[:, :, None]
-    return {"k": jnp.where(c4, kq, entry["k"]),
-            "v": jnp.where(c4, vq, entry["v"]),
-            "k_scale": jnp.where(c3, ks, entry["k_scale"]),
-            "v_scale": jnp.where(c3, vs, entry["v_scale"]),
+    c3 = covered[:, None, :]
+    srows = entry["k_scale"].shape[-2]
+
+    def scales(t, old):
+        return jnp.where(c3, _pad_rows(_positions_minor(t), srows), old)
+
+    return {"k": jnp.where(c4, _positions_minor(kq), entry["k"]),
+            "v": jnp.where(c4, _positions_minor(vq), entry["v"]),
+            "k_scale": scales(ks, entry["k_scale"]),
+            "v_scale": scales(vs, entry["v_scale"]),
             "k_pos": k_pos}
 
 
@@ -709,12 +827,14 @@ def _ring_chunk_attention(params, cfg: ModelConfig, x, positions, lengths,
     # history join: the ring holds every still-reachable earlier position
     # (k_pos ∈ [0, start)); garbage slots carry k_pos = -1 or ≥ start and
     # mask out, so idle-window writes never leak into chunk attention
-    hk, hv = entry["k"], entry["v"]
+    hk, hv = _positions_major(entry["k"]), _positions_major(entry["v"])
     if "k_scale" in entry:
         hk = (hk.astype(jnp.float32)
-              * (entry["k_scale"][..., None] / 127.0)).astype(x.dtype)
+              * (_positions_major(entry["k_scale"])[..., :nkv, None] / 127.0)
+              ).astype(x.dtype)
         hv = (hv.astype(jnp.float32)
-              * (entry["v_scale"][..., None] / 127.0)).astype(x.dtype)
+              * (_positions_major(entry["v_scale"])[..., :nkv, None] / 127.0)
+              ).astype(x.dtype)
     kp = entry["k_pos"][:, None, :]                        # (B, 1, cap)
     q_abs = positions[:, :, None]                          # (B, S, 1)
     vp = (kp >= 0) & (kp < starts[:, None, None])
@@ -723,7 +843,7 @@ def _ring_chunk_attention(params, cfg: ModelConfig, x, positions, lengths,
     logits_p = jnp.einsum("bqhgd,bkhd->bhgqk", qg, hk).astype(jnp.float32) \
         / _math.sqrt(hd)
     logits_p = jnp.where(vp[:, None, None, :, :], logits_p, -1e30)
-    cap = entry["k"].shape[1]
+    cap = entry["k"].shape[-1]
     probs = jax.nn.softmax(
         jnp.concatenate([logits_p, logits_s], axis=-1), axis=-1
     ).astype(x.dtype)
@@ -830,36 +950,43 @@ def prefill_with_cache_chunked(
 # ---------------------------------------------------------------------------
 
 
-def _gather_prefix(entry, block_tables, prefix_blocks: int):
+def _gather_prefix(entry, layer, block_tables, prefix_blocks: int):
     """Gather the leading ``prefix_blocks`` logical blocks of every slot
-    from one layer's pool → tensors over (B, prefix_blocks·bs, ...).
-    Unallocated table entries point at the trash block; the caller masks
-    those positions (implicit position ≥ the slot's prefix length)."""
-    bt = block_tables[:, :prefix_blocks]                   # (B, P)
-    gk = jnp.take(entry["k"], bt, axis=0)                  # (B, P, bs, nkv, hd)
-    gv = jnp.take(entry["v"], bt, axis=0)
-    b, p, bs = gk.shape[0], gk.shape[1], gk.shape[2]
-    out = [gk.reshape(b, p * bs, *gk.shape[3:]),
-           gv.reshape(b, p * bs, *gv.shape[3:])]
+    from layer ``layer`` of a layer-stacked pool entry → position-major
+    tensors over
+    (B, prefix_blocks·bs, ...).  Only those blocks are read.  Unallocated
+    table entries point at the trash block; the caller masks those
+    positions (implicit position ≥ the slot's prefix length)."""
+    ix = (layer, block_tables[:, :prefix_blocks])          # (B, P) blocks
+
+    def gather(x):            # (B, P, n_kv[, hd], bs) → (B, P·bs, n_kv[, hd])
+        g = jnp.moveaxis(x[ix], -1, 2)
+        return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
+
+    out = [gather(entry["k"]), gather(entry["v"])]
     if "k_scale" in entry:
-        out += [jnp.take(entry["k_scale"], bt, axis=0).reshape(b, p * bs, -1),
-                jnp.take(entry["v_scale"], bt, axis=0).reshape(b, p * bs, -1)]
+        nkv = entry["k"].shape[-3]            # scale rows past it are padding
+        out += [gather(entry["k_scale"])[..., :nkv],
+                gather(entry["v_scale"])[..., :nkv]]
     else:
         out += [None, None]
     return out
 
 
-def _paged_scatter_entry(entry, k, v, positions, lengths, starts,
+def _paged_scatter_entry(entry, layer, k, v, positions, lengths, starts,
                          block_tables, kv_quant: bool, kv_offset):
     """Scatter one layer's suffix K/V (post-RoPE, (B, S, nkv, hd)) into its
-    pool blocks.  Suffix token s lands in logical block ``starts//bs + s//bs``
-    at in-block slot ``s % bs`` (starts are block-aligned); blocks beyond the
-    suffix length route to the trash block.  The int8 path quantises with
-    counter = absolute position (+ per-request offset) and the decode-step
-    element indices, so the codes are bit-identical to what token-by-token
-    decode would have written — the bit-reusability contract behind prefix
-    sharing (DESIGN.md §6)."""
-    nbp, bs = entry["k"].shape[0], entry["k"].shape[1]
+    pool blocks — layer ``layer`` of a layer-stacked entry, in place.
+    Suffix token s lands in logical block
+    ``starts//bs + s//bs`` at in-block position ``s % bs`` (starts are
+    block-aligned); blocks beyond the suffix length route to the trash
+    block.  Whole blocks are written, transposed to the pool's
+    positions-minor layout.  The int8 path quantises with counter =
+    absolute position (+ per-request offset) and the decode-step element
+    indices, on the logical (…, nkv, hd) values, so the codes are
+    bit-identical to what token-by-token decode would have written — the
+    bit-reusability contract behind prefix sharing (DESIGN.md §6)."""
+    nbp, bs = entry["k"].shape[-4], entry["k"].shape[-1]
     trash = nbp - 1
     b, s = k.shape[0], k.shape[1]
     nkv, hd = k.shape[2], k.shape[3]
@@ -876,14 +1003,16 @@ def _paged_scatter_entry(entry, k, v, positions, lengths, starts,
     tj = jnp.clip(starts[:, None] // bs + jb, 0, nbmax - 1)
     phys = jnp.where(needed, jnp.take_along_axis(block_tables, tj, axis=1),
                      trash).reshape(-1)                              # (B·JB,)
+    ix = (layer, phys)
 
-    def blocks(t):
-        return t.reshape((b * jb_count, bs) + t.shape[2:])
+    def blocks(t):            # (B, S_pad, nkv[, hd]) → (B·JB, nkv[, hd], bs)
+        t = t.reshape((b * jb_count, bs) + t.shape[2:])
+        return jnp.moveaxis(t, 1, -1)
 
     if not kv_quant:
         dt = entry["k"].dtype
-        return {"k": entry["k"].at[phys].set(blocks(k.astype(dt))),
-                "v": entry["v"].at[phys].set(blocks(v.astype(dt)))}
+        return {"k": entry["k"].at[ix].set(blocks(k.astype(dt))),
+                "v": entry["v"].at[ix].set(blocks(v.astype(dt)))}
 
     off = (jnp.zeros((b,), jnp.int32) if kv_offset is None
            else jnp.broadcast_to(jnp.asarray(kv_offset, jnp.int32), (b,)))
@@ -891,15 +1020,19 @@ def _paged_scatter_entry(entry, k, v, positions, lengths, starts,
     idx4 = _kv_elem_idx(nkv, hd)
     kq, ks = _kv_q8(k, ctr, idx4, 101)
     vq, vs = _kv_q8(v, ctr, idx4, 102)
-    return {"k": entry["k"].at[phys].set(blocks(kq)),
-            "v": entry["v"].at[phys].set(blocks(vq)),
-            "k_scale": entry["k_scale"].at[phys].set(blocks(ks)),
-            "v_scale": entry["v_scale"].at[phys].set(blocks(vs))}
+    srows = entry["k_scale"].shape[-2]
+    return {"k": entry["k"].at[ix].set(blocks(kq)),
+            "v": entry["v"].at[ix].set(blocks(vq)),
+            "k_scale": entry["k_scale"].at[ix].set(
+                _pad_rows(blocks(ks), srows)),
+            "v_scale": entry["v_scale"].at[ix].set(
+                _pad_rows(blocks(vs), srows))}
 
 
 def _paged_prefill_attention(params, cfg: ModelConfig, x, positions, lengths,
-                             starts, block_tables, entry, policy, counter,
-                             kv_quant: bool, kv_offset, prefix_blocks: int):
+                             starts, block_tables, entry, layer, policy,
+                             counter, kv_quant: bool, kv_offset,
+                             prefix_blocks: int):
     """Suffix attention for the paged prefill: queries at absolute positions
     ``starts + t`` attend the in-batch suffix K/V (relative-causal, exactly
     the cold path's ``layers.attention`` grouped-einsum ops) plus — when
@@ -934,7 +1067,8 @@ def _paged_prefill_attention(params, cfg: ModelConfig, x, positions, lengths,
     logits_s = jnp.where(m_ss[None, None, None, :, :], logits_s, -1e30)
 
     if prefix_blocks:
-        pk, pv, pks, pvs = _gather_prefix(entry, block_tables, prefix_blocks)
+        pk, pv, pks, pvs = _gather_prefix(entry, layer, block_tables,
+                                          prefix_blocks)
         if pks is not None:
             pk = (pk.astype(jnp.float32) * (pks[..., None] / 127.0)).astype(x.dtype)
             pv = (pv.astype(jnp.float32) * (pvs[..., None] / 127.0)).astype(x.dtype)
@@ -958,21 +1092,21 @@ def _paged_prefill_attention(params, cfg: ModelConfig, x, positions, lengths,
     out = dist_ctx.gather_heads(out.reshape(b, s, nh * hd))
     out = dense(out, params["wo"], policy, counter, seed=4)
 
-    new_entry = _paged_scatter_entry(entry, k, v, positions, lengths, starts,
-                                     block_tables, kv_quant, kv_offset)
+    new_entry = _paged_scatter_entry(entry, layer, k, v, positions, lengths,
+                                     starts, block_tables, kv_quant, kv_offset)
     return out, new_entry
 
 
 def _paged_prefill_block(bp, cfg: ModelConfig, x, positions, lengths, starts,
-                         block_tables, entry, policy, counter, kv_quant,
-                         kv_offset, prefix_blocks):
+                         block_tables, entry, layer, policy, counter,
+                         kv_quant, kv_offset, prefix_blocks):
     """One transformer block of the paged prefill — ``_apply_block``'s attn
     branch with the prefix-aware attention above in place of
     ``layers.attention``."""
     h = layers.rms_norm(x, bp["ln1"], cfg.norm_eps)
     out, new_entry = _paged_prefill_attention(
         bp["attn"], cfg, h, positions, lengths, starts, block_tables, entry,
-        policy, counter, kv_quant, kv_offset, prefix_blocks)
+        layer, policy, counter, kv_quant, kv_offset, prefix_blocks)
     x = x + out
     if "mlp" in bp or "moe" in bp:
         h2 = layers.rms_norm(x, bp["ln2"], cfg.norm_eps)
@@ -1019,32 +1153,16 @@ def prefill_with_cache_paged(
     starts = jnp.asarray(starts, jnp.int32)
     block_tables = jnp.asarray(block_tables, jnp.int32)
     positions = starts[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-    p_ = _period(cfg)
 
-    def body(carry, xs):
-        h = carry
-        bp, ce = xs
-        new_entries = []
-        for pos_i in range(p_):
-            h, ne = _paged_prefill_block(
-                bp[pos_i], cfg, h, positions, lengths, starts, block_tables,
-                ce[pos_i], policy, counter, kv_quant, kv_offset,
-                prefix_blocks)
-            new_entries.append(ne)
-        return h, tuple(new_entries)
+    def block(bp, kind, h, entry, layer):
+        del kind  # attention-only (checked above)
+        return _paged_prefill_block(
+            bp, cfg, h, positions, lengths, starts, block_tables, entry,
+            layer, policy, counter, kv_quant, kv_offset, prefix_blocks)
 
-    if params["blocks"]:
-        x, new_layers = jax.lax.scan(
-            body, x, (tuple(params["blocks"]), tuple(cache["layers"])))
-    else:
-        new_layers = ()
-    new_rem = []
-    for i, bp in enumerate(params["remainder"]):
-        x, ne = _paged_prefill_block(
-            bp, cfg, x, positions, lengths, starts, block_tables,
-            cache["remainder"][i], policy, counter, kv_quant, kv_offset,
-            prefix_blocks)
-        new_rem.append(ne)
+    # the pool rides in the layer scan's carry: each layer gathers only
+    # its prefix blocks and writes only the suffix blocks, in place
+    x, new_layers, new_rem = _scan_layers(params, cfg, cache, x, block)
 
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -1053,7 +1171,7 @@ def prefill_with_cache_paged(
     new_cache = {
         "pos": jnp.where(lengths > 0, starts + lengths, cache["pos"]),
         "block_tables": block_tables,
-        "layers": list(new_layers),
+        "layers": new_layers,
         "remainder": new_rem,
     }
     return logits, new_cache
@@ -1085,6 +1203,40 @@ def merge_cache(old: Params, new: Params, active: jax.Array) -> Params:
     }
 
 
+def _scan_layers(params, cfg: ModelConfig, cache, x, block):
+    """Run ``block(bp, kind, h, entry, layer) -> (h, entry')`` over every
+    layer: one ``lax.scan`` over the stacked repeats, then the unrolled
+    remainder.  The stacked cache entries ride in the scan's *carry* with
+    the repeat index as ``layer``, so each block updates its layer of the
+    stack in place — carried as scanned inputs and outputs instead, the
+    stack would be sliced per layer, rewritten whole into a fresh output
+    stack and copied back into the loop state on every call.  A remainder
+    entry is a single layer: it runs as a stack of one, at layer 0.
+    Returns ``(x, stacked entries', remainder entries')``."""
+    p_ = _period(cfg)
+    rep = cfg.n_layers // p_
+    stacks = tuple(cache["layers"])
+    if params["blocks"]:
+        def body(carry, xs):
+            h, st = carry
+            bp, r = xs
+            st = list(st)
+            for pos_i in range(p_):
+                h, st[pos_i] = block(bp[pos_i], cfg.layer_kind(pos_i), h,
+                                     st[pos_i], r)
+            return (h, tuple(st)), None
+
+        (x, stacks), _ = jax.lax.scan(
+            body, (x, stacks),
+            (tuple(params["blocks"]), jnp.arange(rep, dtype=jnp.int32)))
+    new_rem = []
+    for i, bp in enumerate(params["remainder"]):
+        one = jax.tree.map(lambda a: a[None], cache["remainder"][i])
+        x, ne = block(bp, cfg.layer_kind(rep * p_ + i), x, one, 0)
+        new_rem.append(jax.tree.map(lambda a: a[0], ne))
+    return x, list(stacks), new_rem
+
+
 def decode_step(
     params: Params,
     cfg: ModelConfig,
@@ -1103,6 +1255,11 @@ def decode_step(
     ``block_tables`` decodes against the paged block pool instead of the
     ring (the tables are loop-invariant across layers — every layer's pool
     shares one logical→physical map).
+
+    The layer scan carries the stacked cache as loop state, not as scanned
+    inputs and outputs: each layer writes its new token into the donated
+    stack in place and the kernel reads that layer where it lies, so no
+    step copies, slices or rewrites a layer's cache (``_scan_layers``).
     """
     pos = cache["pos"]
     block_tables = cache.get("block_tables")
@@ -1110,46 +1267,21 @@ def decode_step(
     b = x.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     positions = pos[:, None]
-    p_ = _period(cfg)
 
-    def body(carry, xs):
-        h = carry
-        bp, ce = xs
-        new_entries = []
-        for pos_i in range(p_):
-            kind = cfg.layer_kind(pos_i)
-            h, ne = _apply_block(
-                bp[pos_i], cfg, kind, h, positions, policy=policy,
-                counter=counter, cache_entry=ce[pos_i], pos=pos,
-                kv_offset=kv_offset, block_tables=block_tables,
-            )
-            new_entries.append(ne)
-        return h, tuple(new_entries)
+    def block(bp, kind, h, entry, layer):
+        return _apply_block(
+            bp, cfg, kind, h, positions, policy=policy, counter=counter,
+            cache_entry=entry, pos=pos, kv_offset=kv_offset,
+            block_tables=block_tables, layer=layer)
 
-    if params["blocks"]:
-        x, new_layer_caches = jax.lax.scan(
-            body, x, (tuple(params["blocks"]), tuple(cache["layers"]))
-        )
-    else:
-        new_layer_caches = ()
-    rep = cfg.n_layers // p_
-    new_rem = []
-    for i, bp in enumerate(params["remainder"]):
-        kind = cfg.layer_kind(rep * p_ + i)
-        x, ne = _apply_block(
-            bp, cfg, kind, x, positions, policy=policy, counter=counter,
-            cache_entry=cache["remainder"][i], pos=pos, kv_offset=kv_offset,
-            block_tables=block_tables,
-        )
-        new_rem.append(ne)
-
+    x, new_layer_caches, new_rem = _scan_layers(params, cfg, cache, x, block)
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = dense(x, head, policy, counter, seed=9)[:, 0].astype(jnp.float32)
     logits = logits[:, : cfg.vocab_size]  # drop vocab padding for sampling
     new_cache = {
         "pos": pos + 1,
-        "layers": list(new_layer_caches),
+        "layers": new_layer_caches,
         "remainder": new_rem,
     }
     if block_tables is not None:
@@ -1164,7 +1296,7 @@ def decode_step(
 
 def _attention_verify(params, cfg: ModelConfig, x, cache, pos, policy, counter,
                       kv_offset=None, alive=None, wcap=None,
-                      block_tables=None, *, kq):
+                      block_tables=None, *, kq, layer):
     """k-token verify attention against the KV cache.  x: (B·K, 1, d),
     slot-major: row b·K + t is slot b's draft row t.
 
@@ -1210,53 +1342,41 @@ def _attention_verify(params, cfg: ModelConfig, x, cache, pos, policy, counter,
     writable = ((offs < jnp.asarray(wcap, jnp.int32)[:, None])
                 & jnp.asarray(alive, bool)[:, None])            # (B, K)
 
+    # positions are minor (see _attention_decode); ``layer`` indexes the
+    # layer-stacked entry, written in place
+    length = cache["k"].shape[-1]
     if paged:
-        bs = cache["k"].shape[1]
-        nbp = cache["k"].shape[0]
-        lb = jnp.clip(posv // bs, 0, block_tables.shape[1] - 1)
-        phys = jnp.take_along_axis(block_tables, lb, axis=1)
+        nbp = cache["k"].shape[-4]
+        lb = jnp.clip(posv // length, 0, block_tables.shape[1] - 1)
+        row = jnp.take_along_axis(block_tables, lb, axis=1)
         # non-writable draft positions go to the trash block (their logical
         # block may be unallocated or beyond this row's write budget)
-        phys = jnp.where(writable, phys, nbp - 1)
-        slot = jnp.mod(posv, bs)
+        row = jnp.where(writable, row, nbp - 1)
+        slot = jnp.mod(posv, length)
     else:
-        cap = cache["k"].shape[1]
-        rows = jnp.arange(b)[:, None]
-        # slot == cap is out of bounds: the scatter drops those writes
-        slot = jnp.where(writable, jnp.mod(posv, cap), cap)
-    quantized = cache["k"].dtype == jnp.int8
-    if quantized:
+        row = jnp.broadcast_to(jnp.arange(b)[:, None], (b, kq))
+        # slot == cap is out of bounds: the write drops it
+        slot = jnp.where(writable, jnp.mod(posv, length), length)
+
+    def write(name, vals):
+        return _write_positions(cache[name], layer, row, slot, vals)
+
+    new_cache = {}
+    if cache["k"].dtype == jnp.int8:
         ctr = posv if kv_offset is None else posv + jnp.broadcast_to(
             jnp.asarray(kv_offset, jnp.int32), (b,))[:, None]
         ctr4 = ctr.reshape(b, kq, 1, 1)
         idx4 = _kv_elem_idx(nkv, hd)
         k8, ks = _kv_q8(k, ctr4, idx4, 101)
         v8, vs = _kv_q8(v, ctr4, idx4, 102)
-        if paged:
-            new_cache = {
-                "k": cache["k"].at[phys, slot].set(k8),
-                "v": cache["v"].at[phys, slot].set(v8),
-                "k_scale": cache["k_scale"].at[phys, slot].set(ks),
-                "v_scale": cache["v_scale"].at[phys, slot].set(vs),
-            }
-        else:
-            new_cache = {
-                "k": cache["k"].at[rows, slot].set(k8),
-                "v": cache["v"].at[rows, slot].set(v8),
-                "k_scale": cache["k_scale"].at[rows, slot].set(ks),
-                "v_scale": cache["v_scale"].at[rows, slot].set(vs),
-                "k_pos": cache["k_pos"].at[rows, slot].set(posv),
-            }
-    elif paged:
-        new_cache = {
-            "k": cache["k"].at[phys, slot].set(k.astype(cache["k"].dtype)),
-            "v": cache["v"].at[phys, slot].set(v.astype(cache["v"].dtype)),
-        }
+        new_cache["k_scale"] = write("k_scale", ks)
+        new_cache["v_scale"] = write("v_scale", vs)
     else:
-        ck = cache["k"].at[rows, slot].set(k.astype(cache["k"].dtype))
-        cv = cache["v"].at[rows, slot].set(v.astype(cache["v"].dtype))
-        k_pos = cache["k_pos"].at[rows, slot].set(posv)
-        new_cache = {"k": ck, "v": cv, "k_pos": k_pos}
+        k8, v8 = k, v
+    new_cache["k"] = write("k", k8)
+    new_cache["v"] = write("v", v8)
+    if not paged:
+        new_cache["k_pos"] = write("k_pos", posv)
 
     from repro.kernels import dispatch as _dispatch
 
@@ -1267,14 +1387,14 @@ def _attention_verify(params, cfg: ModelConfig, x, cache, pos, policy, counter,
             qg, new_cache["k"], new_cache["v"], block_tables, pos,
             k_scale=new_cache.get("k_scale"),
             v_scale=new_cache.get("v_scale"),
-            window=cfg.window or 0,
+            layer=layer, window=cfg.window or 0,
         )
     else:
         attn = _dispatch.verify_attention(
             qg, new_cache["k"], new_cache["v"], new_cache["k_pos"], pos,
             k_scale=new_cache.get("k_scale"),
             v_scale=new_cache.get("v_scale"),
-            window=cfg.window or 0,
+            layer=layer, window=cfg.window or 0,
         )
     out = dist_ctx.gather_heads(
         attn.astype(x.dtype).reshape(b * kq, 1, nh * hd))
@@ -1283,7 +1403,7 @@ def _attention_verify(params, cfg: ModelConfig, x, cache, pos, policy, counter,
 
 def _apply_verify_block(bp, cfg: ModelConfig, x, *, policy, counter,
                         cache_entry, pos, kv_offset, alive, wcap,
-                        block_tables, kq):
+                        block_tables, kq, layer):
     """Verify-forward transformer block over x (B·K, 1, d): attention-only
     archs (the ``supports_spec_decode`` gate), so no SSM/RG-LRU branches.
     MLP and norms see the decode step's (rows, 1, d) shape; MoE is excluded
@@ -1294,7 +1414,8 @@ def _apply_verify_block(bp, cfg: ModelConfig, x, *, policy, counter,
     out, new_cache = _attention_verify(bp["attn"], cfg, h, cache_entry, pos,
                                        policy, counter, kv_offset=kv_offset,
                                        alive=alive, wcap=wcap,
-                                       block_tables=block_tables, kq=kq)
+                                       block_tables=block_tables, kq=kq,
+                                       layer=layer)
     x = x + out
     if "mlp" in bp or "moe" in bp:
         h2 = layers.rms_norm(x, bp["ln2"], cfg.norm_eps)
@@ -1338,36 +1459,15 @@ def verify_step(
     b, kq = tokens.shape
     x = jnp.take(params["embed"], tokens.reshape(b * kq, 1), axis=0)
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    p_ = _period(cfg)
 
-    def body(carry, xs):
-        h = carry
-        bp, ce = xs
-        new_entries = []
-        for pos_i in range(p_):
-            h, ne = _apply_verify_block(
-                bp[pos_i], cfg, h, policy=policy, counter=counter,
-                cache_entry=ce[pos_i], pos=pos, kv_offset=kv_offset,
-                alive=alive, wcap=wcap, block_tables=block_tables, kq=kq,
-            )
-            new_entries.append(ne)
-        return h, tuple(new_entries)
+    def block(bp, kind, h, entry, layer):
+        del kind  # attention-only (the supports_spec_decode gate)
+        return _apply_verify_block(
+            bp, cfg, h, policy=policy, counter=counter, cache_entry=entry,
+            pos=pos, kv_offset=kv_offset, alive=alive, wcap=wcap,
+            block_tables=block_tables, kq=kq, layer=layer)
 
-    if params["blocks"]:
-        x, new_layer_caches = jax.lax.scan(
-            body, x, (tuple(params["blocks"]), tuple(cache["layers"]))
-        )
-    else:
-        new_layer_caches = ()
-    rep = cfg.n_layers // p_
-    new_rem = []
-    for i, bp in enumerate(params["remainder"]):
-        x, ne = _apply_verify_block(
-            bp, cfg, x, policy=policy, counter=counter,
-            cache_entry=cache["remainder"][i], pos=pos, kv_offset=kv_offset,
-            alive=alive, wcap=wcap, block_tables=block_tables, kq=kq,
-        )
-        new_rem.append(ne)
+    x, new_layer_caches, new_rem = _scan_layers(params, cfg, cache, x, block)
 
     x = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -1375,7 +1475,7 @@ def verify_step(
     logits = logits.astype(jnp.float32)[..., : cfg.vocab_size]
     new_cache = {
         "pos": pos,
-        "layers": list(new_layer_caches),
+        "layers": new_layer_caches,
         "remainder": new_rem,
     }
     if block_tables is not None:
@@ -1406,35 +1506,33 @@ def spec_commit(cache: Params, new_pos, written, *, draft_k: int) -> Params:
     block_tables = cache.get("block_tables")
     paged = block_tables is not None
 
-    def scrub_ring(e, lead):
-        cap = e["k"].shape[-3]
-        rows = jnp.arange(b)[:, None]
-        slot = jnp.where(stale, jnp.mod(p, cap), cap)  # cap → dropped OOB
-        ix = (slice(None), rows, slot) if lead else (rows, slot)
-        out = {
-            "k": e["k"].at[ix].set(0),
-            "v": e["v"].at[ix].set(0),
-            "k_pos": e["k_pos"].at[ix].set(-1),
-        }
-        if "k_scale" in e:
-            out["k_scale"] = e["k_scale"].at[ix].set(0.0)
-            out["v_scale"] = e["v_scale"].at[ix].set(0.0)
-        return out
+    def scrub(e, lead):
+        # positions are minor: K/V (..., n_kv, hd, len), scales (..., n_kv,
+        # len), ring k_pos (..., B, len)
+        length = e["k"].shape[-1]
+        if paged:
+            nbp = e["k"].shape[-4]
+            lb = jnp.clip(p // length, 0, block_tables.shape[1] - 1)
+            row = jnp.take_along_axis(block_tables, lb, axis=1)
+            row = jnp.where(stale, row, nbp - 1)       # non-stale → trash
+            slot = jnp.mod(p, length)
+        else:
+            row = jnp.broadcast_to(jnp.arange(b)[:, None], p.shape)
+            slot = jnp.where(stale, jnp.mod(p, length), length)  # OOB drop
 
-    def scrub_paged(e, lead):
-        nbp, bs = e["k"].shape[-4], e["k"].shape[-3]
-        lb = jnp.clip(p // bs, 0, block_tables.shape[1] - 1)
-        phys = jnp.take_along_axis(block_tables, lb, axis=1)
-        phys = jnp.where(stale, phys, nbp - 1)         # non-stale → trash
-        slot = jnp.mod(p, bs)
-        ix = (slice(None), phys, slot) if lead else (phys, slot)
-        out = {"k": e["k"].at[ix].set(0), "v": e["v"].at[ix].set(0)}
-        if "k_scale" in e:
-            out["k_scale"] = e["k_scale"].at[ix].set(0.0)
-            out["v_scale"] = e["v_scale"].at[ix].set(0.0)
-        return out
+        def write(a, val):
+            # every layer of the stack (a remainder entry: a stack of one)
+            # scrubs the same positions, each in place
+            stack = a if lead else a[None]
+            fill = jnp.full(row.shape + stack.shape[2:-1], val, a.dtype)
+            stack = jax.lax.fori_loop(
+                0, stack.shape[0],
+                lambda l, st: _write_positions(st, l, row, slot, fill), stack)
+            return stack if lead else stack[0]
 
-    scrub = scrub_paged if paged else scrub_ring
+        return {name: write(a, -1 if name == "k_pos" else 0)
+                for name, a in e.items()}
+
     new_cache = {
         "pos": new_pos,
         "layers": [scrub(e, True) for e in cache["layers"]],

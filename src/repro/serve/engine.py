@@ -519,6 +519,8 @@ class Engine:
         self.heads_sharded = (mesh is not None
                                and dist_sharding.serve_heads_shardable(
                                    cfg, self.tp))
+        # int8 scale rows pad per head shard (transformer.scale_rows)
+        self._head_shards = self.tp if self.heads_sharded else 1
         # inside shard_map the model code sees local shapes: scale the head
         # counts down (head_dim pinned so cfg.hd() is unchanged)
         self._cfg_local = (_dc_replace(cfg,
@@ -537,7 +539,9 @@ class Engine:
         if kv_layout == "paged":
             from repro.kernels import autotune as _autotune
             from repro.kernels import dispatch as _dispatch
+            from repro.kernels.decode_attention import check_pool_block
 
+            backend = _dispatch.resolve_backend(None).name
             if block_size is None:
                 nkv = max(1, cfg.n_kv_heads)
                 shape = (batch, max_len, nkv,
@@ -545,8 +549,10 @@ class Engine:
                 dtype = "int8" if kv_quant else "bfloat16"
                 block_size = _autotune.best_block(
                     "paged_attention", shape, dtype, 8 if kv_quant else 16,
-                    "flash", _dispatch.resolve_backend(None).name)[0]
+                    "flash", backend)[0]
             self.block_size = bs = int(block_size)
+            if backend == "pallas-tpu":
+                check_pool_block(bs)
             self.nbmax = -(-max_len // bs)
             if self.prefill_chunk is not None:
                 # paged chunks stay block-aligned so every continuation chunk
@@ -577,14 +583,16 @@ class Engine:
             self.cache = registry.make_cache(
                 params, cfg, batch, max_len, frames=frames, policy=policy,
                 kv_quant=kv_quant, kv_layout="paged", block_size=bs,
-                num_blocks=self._nb_local, data_shards=self.dp)
+                num_blocks=self._nb_local, data_shards=self.dp,
+                head_shards=self._head_shards)
             self._bt = np.full((batch, self.nbmax), self._trash, np.int32)
             self._bt_dirty = True
         else:
             self.pools = []
             self.cache = registry.make_cache(params, cfg, batch, max_len,
                                              frames=frames, policy=policy,
-                                             kv_quant=kv_quant)
+                                             kv_quant=kv_quant,
+                                             head_shards=self._head_shards)
 
         cfg_l = self._cfg_local
         prefill_step, decode_step = make_serve_fns(
@@ -1961,7 +1969,7 @@ class Engine:
                 frames=self._frames, policy=self.policy,
                 kv_quant=self.kv_quant, kv_layout="paged",
                 block_size=self.block_size, num_blocks=self._nb_local,
-                data_shards=self.dp)
+                data_shards=self.dp, head_shards=self._head_shards)
             self._bt = np.full((self.batch, self.nbmax), self._trash,
                                np.int32)
             for i, req in enumerate(self.slots):
@@ -1973,7 +1981,7 @@ class Engine:
             self.cache = registry.make_cache(
                 self.params, self.cfg, self.batch, self.max_len,
                 frames=self._frames, policy=self.policy,
-                kv_quant=self.kv_quant)
+                kv_quant=self.kv_quant, head_shards=self._head_shards)
         self._dev_dirty = True
         self._replay_device_state()
         self._dev_dirty = True

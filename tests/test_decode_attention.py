@@ -30,7 +30,10 @@ def _ring_inputs(seed, *, b=3, cap=64, nkv=2, group=2, hd=32, quantized=False,
                  pos_vals=(5, 40, 63)):
     """A realistic ring-cache snapshot: slot s of row i holds the latest
     prompt/decode position p ≡ s (mod cap) with p ≤ pos_i; unwritten slots
-    carry k_pos = -1 (and arbitrary codes — masking must hide them)."""
+    carry k_pos = -1 (and arbitrary codes — masking must hide them).  Drawn
+    position-major, stored as the engine stores the ring: heads-major,
+    positions minor — K/V (b, nkv, hd, cap), scales (b, nkv, cap) — as a
+    layer-stacked cache of one layer (``layer=0``)."""
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(b, nkv, group, hd)), jnp.bfloat16)
     pos = jnp.asarray(pos_vals[:b], jnp.int32)
@@ -48,13 +51,30 @@ def _ring_inputs(seed, *, b=3, cap=64, nkv=2, group=2, hd=32, quantized=False,
         k = jnp.asarray(rng.normal(size=(b, cap, nkv, hd)), jnp.bfloat16)
         v = jnp.asarray(rng.normal(size=(b, cap, nkv, hd)), jnp.bfloat16)
         ks = vs = None
+    k, v, ks, vs = (_minor(x) for x in (k, v, ks, vs))
+    k, v, k_pos, ks, vs = (None if x is None else x[None]
+                           for x in (k, v, k_pos, ks, vs))
     return q, k, v, k_pos, pos, ks, vs
+
+
+def _minor(x):
+    """Position-major (b, cap, ...) → the stored (b, ..., cap)."""
+    return None if x is None else jnp.moveaxis(x, 1, -1)
+
+
+def _major(x):
+    """The stored (b, ..., cap) → position-major (b, cap, ...)."""
+    return None if x is None else jnp.moveaxis(x, -1, 1)
 
 
 def _einsum_baseline(q, k, v, k_pos, pos, ks, vs, window):
     """The pre-PR-3 ``_attention_decode`` einsum path, f32 logits/probs (the
     old path additionally rounded logits and probabilities to bf16; f32 here
-    isolates the association difference from that storage rounding)."""
+    isolates the association difference from that storage rounding).  Takes
+    the stored ring layout, a stack of one layer."""
+    k_pos = k_pos[0]
+    k, v, ks, vs = (_major(None if x is None else x[0])
+                    for x in (k, v, ks, vs))
     b, cap, nkv, hd = k.shape
     group = q.shape[2]
     logits = jnp.einsum("bhgd,bkhd->bhgk", q.astype(jnp.float32),
@@ -88,11 +108,11 @@ def test_interpret_bit_identical_to_xla_ref(quantized, window, group):
         group, group=group, quantized=quantized)
     for bk in (16, 64):
         out_i = dispatch.decode_attention(
-            q, k, v, k_pos, pos, k_scale=ks, v_scale=vs, window=window,
-            block=(bk,), backend="pallas-interpret")
+            q, k, v, k_pos, pos, k_scale=ks, v_scale=vs, layer=0,
+            window=window, block=(bk,), backend="pallas-interpret")
         out_r = dispatch.decode_attention(
-            q, k, v, k_pos, pos, k_scale=ks, v_scale=vs, window=window,
-            block=(bk,), backend="xla-ref")
+            q, k, v, k_pos, pos, k_scale=ks, v_scale=vs, layer=0,
+            window=window, block=(bk,), backend="xla-ref")
         assert out_i.dtype == jnp.float32
         assert jnp.array_equal(out_i, out_r), (quantized, window, group, bk)
 
@@ -107,9 +127,11 @@ def test_interpret_autotuned_block_matches_explicit():
         "decode_attention", (3, 64, 2, 2, 32), "int8", 8, "flash",
         "pallas-interpret")
     auto = dispatch.decode_attention(q, k, v, k_pos, pos, k_scale=ks,
-                                     v_scale=vs, backend="pallas-interpret")
+                                     v_scale=vs, layer=0,
+                                     backend="pallas-interpret")
     explicit = dispatch.decode_attention(q, k, v, k_pos, pos, k_scale=ks,
-                                         v_scale=vs, block=tuple(picked),
+                                         v_scale=vs, layer=0,
+                                         block=tuple(picked),
                                          backend="pallas-interpret")
     assert jnp.array_equal(auto, explicit)
 
@@ -121,7 +143,7 @@ def test_oracle_matches_full_softmax_einsum(quantized, window):
     float-summation association (it is *more* precise than the retired
     in-model path, which stored logits and probabilities in bf16)."""
     q, k, v, k_pos, pos, ks, vs = _ring_inputs(11, quantized=quantized)
-    out_r = ref.decode_attention_ref(q, k, v, k_pos, pos, ks, vs,
+    out_r = ref.decode_attention_ref(q, k, v, k_pos, pos, ks, vs, layer=0,
                                      window=window, block=(16,))
     base = _einsum_baseline(q, k, v, k_pos, pos, ks, vs, window)
     np.testing.assert_allclose(np.asarray(out_r), np.asarray(base),
@@ -129,15 +151,16 @@ def test_oracle_matches_full_softmax_einsum(quantized, window):
 
 
 def _as_pool(x, bt, bs):
-    """Scatter an unwrapped ring array (B, cap, ...) into a paged pool
-    through block table ``bt``: logical block j of slot b lands at
-    physical block bt[b, j]."""
+    """Scatter an unwrapped stored ring array (1, B, ..., cap) into a paged
+    pool (1, blocks, ..., bs) through block table ``bt``: logical block j
+    of slot b lands at physical block bt[b, j]."""
     if x is None:
         return None
+    x = _major(x[0])
     b, cap = x.shape[:2]
     blocks = x.reshape((b * (cap // bs), bs) + x.shape[2:])
     order = np.argsort(np.asarray(bt).reshape(-1))
-    return blocks[order]
+    return _minor(blocks[order])[None]
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
@@ -156,7 +179,7 @@ def test_paged_and_verify_oracles_match_full_softmax_einsum(kind, quantized,
     q, k, v, k_pos, last, ks, vs = _ring_inputs(
         17, quantized=quantized, pos_vals=(7, 42, 62))
     pos = last - (kq - 1)
-    b, cap = k.shape[:2]
+    b, cap = k_pos.shape[1:]
     bt = jnp.asarray(np.random.default_rng(3).permutation(b * cap // bs)
                      .reshape(b, cap // bs), jnp.int32)
     pool = [_as_pool(x, bt, bs) for x in (k, v, ks, vs)]
@@ -164,16 +187,18 @@ def test_paged_and_verify_oracles_match_full_softmax_einsum(kind, quantized,
         size=(b, kq) + q.shape[1:]), jnp.bfloat16)
     if kind == "paged-decode":
         outs = [ref.paged_decode_attention_ref(q, *pool[:2], bt, last,
-                                               *pool[2:], window=window)]
+                                               *pool[2:], layer=0,
+                                               window=window)]
         qs, ps = [q], [last]
     elif kind == "ring-verify":
         out = ref.verify_attention_ref(qv, k, v, k_pos, pos, ks, vs,
-                                       window=window, block=(16,))
+                                       layer=0, window=window, block=(16,))
         outs = [out[:, t] for t in range(kq)]
         qs, ps = [qv[:, t] for t in range(kq)], [pos + t for t in range(kq)]
     else:
         out = ref.paged_verify_attention_ref(qv, *pool[:2], bt, pos,
-                                             *pool[2:], window=window)
+                                             *pool[2:], layer=0,
+                                             window=window)
         outs = [out[:, t] for t in range(kq)]
         qs, ps = [qv[:, t] for t in range(kq)], [pos + t for t in range(kq)]
     for o, qt, pt in zip(outs, qs, ps):
@@ -188,8 +213,9 @@ def test_block_size_invariance_and_masked_slot_independence():
     the sliding window — cannot leak into the output even with poisoned
     codes."""
     q, k, v, k_pos, pos, ks, vs = _ring_inputs(13, quantized=True)
-    outs = [ref.decode_attention_ref(q, k, v, k_pos, pos, ks, vs, window=16,
-                                     block=(bk,)) for bk in (8, 16, 32, 64)]
+    outs = [ref.decode_attention_ref(q, k, v, k_pos, pos, ks, vs, layer=0,
+                                     window=16, block=(bk,))
+            for bk in (8, 16, 32, 64)]
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
                                    rtol=1e-5, atol=1e-6)
@@ -198,17 +224,19 @@ def test_block_size_invariance_and_masked_slot_independence():
     # -1e30 sentinel either way and exp to exactly 0, so the output must be
     # *bitwise* unchanged
     posb = np.asarray(pos)[:, None]
-    kp = np.asarray(k_pos)
+    kp = np.asarray(k_pos)[0]
     valid = jnp.asarray((kp >= 0) & (kp <= posb) & (kp > posb - 16))
-    vm = valid[:, :, None, None]
+    vm = valid[:, None, None, :]          # positions are minor, under the
+    #                                       stack of one (broadcast)
     k_bad = jnp.where(vm, k, jnp.int8(127))
     v_bad = jnp.where(vm, v, jnp.int8(-128))
-    ks_bad = jnp.where(valid[:, :, None], ks, 1e4)
-    vs_bad = jnp.where(valid[:, :, None], vs, 1e4)
-    clean = ref.decode_attention_ref(q, k, v, k_pos, pos, ks, vs, window=16,
-                                     block=(16,))
+    ks_bad = jnp.where(valid[:, None, :], ks, 1e4)
+    vs_bad = jnp.where(valid[:, None, :], vs, 1e4)
+    clean = ref.decode_attention_ref(q, k, v, k_pos, pos, ks, vs, layer=0,
+                                     window=16, block=(16,))
     poisoned = ref.decode_attention_ref(q, k_bad, v_bad, k_pos, pos, ks_bad,
-                                        vs_bad, window=16, block=(16,))
+                                        vs_bad, layer=0, window=16,
+                                        block=(16,))
     assert jnp.array_equal(clean, poisoned)
 
 

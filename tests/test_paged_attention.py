@@ -34,7 +34,11 @@ def _dual_layout_inputs(seed, *, b=3, bs=16, max_len=64, nkv=2, group=2,
                         hd=32, quantized=True, pos_vals=(5, 40, 63)):
     """One token stream materialised in BOTH cache layouts: the dense ring
     (k_pos-tracked) and the paged pool (blocks permuted through a block
-    table, plus a trash block holding poison)."""
+    table, plus a trash block holding poison).  Written position-major
+    below, then stored heads-major with positions minor, as the engine
+    stores them: ring (b, nkv, hd, max_len), pool (blocks, nkv, hd, bs),
+    scales (…, nkv, positions) — each a layer-stacked cache of one layer
+    (``layer=0``)."""
     rng = np.random.default_rng(seed)
     nbmax = max_len // bs
     num_blocks = b * nbmax
@@ -77,18 +81,20 @@ def _dual_layout_inputs(seed, *, b=3, bs=16, max_len=64, nkv=2, group=2,
             pool_s["ks"][phys, t], pool_s["vs"][phys, t] = sc
 
     cast = jnp.int8 if quantized else jnp.bfloat16
+
+    def store(x, dt=None):    # position axis 1 → minor; a stack of one
+        return jnp.asarray(np.moveaxis(x, 1, -1), dt)[None]
+
     out = dict(
         q=q, pos=pos,
-        ring_k=jnp.asarray(ring["k"], cast), ring_v=jnp.asarray(ring["v"], cast),
-        k_pos=jnp.asarray(kpos, jnp.int32),
-        pool_k=jnp.asarray(pool["k"], cast), pool_v=jnp.asarray(pool["v"], cast),
+        ring_k=store(ring["k"], cast), ring_v=store(ring["v"], cast),
+        k_pos=jnp.asarray(kpos, jnp.int32)[None],
+        pool_k=store(pool["k"], cast), pool_v=store(pool["v"], cast),
         bt=jnp.asarray(bt),
     )
     if quantized:
-        out.update(ring_ks=jnp.asarray(ring_s["ks"]),
-                   ring_vs=jnp.asarray(ring_s["vs"]),
-                   pool_ks=jnp.asarray(pool_s["ks"]),
-                   pool_vs=jnp.asarray(pool_s["vs"]))
+        out.update(ring_ks=store(ring_s["ks"]), ring_vs=store(ring_s["vs"]),
+                   pool_ks=store(pool_s["ks"]), pool_vs=store(pool_s["vs"]))
     else:
         out.update(ring_ks=None, ring_vs=None, pool_ks=None, pool_vs=None)
     return out
@@ -110,11 +116,11 @@ def test_paged_interpret_bit_identical_to_xla_ref(quantized, window, group):
     d = _dual_layout_inputs(group, group=group, quantized=quantized)
     out_i = dispatch.paged_decode_attention(
         d["q"], d["pool_k"], d["pool_v"], d["bt"], d["pos"],
-        k_scale=d["pool_ks"], v_scale=d["pool_vs"], window=window,
+        k_scale=d["pool_ks"], v_scale=d["pool_vs"], layer=0, window=window,
         backend="pallas-interpret")
     out_r = dispatch.paged_decode_attention(
         d["q"], d["pool_k"], d["pool_v"], d["bt"], d["pos"],
-        k_scale=d["pool_ks"], v_scale=d["pool_vs"], window=window,
+        k_scale=d["pool_ks"], v_scale=d["pool_vs"], layer=0, window=window,
         backend="xla-ref")
     assert out_i.dtype == jnp.float32
     assert jnp.array_equal(out_i, out_r), (quantized, window, group)
@@ -134,11 +140,11 @@ def test_paged_bit_identical_to_ring_same_stream(quantized, window):
     for backend in ("xla-ref", "pallas-interpret"):
         ring = dispatch.decode_attention(
             d["q"], d["ring_k"], d["ring_v"], d["k_pos"], d["pos"],
-            k_scale=d["ring_ks"], v_scale=d["ring_vs"], window=window,
+            k_scale=d["ring_ks"], v_scale=d["ring_vs"], layer=0, window=window,
             block=(bs,), backend=backend)
         paged = dispatch.paged_decode_attention(
             d["q"], d["pool_k"], d["pool_v"], d["bt"], d["pos"],
-            k_scale=d["pool_ks"], v_scale=d["pool_vs"], window=window,
+            k_scale=d["pool_ks"], v_scale=d["pool_vs"], layer=0, window=window,
             backend=backend)
         assert jnp.array_equal(ring, paged), (quantized, window, backend)
 
@@ -150,11 +156,12 @@ def test_paged_gqa_and_single_block_edge():
                             quantized=True, pos_vals=(0, 31))
     out_i = dispatch.paged_decode_attention(
         d["q"], d["pool_k"], d["pool_v"], d["bt"], d["pos"],
-        k_scale=d["pool_ks"], v_scale=d["pool_vs"],
+        k_scale=d["pool_ks"], v_scale=d["pool_vs"], layer=0,
         backend="pallas-interpret")
     out_r = dispatch.paged_decode_attention(
         d["q"], d["pool_k"], d["pool_v"], d["bt"], d["pos"],
-        k_scale=d["pool_ks"], v_scale=d["pool_vs"], backend="xla-ref")
+        k_scale=d["pool_ks"], v_scale=d["pool_vs"], layer=0,
+        backend="xla-ref")
     assert jnp.array_equal(out_i, out_r)
     assert not bool(jnp.any(jnp.isnan(out_r)))
 
@@ -296,3 +303,111 @@ def test_paged_rejects_unservable_requests():
     eng.submit(Request(rid=1, prompt=[1, 2, 3, 4, 5], max_new=4))  # > 1 block
     done = sorted(eng.run(20), key=lambda r: r.rid)
     assert [r.finish_reason for r in done] == ["rejected", "rejected"]
+
+
+@pytest.mark.parametrize("path", ["prefill", "decode"])
+def test_pool_codes_are_the_position_major_codes_transposed(path):
+    """Prefix-block reuse rests on the codes a position holds being a pure
+    function of (value, position + request offset, element index): the
+    position-major pool stored the quantiser's (…, n_kv, hd) output as it
+    came.  The heads-major pool must hold exactly those codes and scales,
+    only transposed.  K/V values go in at known positions through the paged
+    prefill's block scatter or the decode step's per-position write, into
+    one layer of a layer-stacked pool, and a transposed read of every
+    written block equals the position-major quantiser output bitwise; the
+    other layers and the padding scale rows stay zero."""
+    from repro.models import transformer as tfm
+
+    rng = np.random.default_rng(21)
+    b, s, nkv, hd, bs, layers, layer = 2, 24, 2, 8, 8, 3, 1
+    nbmax, nbp = s // bs, 2 * (s // bs) + 1
+    srows = tfm.scale_rows(nkv)
+    bt = jnp.asarray(rng.permutation(nbp - 1).reshape(b, nbmax), jnp.int32)
+    off = jnp.asarray([0, 1000], jnp.int32)
+    kv = [jnp.asarray(rng.normal(size=(b, s, nkv, hd)), jnp.bfloat16)
+          for _ in range(2)]
+    pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    # the position-major codes and scales, as the quantiser returns them
+    idx4 = tfm._kv_elem_idx(nkv, hd)
+    want = [tfm._kv_q8(x, (pos + off[:, None])[:, :, None, None], idx4, seed)
+            for x, seed in zip(kv, (101, 102))]
+
+    stack = {name: jnp.zeros(shape, jnp.int8 if name in "kv"
+                             else jnp.float32)
+             for name, shape in tfm.kv_shapes(
+                 (layers, nbp), nkv, hd, bs, quantized=True).items()}
+    assert stack["k_scale"].shape[-2] == srows
+    if path == "prefill":
+        got = tfm._paged_scatter_entry(
+            stack, layer, kv[0], kv[1], pos, jnp.full((b,), s, jnp.int32),
+            jnp.zeros((b,), jnp.int32), bt, True, off)
+    else:                      # one token a tick, as _attention_decode writes
+        got = stack
+        for t in range(s):
+            rows = bt[:, t // bs][:, None]
+            slots = jnp.full((b, 1), t % bs, jnp.int32)
+            for (codes, scales), c, sc in zip(want, ("k", "v"),
+                                             ("k_scale", "v_scale")):
+                got[c] = tfm._write_positions(got[c], layer, rows, slots,
+                                              codes[:, t:t + 1])
+                got[sc] = tfm._write_positions(got[sc], layer, rows, slots,
+                                               scales[:, t:t + 1])
+
+    for (codes, scales), c, sc in zip(want, ("k", "v"), ("k_scale", "v_scale")):
+        for i in range(b):
+            for j in range(nbmax):
+                blk = slice(j * bs, (j + 1) * bs)
+                phys = int(bt[i, j])
+                # transposed read: positions back to the major axis
+                read = jnp.moveaxis(got[c][layer, phys], -1, 0)
+                read_s = jnp.moveaxis(got[sc][layer, phys], -1, 0)
+                assert jnp.array_equal(read, codes[i, blk]), (c, i, j)
+                assert jnp.array_equal(read_s[:, :nkv], scales[i, blk])
+                assert not jnp.any(read_s[:, nkv:])       # padding rows
+        others = [lyr for lyr in range(layers) if lyr != layer]
+        assert not jnp.any(got[c][jnp.asarray(others)])
+        assert not jnp.any(got[sc][jnp.asarray(others)])
+
+
+@pytest.mark.parametrize("length", [384, 24])
+def test_write_positions_matches_an_elementwise_scatter(length):
+    """The whole-row write equals writing each column on its own: several
+    writes per slot — two in one row, one past the end (dropped) — into
+    one layer of a layer-stacked array, for K/V columns, scale columns
+    (fewer values than padded rows) and ring positions."""
+    from repro.models import transformer as tfm
+
+    rng = np.random.default_rng(length)
+    layers, layer, rows_n, nkv, hd = 3, 2, 4, 2, 8
+    srows = tfm.scale_rows(nkv)
+    rows = jnp.asarray([[1, 1, 1], [3, 3, 0]], jnp.int32)
+    edge = min(127, length - 2)
+    slots = jnp.asarray([[edge, edge + 1, length],
+                         [5, 6, length - 1]], jnp.int32)
+    for inner, vshape in (((nkv, hd), (nkv, hd)), ((srows,), (nkv,)),
+                          ((), ())):
+        arr = jnp.asarray(rng.normal(size=(layers, rows_n) + inner
+                                     + (length,)), jnp.float32)
+        vals = jnp.asarray(rng.normal(size=(2, 3) + vshape), jnp.float32)
+        want = np.array(arr)
+        for bi in range(2):
+            for u in range(3):
+                if int(slots[bi, u]) < length:
+                    col = np.zeros(inner, np.float32)
+                    col[tuple(slice(0, n) for n in vshape)] = vals[bi, u]
+                    want[layer, int(rows[bi, u]), ..., int(slots[bi, u])] = col
+        got = tfm._write_positions(arr, layer, rows, slots, vals)
+        assert np.array_equal(np.asarray(got), want), inner
+
+
+def test_engine_refuses_a_sub_lane_pool_block_on_tpu(monkeypatch):
+    """On the chip a pool block must be whole 128-position lane tiles
+    (``decode_attention.check_pool_block``): the engine refuses an
+    explicit 16 up front and picks a lane multiple when left to choose."""
+    monkeypatch.setattr(dispatch, "_on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        Engine(PARAMS, CFG, batch=2, max_len=32, kv_layout="paged",
+               block_size=16, kv_quant=True)
+    eng = Engine(PARAMS, CFG, batch=2, max_len=512, kv_layout="paged",
+                 kv_quant=True)
+    assert eng.block_size == 128

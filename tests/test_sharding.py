@@ -82,13 +82,18 @@ SERVE_MESHES = {
 }
 
 
-def _paged_cache_shapes(cfg, batch=4, max_len=64, bs=8, dp=1, kv_quant=True):
+def _paged_cache_shapes(cfg, batch=4, max_len=64, bs=8, dp=1, tp=1,
+                        kv_quant=True):
+    """The engine's paged cache on a (dp, tp) mesh: int8 scale rows pad per
+    head shard when the KV heads split ``tp`` ways."""
     params = jax.eval_shape(lambda k: registry.init_model(k, cfg),
                             jax.random.PRNGKey(0))
+    heads = tp if shd.serve_heads_shardable(cfg, tp) else 1
     return jax.eval_shape(
         lambda p: registry.make_cache(p, cfg, batch, max_len,
                                       kv_quant=kv_quant, kv_layout="paged",
-                                      block_size=bs, data_shards=dp),
+                                      block_size=bs, data_shards=dp,
+                                      head_shards=heads),
         params)
 
 
@@ -99,7 +104,7 @@ def test_cache_specs_paged_divide(mesh_shape):
     cfg = get_config("smollm_135m").reduced()     # 2 KV heads
     dp, tp = mesh_shape
     mesh = SERVE_MESHES[mesh_shape]
-    cache = _paged_cache_shapes(cfg, dp=dp)
+    cache = _paged_cache_shapes(cfg, dp=dp, tp=tp)
     specs = shd.cache_specs(cache, cfg, mesh)
     _check(cache, specs, mesh)
     ent = specs["layers"][0]          # stacked entry: leading repeat axis
@@ -108,8 +113,9 @@ def test_cache_specs_paged_divide(mesh_shape):
     # repeat axis never shards; the pool-block axis carries 'data'
     assert ent["k"][0] is None
     assert ent["k"][1] == ("data" if dp > 1 else None)
-    assert ent["k"][3] == ("model" if tp > 1 else None)
-    assert ent["k_scale"][3] == ("model" if tp > 1 else None)
+    # heads-major pool (L, blocks, H, hd, bs): heads at stacked dim 2
+    assert ent["k"][2] == ("model" if tp > 1 else None)
+    assert ent["k_scale"][2] == ("model" if tp > 1 else None)
 
 
 def test_cache_specs_paged_gqa_fallback():
@@ -119,13 +125,13 @@ def test_cache_specs_paged_gqa_fallback():
     from dataclasses import replace
     cfg = replace(cfg, n_kv_heads=1)              # MQA: 1 % 2 != 0
     mesh = SERVE_MESHES[(1, 2)]
-    cache = _paged_cache_shapes(cfg)
+    cache = _paged_cache_shapes(cfg, tp=2)
     specs = shd.cache_specs(cache, cfg, mesh)
     _check(cache, specs, mesh)
     ent = specs["layers"][0]
-    assert ent["k"][3] is None
-    assert ent["v"][3] is None
-    assert ent["k_scale"][3] is None
+    assert ent["k"][2] is None
+    assert ent["v"][2] is None
+    assert ent["k_scale"][2] is None
     assert not shd.serve_heads_shardable(cfg, 2)
 
 

@@ -165,7 +165,8 @@ def _ring_verify_inputs(seed, *, b=2, cap=32, nkv=2, group=2, hd=16, kq=3,
                         quantized=False, pos_vals=(5, 20)):
     """A ring snapshot mid-verify: slots hold positions up to
     ``pos + kq - 1`` (base row + drafted rows already scattered); unwritten
-    slots carry k_pos = -1 and arbitrary codes that masking must hide."""
+    slots carry k_pos = -1 and arbitrary codes that masking must hide.
+    Drawn position-major, stored as the engine stores it (``_minor``)."""
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(b, kq, nkv, group, hd)), jnp.bfloat16)
     pos = jnp.asarray(pos_vals[:b], jnp.int32)
@@ -183,7 +184,15 @@ def _ring_verify_inputs(seed, *, b=2, cap=32, nkv=2, group=2, hd=16, kq=3,
         k = jnp.asarray(rng.normal(size=(b, cap, nkv, hd)), jnp.bfloat16)
         v = jnp.asarray(rng.normal(size=(b, cap, nkv, hd)), jnp.bfloat16)
         ks = vs = None
-    return q, k, v, k_pos, pos, ks, vs
+    k, v, ks, vs = (_minor(x) for x in (k, v, ks, vs))
+    return q, k, v, k_pos[None], pos, ks, vs
+
+
+def _minor(x):
+    """Position-major (n, positions, ...) → the cache's stored heads-major,
+    positions-minor (n, ..., positions), as a layer-stacked cache of one
+    layer (``layer=0``)."""
+    return None if x is None else jnp.moveaxis(x, 1, -1)[None]
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
@@ -197,11 +206,11 @@ def test_verify_interpret_bit_identical_to_xla_ref(quantized, window, group):
         group, group=group, quantized=quantized)
     for bk in (8, 32):
         out_i = dispatch.verify_attention(
-            q, k, v, k_pos, pos, k_scale=ks, v_scale=vs, window=window,
-            block=(bk,), backend="pallas-interpret")
+            q, k, v, k_pos, pos, k_scale=ks, v_scale=vs, layer=0,
+            window=window, block=(bk,), backend="pallas-interpret")
         out_r = dispatch.verify_attention(
-            q, k, v, k_pos, pos, k_scale=ks, v_scale=vs, window=window,
-            block=(bk,), backend="xla-ref")
+            q, k, v, k_pos, pos, k_scale=ks, v_scale=vs, layer=0,
+            window=window, block=(bk,), backend="xla-ref")
         assert out_i.dtype == jnp.float32
         assert jnp.array_equal(out_i, out_r), (quantized, window, group, bk)
 
@@ -233,10 +242,13 @@ def test_paged_verify_interpret_bit_identical_to_xla_ref(quantized, group):
         k = jnp.asarray(rng.normal(size=(nblocks, bs, nkv, hd)), jnp.bfloat16)
         v = jnp.asarray(rng.normal(size=(nblocks, bs, nkv, hd)), jnp.bfloat16)
         ks = vs = None
+    k, v, ks, vs = (_minor(x) for x in (k, v, ks, vs))
     out_i = dispatch.paged_verify_attention(
-        q, k, v, bt, pos, k_scale=ks, v_scale=vs, backend="pallas-interpret")
+        q, k, v, bt, pos, k_scale=ks, v_scale=vs, layer=0,
+        backend="pallas-interpret")
     out_r = dispatch.paged_verify_attention(
-        q, k, v, bt, pos, k_scale=ks, v_scale=vs, backend="xla-ref")
+        q, k, v, bt, pos, k_scale=ks, v_scale=vs, layer=0,
+        backend="xla-ref")
     assert out_i.dtype == jnp.float32
     assert jnp.array_equal(out_i, out_r), (quantized, group)
 
@@ -252,10 +264,10 @@ def test_verify_row_equals_sequential_decode_row(quantized):
     q, k, v, k_pos, pos, ks, vs = _ring_verify_inputs(7, quantized=quantized)
     kq = q.shape[1]
     ver = dispatch.verify_attention(q, k, v, k_pos, pos, k_scale=ks,
-                                    v_scale=vs, backend="xla-ref")
+                                    v_scale=vs, layer=0, backend="xla-ref")
     for t in range(kq):
         one = dispatch.decode_attention(q[:, t], k, v, k_pos, pos + t,
-                                        k_scale=ks, v_scale=vs,
+                                        k_scale=ks, v_scale=vs, layer=0,
                                         backend="xla-ref")
         assert jnp.array_equal(ver[:, t], one), t
 
@@ -303,7 +315,8 @@ def _readable_paged_bytes(eng):
     the trash block plus free-list blocks are never read at all.  Pool
     *bookkeeping* (refcounts, free-list order, tables) is still compared
     exactly in the test body.  Leaves without a block axis (``pos``) are
-    returned whole."""
+    returned whole; the in-block position is every pool leaf's minor
+    axis."""
     nbp = eng.num_blocks + 1
     bs = eng.block_size
     out = []
@@ -322,7 +335,7 @@ def _readable_paged_bytes(eng):
             for li, phys in enumerate(table):
                 rows = min(max(pos - li * bs, 0), bs)
                 blk = np.take(a, phys, axis=bax)
-                out.append(np.take(blk, range(rows), axis=bax))
+                out.append(np.take(blk, range(rows), axis=-1))
     return out
 
 
