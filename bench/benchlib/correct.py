@@ -1,11 +1,14 @@
 """Whether what the timed path served is right: a sample of the requests
 the window finished, drawn from the seed and holding the longest, is run
-through the plain float32 reference (``reference/forward.py``) over each
-prompt with its served tokens.  For every served token the gap is how far
-its reference logit lies below the reference's best at that position; the
-widest gap is compared with the cell's limit.  Greedy decoding makes that
-a fair test: a served token that is not the reference's best (or within
-rounding of it) is a wrong token.
+through the plain float32 reference over each prompt with its served
+tokens.  The reference is the ``logits_at`` of the cell's architecture
+module, ``reference/<architecture>.py`` (``forward`` where the
+configuration names none; ``benchlib/spec.py`` lists what the module
+gives).  For every served token the gap is how far its reference logit
+lies below the reference's best at that position; the widest gap is
+compared with the cell's limit.  Greedy decoding makes that a fair test:
+a served token that is not the reference's best (or within rounding of
+it) is a wrong token.
 
 The same positions can also be read under the reference's lower-precision
 control: the gap of the token the control puts first.  Set-up of a limit
@@ -46,17 +49,18 @@ def _padded(rec, max_len: int, rows_max: int):
     return tokens, rows, np.asarray(rec.out, np.int32), n
 
 
-def gaps(weights, model: dict, recs: List, max_len: int, rows_max: int,
+def gaps(arch, weights, model: dict, recs: List, max_len: int, rows_max: int,
          control: bool = False):
     """Widest gap of the served tokens (and, with ``control``, of the
-    control's first choices) over the sampled requests; tokens compared."""
+    control's first choices) over the sampled requests, by the reference of
+    the architecture module ``arch``; tokens compared."""
     served_gap, control_gap, n_tok = 0.0, 0.0, 0
     for rec in recs:
         tokens, rows, out, n = _padded(rec, max_len, rows_max)
         served = np.zeros(rows_max, np.int32)
         served[:n] = out
         g, c = (np.asarray(x) for x in _row_gaps(weights, tokens, rows, served,
-                                                 model_items=_items(model),
+                                                 arch=arch, model_items=_items(model),
                                                  control=control))
         served_gap = max(served_gap, float(g[:n].max()))
         control_gap = max(control_gap, float(c[:n].max()))
@@ -69,29 +73,28 @@ def _items(model: dict):
                         for k, v in model.items()))
 
 
-def _row_gaps_impl(weights, tokens, rows, served, *, model_items, control):
+def _row_gaps_impl(weights, tokens, rows, served, *, arch, model_items, control):
     import jax.numpy as jnp
 
-    from reference.forward import logits_at
-
     model = dict(model_items)
-    ref = logits_at(weights, model, tokens, rows)
+    ref = arch.logits_at(weights, model, tokens, rows)
     best = jnp.max(ref, axis=-1)
     got = jnp.take_along_axis(ref, served[:, None], axis=-1)[:, 0]
     alt = best
     if control:
-        pick = jnp.argmax(logits_at(weights, model, tokens, rows, control=True), axis=-1)
+        pick = jnp.argmax(arch.logits_at(weights, model, tokens, rows, control=True), axis=-1)
         alt = jnp.take_along_axis(ref, pick[:, None], axis=-1)[:, 0]
     return best - got, best - alt
 
 
-def _row_gaps(*args, model_items, control):
+def _row_gaps(*args, arch, model_items, control):
     import jax
 
     global _ROW_GAPS
     if _ROW_GAPS is None:
-        _ROW_GAPS = jax.jit(_row_gaps_impl, static_argnames=("model_items", "control"))
-    return _ROW_GAPS(*args, model_items=model_items, control=control)
+        _ROW_GAPS = jax.jit(_row_gaps_impl,
+                            static_argnames=("arch", "model_items", "control"))
+    return _ROW_GAPS(*args, arch=arch, model_items=model_items, control=control)
 
 
 _ROW_GAPS = None

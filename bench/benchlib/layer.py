@@ -2,20 +2,32 @@
 
 ``read(ctx)`` returns a number, or None when the run holds nothing for it
 to read; the harness then leaves the metric out of the result line.  A
-reader never returns 0 for a share of a roofline or of a peak."""
+reader never returns 0 for a share of a roofline or of a peak.
+
+``ctx.work`` is ``work(model)`` of the cell's architecture module,
+``reference/<architecture>.py`` (``benchlib/spec.py``): whole-model
+integer counts, summed over the layers by the module, so a reader never
+multiplies by a layer count:
+
+- ``token_matmul_flops(head)``: one token through every layer, plus one
+  row of logits with ``head``;
+- ``attention_flops(ctx)``: one query over ``ctx`` keys in every layer;
+- ``decode_attention_bytes(ctx, nbmax)``, ``decode_attention_flops(ctx)``:
+  what the decode-attention kernel must move and compute for one row over
+  all layers;
+- ``head_flops()``: one row of logits;
+- ``kv_bytes_per_token()``, ``weight_bytes()``."""
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-from benchlib.work import Dims
-
 
 @dataclass
 class Ctx:
     model: dict
-    dims: Dims
+    work: object                  # the architecture module's work(model)
     peaks: dict
     settings: dict
     records: List

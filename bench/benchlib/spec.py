@@ -10,18 +10,38 @@ mix.  Each lives in a file of its own under the benchmark's directory:
 - ``cells/<workload>.json``: the engine settings, the load level, the traced
   sub-window and the correctness limit of this pairing;
 - ``metrics/<metric>.py`` (or ``metrics/<base>.py`` for ``<base>.<suffix>``):
-  the reader of one per-layer metric.
+  the reader of one per-layer metric;
+- ``reference/<architecture>.py``: the architecture the configuration names
+  under its optional key ``architecture`` (``forward``, the Llama-family
+  decoder, where the key is absent).  It is the one place that says what
+  the architecture is, and gives:
 
-A later cell, configuration, mix or metric is new files plus new entries in
-``BENCHMARK.json``; no file here changes.
+  - ``logits_at(w, model, tokens, rows, *, control=False)``: the plain
+    float32 reference's logits after each position in ``rows``, and with
+    ``control`` its lower-precision control (``benchlib/correct.py``);
+  - ``work(model)``: the whole model's work counts, summed over its layers,
+    that the per-layer readers take as ``ctx.work`` (``benchlib/layer.py``);
+  - optionally ``WIDTH_KEYS``, ``{file key: ModelConfig attribute}`` pairs
+    (an attribute, or a method of no arguments) that
+    ``run.program_config`` checks beside the dense widths;
+  - optionally ``weight_kind(path, shape)``: a leaf's kind (``"norm"``,
+    ``"embed"``, ``"head"`` or ``"matrix"``) where the name rule of
+    ``benchlib/weights.py`` would misread it, else None.
+
+A later cell, configuration, architecture, mix or metric is new files plus
+new entries in ``BENCHMARK.json``; no file here changes.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
+import re
+import sys
 from dataclasses import dataclass, field
+from types import ModuleType
 from typing import Callable, List, Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -58,6 +78,7 @@ class Cell:
     config: dict
     mix: dict
     settings: dict
+    arch: ModuleType              # reference/<architecture>.py
     end_to_end: List[Metric] = field(default_factory=list)
     per_layer: List[Metric] = field(default_factory=list)
 
@@ -69,10 +90,34 @@ def _metric(entry: dict) -> Metric:
                   workloads=entry.get("workloads"))
 
 
+@functools.lru_cache(maxsize=None)
+def _load_module(path: str, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod           # a dataclass looks its module up there
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def architecture(name: str, bench_dir: str = BENCH_DIR) -> ModuleType:
+    """The module ``reference/<name>.py``, loaded by path (once a process),
+    if it gives ``logits_at`` and ``work``."""
+    if not isinstance(name, str) or not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_-]*", name):
+        raise SpecError(f"architecture {name!r} is not a module name")
+    path = os.path.join(bench_dir, "reference", f"{name}.py")
+    if not os.path.exists(path):
+        raise SpecError(f"no architecture module {os.path.relpath(path, ROOT)}")
+    mod = _load_module(path, f"bench_reference_{name.replace('-', '_')}")
+    missing = [f for f in ("logits_at", "work") if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SpecError(f"architecture module {name!r} lacks {', '.join(missing)}")
+    return mod
+
+
 def load_cell(workload: str, bench_dir: str = BENCH_DIR,
               benchmark: Optional[dict] = None) -> Cell:
-    """The cell named ``workload`` with its configuration, mix, settings and
-    the metrics it reports, all found by name."""
+    """The cell named ``workload`` with its configuration, mix, settings,
+    architecture module and the metrics it reports, all found by name."""
     if benchmark is None:
         benchmark = _load_json(os.path.join(os.path.dirname(bench_dir),
                                             "BENCHMARK.json"))
@@ -90,7 +135,9 @@ def load_cell(workload: str, bench_dir: str = BENCH_DIR,
                  if (workload in m["workloads"] if m.get("workloads") is not None
                      else m["moves"] in reported)]
     return Cell(name=workload, chips=int(w["chips"]), config=config, mix=mix,
-                settings=settings, end_to_end=e2e, per_layer=per_layer)
+                settings=settings,
+                arch=architecture(config.get("architecture", "forward"), bench_dir),
+                end_to_end=e2e, per_layer=per_layer)
 
 
 def metric_reader(name: str, bench_dir: str = BENCH_DIR) -> Callable:
@@ -101,9 +148,6 @@ def metric_reader(name: str, bench_dir: str = BENCH_DIR) -> Callable:
     for stem in (name, base):
         path = os.path.join(bench_dir, "metrics", f"{stem}.py")
         if os.path.exists(path):
-            spec = importlib.util.spec_from_file_location(
-                f"bench_metric_{stem.replace('.', '_').replace('-', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+            return _load_module(
+                path, f"bench_metric_{stem.replace('.', '_').replace('-', '_')}").read
     raise SpecError(f"no reader for per-layer metric {name!r} under metrics/")

@@ -29,10 +29,13 @@ def _kind(path: str) -> str:
     return "matrix"
 
 
-def make_params(program_cfg, seed: int, vocab: int):
+def make_params(program_cfg, seed: int, vocab: int, weight_kind=None):
     """The parameter tree of ``program_cfg`` filled from ``seed``: matrices
     N(0, 1/fan_in), the embedding N(0, 0.02²) with its vocabulary padding
-    rows zero, an untied head N(0, 0.02²), norm gains 1 + N(0, 0.05²)."""
+    rows zero, an untied head N(0, 0.02²), norm gains 1 + N(0, 0.05²).  A
+    leaf's kind comes from its name, unless ``weight_kind(path, shape)`` (the
+    architecture module's, where it has one) names it; leaf ``i`` is drawn
+    from ``fold_in(key, i)`` either way."""
     import jax
     import jax.numpy as jnp
 
@@ -41,7 +44,10 @@ def make_params(program_cfg, seed: int, vocab: int):
     shapes = jax.eval_shape(
         lambda: registry.init_model(jax.random.PRNGKey(0), program_cfg))
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-    kinds = [_kind(jax.tree_util.keystr(p)) for p, _ in flat]
+    kinds = []
+    for key_path, leaf in flat:
+        path = jax.tree_util.keystr(key_path)
+        kinds.append((weight_kind and weight_kind(path, leaf.shape)) or _kind(path))
 
     def make(key):
         out = []

@@ -8,7 +8,8 @@ For each seed, in one process: a run of the cell at its own size and load
 (a window of ``--seconds``, long enough to finish the mix's longest
 requests), then the widest logit gap of the served tokens (the program's
 reading) and, on the same sample, of the reference's lower-precision
-control (``reference/forward.py``, ``control=True``).  The limit goes above
+control (``logits_at(..., control=True)`` of the cell's architecture
+module, ``reference/<architecture>.py``).  The limit goes above
 the largest program reading and below the smallest control reading.  One
 JSON line per seed, then a summary line.
 """

@@ -1,5 +1,10 @@
-"""Plain float32 forward of a Llama-family decoder, as the configuration
-file states it (``configs/<name>.json``, the ``model`` keys).
+"""The architecture module ``forward``: a Llama-family decoder, the one a
+configuration runs where its file names no ``architecture``
+(``benchlib/spec.py`` lists what such a module gives).  Here that is
+``logits_at``, the plain float32 forward as the configuration file states
+it (``configs/<name>.json``, the ``model`` keys); ``work``, the counts of
+``benchlib/work.py`` summed over the layers, all alike; and ``WIDTH_KEYS``,
+which says that the file's ``head_dim`` is the program's.
 
 Every matrix product runs at ``Precision.HIGHEST``: on a TPU an f32 product
 otherwise runs in bf16 passes.  The weights are the benchmark's own
@@ -26,11 +31,17 @@ from __future__ import annotations
 
 import functools
 import math
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 
+from benchlib import work as counts
+
 HI = jax.lax.Precision.HIGHEST
+
+# checked by ``run.program_config`` beside the dense widths
+WIDTH_KEYS = {"head_dim": "hd"}
 
 
 def _mm(a, b):
@@ -123,3 +134,37 @@ def logits_at(w, model: dict, tokens, rows, *, control: bool = False):
     return _logits_rows(w, jnp.asarray(tokens, jnp.int32),
                         jnp.asarray(rows, jnp.int32), model_items=items,
                         control=control)
+
+
+@dataclass(frozen=True)
+class Work:
+    """Whole-model counts of a decoder whose layers are all alike: the
+    per-layer counts of ``benchlib/work.py`` times the layers."""
+    m: counts.Dims
+    tied: bool
+
+    def token_matmul_flops(self, head: bool) -> int:
+        return counts.token_matmul_flops(self.m, head)
+
+    def attention_flops(self, ctx) -> int:
+        return counts.attention_flops(self.m, ctx)
+
+    def decode_attention_bytes(self, ctx: int, nbmax: int) -> int:
+        return counts.decode_attention_bytes(self.m, ctx, nbmax) * self.m.layers
+
+    def decode_attention_flops(self, ctx: int) -> int:
+        return counts.decode_attention_flops(self.m, ctx) * self.m.layers
+
+    def head_flops(self) -> int:
+        """2·d·vocab: one row of logits."""
+        return 2 * self.m.d * self.m.vocab
+
+    def kv_bytes_per_token(self) -> int:
+        return counts.kv_bytes_per_token(self.m)
+
+    def weight_bytes(self) -> int:
+        return counts.weight_bytes(self.m, self.tied)
+
+
+def work(model: dict) -> Work:
+    return Work(counts.Dims.of(model), bool(model.get("tie_word_embeddings", True)))

@@ -4,10 +4,11 @@
     python bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is an entry of ``workloads`` in ``BENCHMARK.json``; its model,
-traffic mix, engine settings and per-layer metric readers are found by name
-under ``bench/`` (``benchlib/spec.py``).  One process: it checks that JAX
-sees the chips the cell asks for (and exits non-zero, printing no result,
-when it does not), makes the weights on the device from ``--seed``, builds
+architecture module (reference, work counts, width checks), traffic mix,
+engine settings and per-layer metric readers are found by name under
+``bench/`` (``benchlib/spec.py``).  One process: it checks that JAX sees the
+chips the cell asks for (and exits non-zero, printing no result, when it
+does not), makes the weights on the device from ``--seed``, builds
 ``repro.serve.Engine``, compiles every program the window can call (set-up,
 ``setup_s``), then offers the cell's traffic from an in-process client for
 ``--seconds`` and checks what was served against the plain float32
@@ -69,19 +70,21 @@ def require_chips(chips: int):
 
 
 def program_config(cell):
-    """The program's ModelConfig, checked against the configuration file."""
+    """The program's ModelConfig, checked against the configuration file:
+    the dense widths above and the architecture module's ``WIDTH_KEYS``
+    (an attribute, or a method of no arguments, of ModelConfig)."""
     from repro.configs import get_config
 
     cfg = get_config(cell.config["program_config"])
     if cell.config.get("program_reduced"):
         cfg = cfg.reduced()           # the tests' tiny stand-in; no cell uses it
     model = cell.config["model"]
-    for key, attr in WIDTH_KEYS.items():
-        if key in model and getattr(cfg, attr) != model[key]:
+    for key, attr in dict(WIDTH_KEYS, **getattr(cell.arch, "WIDTH_KEYS", {})).items():
+        runs = getattr(cfg, attr)
+        runs = runs() if callable(runs) else runs
+        if key in model and runs != model[key]:
             raise SpecError(f"{cell.config['name']}: the program runs {attr}="
-                            f"{getattr(cfg, attr)!r}, the file states {key}={model[key]!r}")
-    if cfg.hd() != model.get("head_dim", cfg.hd()):
-        raise SpecError(f"{cell.config['name']}: head_dim {cfg.hd()} != {model['head_dim']}")
+                            f"{runs!r}, the file states {key}={model[key]!r}")
     return cfg
 
 
@@ -191,7 +194,6 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices=None,
     from benchlib.traffic import Traffic
     from benchlib.warmup import warm
     from benchlib.weights import make_params
-    from benchlib.work import Dims
     from repro.serve.engine import Engine, Request, SamplingParams
 
     clock = CompileClock()
@@ -203,7 +205,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices=None,
     cfg = program_config(cell)
 
     marks = [("start", PROCESS_START), ("jax", time.perf_counter())]
-    params = make_params(cfg, seed, model["vocab_size"])
+    params = make_params(cfg, seed, model["vocab_size"],
+                         getattr(cell.arch, "weight_kind", None))
     jax.block_until_ready(params)
     marks.append(("weights", time.perf_counter()))
     batch, max_len, kwargs = engine_args(eng_cfg, len(devices))
@@ -274,7 +277,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices=None,
         if tw.state == "done":
             reduced = tr.reduce(tr.load_xplane(tw.dir))
         shutil.rmtree(tw.dir, ignore_errors=True)
-        ctx = Ctx(model=model, dims=Dims.of(model), peaks=peaks or {},
+        ctx = Ctx(model=model, work=cell.arch.work(model), peaks=peaks or {},
                   settings=st, records=records, t0=client.t0, t_end=client.t_end,
                   compile_s=compile_s, block_size=getattr(eng, "block_size", None),
                   nbmax=getattr(eng, "nbmax", None),
@@ -302,7 +305,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices=None,
     gc.collect()
     check = st["check"]
     picked = corr.sample(records, seed, check["sample_tokens"])
-    gap, low, n_tok = corr.gaps(params, model, picked, eng_cfg["max_len"],
+    gap, low, n_tok = corr.gaps(cell.arch, params, model, picked, eng_cfg["max_len"],
                                 check["rows"], control=control)
     limit = check["max_logit_gap"]
     result["correct"] = corr.verdict(gap, n_tok, limit)

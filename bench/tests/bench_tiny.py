@@ -59,4 +59,4 @@ def tiny_cell(kind: str, limit: float):
                 "trace": {"start_s": 0.5, "length_s": 1.0},
                 "check": {"sample_tokens": 48, "rows": 32, "max_logit_gap": limit}}
     return Cell(name=f"tiny-{kind}", chips=1, config=config, mix=mix, settings=settings,
-                end_to_end=real.end_to_end, per_layer=real.per_layer)
+                arch=real.arch, end_to_end=real.end_to_end, per_layer=real.per_layer)

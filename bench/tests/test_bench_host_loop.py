@@ -14,7 +14,7 @@ from bench_tiny import TINY_MODEL, bench_run, tiny_cell
 from benchlib import host_loop
 from benchlib.spec import metric_reader
 from benchlib.trace import Event, Reduced, load_xplane, reduce
-from benchlib.work import Dims
+from reference import forward
 
 DEV, HOST = "/device:TPU:0", "/host:CPU"
 T0 = 10.0                     # the client's reading at the window's start, s
@@ -143,7 +143,7 @@ def existing_readings(r):
     """What the accepted readers and the breakdown read of a trace."""
     ctx = types.SimpleNamespace(
         trace=r, trace_counters={"ticks": 4, "prefill_tokens": 512},
-        dims=Dims.of(TINY_MODEL), nbmax=16,
+        work=forward.work(TINY_MODEL), nbmax=16,
         peaks={"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12},
         decode_contexts=lambda: [100, 200, 300], first_tokens_in_trace=lambda: [],
         ideal_hits=lambda: {})
